@@ -209,8 +209,21 @@ def sharded_worker(shards: int, smoke: bool) -> list:
 
 
 def _sharded_ab(shards: int, smoke: bool) -> list:
-    """Spawn the sharded worker under a forced {shards}-device topology and
-    re-parse its CSV rows."""
+    """The replicated-vs-sharded A/B over ``shards`` devices.
+
+    On the CPU backend it runs in a child under a forced {shards}-device
+    topology, whose CSV rows are re-parsed here.  An accelerator belongs to
+    the one process that holds it, so elsewhere the A/B runs in this process
+    when it has the devices, and otherwise reports a row that says it did
+    not run (no timing, no pass)."""
+    if jax.default_backend() != "cpu":
+        if jax.device_count() >= shards:
+            return sharded_worker(shards, smoke)
+        return [(
+            f"mapping/metl_consume_sharded_{shards}sh",
+            float("nan"),
+            f"not run: {jax.device_count()} devices",
+        )]
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={shards}"
     src = os.path.join(os.path.dirname(__file__), "..", "src")

@@ -9,16 +9,11 @@ import jax
 def bench(fn: Callable, *args, warmup: int = 2, iters: int = 10) -> float:
     """Median wall-time per call in microseconds (device-synchronised)."""
     for _ in range(warmup):
-        out = fn(*args)
-        jax.block_until_ready(out) if hasattr(out, "block_until_ready") or isinstance(out, (tuple, list, dict)) else None
+        jax.block_until_ready(fn(*args))
     times = []
     for _ in range(iters):
         t0 = time.perf_counter()
-        out = fn(*args)
-        try:
-            jax.block_until_ready(out)
-        except Exception:
-            pass
+        jax.block_until_ready(fn(*args))
         times.append(time.perf_counter() - t0)
     times.sort()
     return times[len(times) // 2] * 1e6

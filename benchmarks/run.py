@@ -55,6 +55,9 @@ def main() -> None:
     ap.add_argument("--artifact", default=None, metavar="DIR",
                     help="write a BENCH_<ts>.json trajectory artifact to DIR")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     print("name,us_per_call,derived")
     failed = 0
     all_rows = []

@@ -34,6 +34,7 @@ from repro.etl import (
 )
 from repro.train.loop import TrainConfig, train
 from repro.train.optimizer import AdamWConfig
+from repro.launch.compile_cache import use_compile_cache
 
 SCALES = {
     # n_layers, d_model, heads, d_ff  (~params with 8k vocab)
@@ -51,6 +52,7 @@ def main() -> None:
     ap.add_argument("--model-scale", default="smoke", choices=list(SCALES))
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    use_compile_cache()
 
     # -- the ETL side: CDC stream -> METL pipeline -> BatcherSink -------------
     sc = build_scenario(ScenarioConfig(n_schemas=12, versions_per_schema=4, seed=0))

@@ -30,6 +30,7 @@ from repro.etl import (
     TableSink,
     TokenizerSink,
 )
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main() -> None:
@@ -41,6 +42,7 @@ def main() -> None:
     ap.add_argument("--engine", default="fused", choices=["fused", "blocks"])
     ap.add_argument("--sync", action="store_true", help="disable the double buffer")
     args = ap.parse_args()
+    use_compile_cache()
 
     sc = build_scenario(ScenarioConfig(n_schemas=8, versions_per_schema=3, seed=3))
     coord = StateCoordinator(sc.registry, sc.dpm)

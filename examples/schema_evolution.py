@@ -45,6 +45,7 @@ from repro.etl import (
     VersionDeleted,
     replay_control_log,
 )
+from repro.launch.compile_cache import use_compile_cache
 
 
 def scripted_control(registry):
@@ -73,6 +74,7 @@ def main() -> None:
     ap.add_argument("--instances", type=int, default=0,
                     help="run the scripted stream over an N-instance Cluster")
     args = ap.parse_args()
+    use_compile_cache()
 
     import repro.configs as C
     from repro.train.loop import TrainConfig, train

@@ -13,6 +13,7 @@ import jax
 import repro.configs as C
 from repro.models import model as M
 from repro.serve.decode import ServeConfig, Server, greedy_decode
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main() -> None:
@@ -21,6 +22,7 @@ def main() -> None:
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=12)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = C.get_smoke(args.arch)
     params = M.init_params(cfg, jax.random.PRNGKey(0))

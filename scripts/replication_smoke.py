@@ -24,6 +24,10 @@ CLI roles):
    seen exactly once (nothing duplicated), same rows in each.
 
 Exit 0 on success; non-zero with a diagnostic on any divergence.
+
+This is a CPU drill of the control plane: every process it starts runs JAX
+on the CPU backend (``JAX_PLATFORMS=cpu``).  The leader and followers run
+side by side, and an accelerator belongs to one process at a time.
 """
 
 from __future__ import annotations
@@ -39,6 +43,7 @@ import tempfile
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ENV = {
     **os.environ,
+    "JAX_PLATFORMS": "cpu",
     "PYTHONPATH": os.path.join(REPO, "src")
     + (os.pathsep + os.environ["PYTHONPATH"] if os.environ.get("PYTHONPATH") else ""),
 }

@@ -100,7 +100,7 @@ class Cluster:
         *,
         engine: Any = "fused",
         mesh: Any = None,
-        impl: str = "ref",
+        impl: str = "auto",
         device_densify: bool = False,
         async_consume: bool = False,
         strict_state: bool = False,

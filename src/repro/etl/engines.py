@@ -739,7 +739,7 @@ class MappingEngine:
     def __init__(
         self,
         *,
-        impl: str = "ref",
+        impl: str = "auto",
         stats: Optional[collections.Counter] = None,
         manager: Optional[PlanManager] = None,
     ) -> None:
@@ -913,7 +913,7 @@ def register_engine(name: str) -> Any:
 def make_engine(
     engine: Any = "fused",
     *,
-    impl: str = "ref",
+    impl: str = "auto",
     mesh: Any = None,
     device_densify: bool = False,
     stats: Optional[collections.Counter] = None,
@@ -943,7 +943,7 @@ def make_engine(
     if isinstance(engine, MappingEngine):
         # an instance carries its own impl/mesh; silently overriding (or
         # dropping) conflicting kwargs would run a different path than asked
-        if impl != "ref" and impl != engine.impl:
+        if impl != "auto" and impl != engine.impl:
             raise ValueError(
                 f"impl={impl!r} conflicts with engine instance impl={engine.impl!r}; "
                 "configure the instance instead"
@@ -1019,7 +1019,7 @@ class FusedEngine(MappingEngine):
     def __init__(
         self,
         *,
-        impl: str = "ref",
+        impl: str = "auto",
         device_densify: bool = False,
         min_device_events: int = 32,
         stats: Optional[collections.Counter] = None,
@@ -1149,7 +1149,7 @@ class ShardedEngine(MappingEngine):
     plan_kind = "sharded"
 
     def __init__(
-        self, *, mesh: Any, impl: str = "ref", device_densify: bool = False,
+        self, *, mesh: Any, impl: str = "auto", device_densify: bool = False,
         min_device_events: int = 32, stats: Optional[collections.Counter] = None,
         manager: Optional[PlanManager] = None,
     ) -> None:
@@ -1314,7 +1314,7 @@ class BlocksEngine(MappingEngine):
     plan_kind = "blocks"
 
     def __init__(
-        self, *, impl: str = "ref",
+        self, *, impl: str = "auto",
         stats: Optional[collections.Counter] = None,
         manager: Optional[PlanManager] = None,
     ) -> None:

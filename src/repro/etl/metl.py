@@ -70,7 +70,7 @@ class METLApp:
         *,
         strict_state: bool = False,
         dedup_window: int = 4096,
-        impl: str = "ref",
+        impl: str = "auto",
         engine: Union[str, MappingEngine] = "fused",
         mesh: Any = None,
         device_densify: bool = False,
@@ -108,6 +108,8 @@ class METLApp:
         # triage/state rather than called by the user); delivered by the
         # next consume() / take_replayed() so they are never lost
         self._replay_rows: List[CanonicalRow] = []
+        # consume_scalar's per-(o, v) view of the snapshot's DPM
+        self._scalar_cols: Optional[tuple] = None
         # weak registration: the coordinator must not keep this app alive
         # (or keep evicting its corpse) after the owner drops it -- the
         # bench/test pattern constructs many apps against one coordinator
@@ -330,12 +332,21 @@ class METLApp:
     def consume_scalar(self, events: Iterable[CDCEvent]) -> List[Message]:
         # lazy refresh buffers (not drops) any replayed-parked-event rows
         self.ensure_ready()
+        snap = self._snapshot
+        if self._scalar_cols is None or self._scalar_cols[0] is not snap:
+            # Algorithm 6 scans the DPM for the message's (o, v) column;
+            # handing it only that column's blocks (in DPM order) keeps the
+            # output identical and the oracle affordable at paper scale
+            cols: Dict = collections.defaultdict(dict)
+            for key, elements in snap.dpm.items():
+                cols[key[:2]][key] = elements
+            self._scalar_cols = (snap, dict(cols))
+        by_col = self._scalar_cols[1]
         out: List[Message] = []
         for ev in events:
             msg = ev.message().densify()
-            if msg.state != self._snapshot.i:
+            if msg.state != snap.i:
                 continue
-            out.extend(
-                map_message_dense(self._snapshot.dpm, self.coordinator.registry, msg)
-            )
+            col = by_col.get((msg.schema_id, msg.version), {})
+            out.extend(map_message_dense(col, self.coordinator.registry, msg))
         return out

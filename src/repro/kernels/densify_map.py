@@ -37,7 +37,11 @@ exactly the numpy fancy-index semantics of the host scatter
 
 Grid: (S_pad / block_s, W / block_n); ``rows``/``blks`` are scalar-prefetch
 operands (SMEM), ``src2d`` contributes one lane tile of all blocks per grid
-cell, and the item tables ride whole in VMEM (they are O(chunk) small).
+cell, and the item tables ride whole in VMEM, single-buffered (they are
+O(chunk) small).  Each cell first copies its block rows and event rows into
+VMEM scratch one routing scalar at a time
+(:func:`repro.kernels.tiling.gather_rows`) -- Mosaic has no vector row
+gather -- then runs the select chain on the gathered tiles.
 """
 
 from __future__ import annotations
@@ -50,10 +54,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["densify_map", "densify_map_shard"]
+from .tiling import LANE, SUBLANE, gather_rows, round_up, vmem_limit
 
-LANE = 128
-SUBLANE = 8
+__all__ = ["densify_map", "densify_map_shard"]
 
 
 def _kernel(
@@ -64,26 +67,29 @@ def _kernel(
     x_ref: Any,
     out_v_ref: Any,
     out_m_ref: Any,
+    src_buf: Any,
+    es_buf: Any,
+    ex_buf: Any,
     *,
     block_s: int,
     k: int,
     fill: float,
 ) -> None:
-    i = pl.program_id(0)
-    rows = rows_ref[pl.ds(i * block_s, block_s)]  # (block_s,) int32 from SMEM
-    blks = blks_ref[pl.ds(i * block_s, block_s)]  # (block_s,) int32 from SMEM
-    src = jnp.take(src2d_ref[...], blks, axis=0)  # (block_s, block_n)
-    es = jnp.take(slot_ref[...], rows, axis=0)  # (block_s, K_pad)
-    ex = jnp.take(x_ref[...], rows, axis=0)  # (block_s, K_pad)
+    base = pl.program_id(0) * block_s
+    gather_rows(blks_ref, base, block_s, [(src_buf, src2d_ref)])
+    gather_rows(rows_ref, base, block_s, [(es_buf, slot_ref), (ex_buf, x_ref)])
+    src = src_buf[...]  # (block_s, block_n)
+    es = es_buf[...]  # (block_s, K_pad)
+    ex = ex_buf[...]  # (block_s, K_pad)
     valid = src >= 0
     # compare-accumulate over the K items of each output row's event: item j
     # lands in every output slot whose src equals its payload slot.  -1
     # (dropped item) can never match a valid src entry, so no extra mask.
-    acc = jnp.full(src.shape, fill, x_ref.dtype)
+    acc = jnp.full(src.shape, fill, ex.dtype)
     hit = jnp.zeros(src.shape, jnp.bool_)
     for j in range(k):  # K is tiny and static: unrolled select chain
-        m = valid & (src == es[:, j][:, None])
-        acc = jnp.where(m, ex[:, j][:, None], acc)
+        m = valid & (src == es[:, j : j + 1])
+        acc = jnp.where(m, ex[:, j : j + 1], acc)
         hit = hit | m
     out_v_ref[...] = acc
     out_m_ref[...] = hit.astype(jnp.int8)
@@ -102,7 +108,7 @@ def densify_map(
     block_s: int = 256,
     block_n: int = LANE,
     fill: float = 0.0,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Densify + map every (event, block) pair of a chunk in one launch.
 
@@ -119,16 +125,16 @@ def densify_map(
         raise ValueError(f"W={w} not a multiple of block_n={block_n}")
     if n_blocks_pad % SUBLANE:
         raise ValueError(f"n_blocks_pad={n_blocks_pad} not a multiple of {SUBLANE}")
+    if x2d.dtype.itemsize != 4:
+        raise ValueError(f"x2d must be a 32-bit dtype, got {x2d.dtype}")
 
     # pad to tile boundaries (callers bucket shapes, so these usually no-op);
     # the item lane axis pads to the vector width -- the padded lanes carry
     # slot -1 and are never read by the unrolled loop (it runs true-K only)
-    s8 = -(-s // SUBLANE) * SUBLANE
-    bs = min(block_s, s8)
-    bs = -(-bs // SUBLANE) * SUBLANE
-    s_pad = -(-s // bs) * bs
-    b_pad = -(-b // SUBLANE) * SUBLANE
-    k_pad = -(-k // LANE) * LANE
+    bs = round_up(min(block_s, round_up(s, SUBLANE)), SUBLANE)
+    s_pad = round_up(s, bs)
+    b_pad = round_up(b, SUBLANE)
+    k_pad = round_up(k, LANE)
     if s_pad != s:
         rows = jnp.pad(rows, (0, s_pad - s))
         blks = jnp.pad(blks, (0, s_pad - s))
@@ -139,6 +145,7 @@ def densify_map(
         x2d = jnp.pad(x2d, ((0, b_pad - b), (0, k_pad - k)))
 
     grid = (s_pad // bs, w // block_n)
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     out_v, out_m = pl.pallas_call(
         functools.partial(_kernel, block_s=bs, k=k, fill=fill),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -146,18 +153,28 @@ def densify_map(
             grid=grid,
             in_specs=[
                 pl.BlockSpec((n_blocks_pad, block_n), lambda i, j, rows, blks: (0, j)),
-                pl.BlockSpec((b_pad, k_pad), lambda i, j, rows, blks: (0, 0)),
-                pl.BlockSpec((b_pad, k_pad), lambda i, j, rows, blks: (0, 0)),
+                whole,
+                whole,
             ],
             out_specs=[
                 pl.BlockSpec((bs, block_n), lambda i, j, rows, blks: (i, j)),
                 pl.BlockSpec((bs, block_n), lambda i, j, rows, blks: (i, j)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bs, block_n), jnp.int32),
+                pltpu.VMEM((bs, k_pad), jnp.int32),
+                pltpu.VMEM((bs, k_pad), x2d.dtype),
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((s_pad, w), x2d.dtype),
             jax.ShapeDtypeStruct((s_pad, w), jnp.int8),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit(
+                slot2d.nbytes, x2d.nbytes, 2 * n_blocks_pad * block_n * 4
+            )
+        ),
         interpret=interpret,
     )(rows, blks, src2d, slot2d, x2d)
     return out_v[:s], out_m[:s]
@@ -173,7 +190,7 @@ def densify_map_shard(
     block_s: int = 256,
     block_n: int = LANE,
     fill: float = 0.0,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Per-shard body of the sharded device-densify path -- runs INSIDE
     shard_map.  Same layout contract as

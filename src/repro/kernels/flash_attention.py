@@ -99,7 +99,7 @@ def flash_attention(
     n_rep: int = 1,
     block_q: int = 256,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     n, s, hd = q.shape
     nk_heads, t, _ = k.shape

@@ -6,15 +6,18 @@ of dense messages is a gather along the attribute (lane) axis.
 
 TPU adaptation (vs. the paper's JVM hashmap lookups):
 
-  * ``src`` is a *scalar-prefetch* operand: it lands in SMEM before the grid
-    body runs, so index tiles are available ahead of the payload tiles
-    streaming HBM->VMEM (the TPU analogue of the paper's Caffeine-cached
-    O(1) column lookup).
+  * ``src`` rides as a (1, N_out) VMEM row, tiled by ``block_n`` lanes next
+    to the payload tiles (the TPU analogue of the paper's Caffeine-cached
+    O(1) column lookup).  SMEM serves only scalar loads, so an index
+    *vector* cannot come from a scalar-prefetch operand.
   * The batch axis is tiled to ``block_b`` sublane rows; the output attribute
     axis is tiled to ``block_n`` lanes (multiples of 128).  Each grid cell
     reads the *full* input row (mapping widths are small -- schema versions
     have O(10..1000) attributes, so a row tile fits VMEM comfortably) and
-    gathers one output tile with ``take_along_axis`` on the lane axis.
+    gathers one output tile with a lane-axis gather
+    (:func:`repro.kernels.tiling.lane_gather`).  Payloads narrower than 32
+    bits and the int8 mask are widened on device first: the lane gather
+    moves 32-bit lanes.
   * The paper's "null object" is the validity mask: ``mask`` rides through
     the same gather and pad slots (src = -1) are forced invalid.
 
@@ -34,12 +37,10 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from .tiling import LANE, SUBLANE, lane_gather, round_up
 
 __all__ = ["masked_gather"]
-
-LANE = 128
-SUBLANE = 8
 
 
 def _kernel(
@@ -49,20 +50,15 @@ def _kernel(
     out_v_ref: Any,
     out_m_ref: Any,
     *,
-    block_n: int,
     fill: float,
 ) -> None:
-    j = pl.program_id(1)
-    idx = src_ref[pl.ds(j * block_n, block_n)]  # (block_n,) int32 from SMEM
+    idx = src_ref[...]  # (1, block_n) int32
     valid = idx >= 0
-    safe = jnp.where(valid, idx, 0)
-    vals = vals_ref[...]  # (block_b, n_in_pad)
-    mask = mask_ref[...]  # (block_b, n_in_pad) int8
-    bb = vals.shape[0]
-    idx2 = jnp.broadcast_to(safe[None, :], (bb, block_n))
-    g_v = jnp.take_along_axis(vals, idx2, axis=1)
-    g_m = jnp.take_along_axis(mask, idx2, axis=1)
-    ok = (g_m != 0) & valid[None, :]
+    bb = vals_ref.shape[0]
+    safe = jnp.broadcast_to(jnp.where(valid, idx, 0), (bb, idx.shape[1]))
+    g_v = lane_gather(vals_ref[...], safe)
+    g_m = lane_gather(mask_ref[...], safe)
+    ok = (g_m != 0) & valid
     out_v_ref[...] = jnp.where(ok, g_v, jnp.asarray(fill, g_v.dtype))
     out_m_ref[...] = ok.astype(jnp.int8)
 
@@ -78,7 +74,7 @@ def masked_gather(
     block_b: int = 256,
     block_n: int = LANE,
     fill: float = 0.0,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Apply a compacted DMM block to a batch of dense messages.
 
@@ -90,36 +86,35 @@ def masked_gather(
     (n_out,) = src.shape
     if n_out % block_n:
         raise ValueError(f"N_out={n_out} not a multiple of block_n={block_n}")
-    mask = mask.astype(jnp.int8)
+    dtype = values.dtype
+    values = values.astype(jnp.float32) if dtype.itemsize < 4 else values
+    mask = mask.astype(jnp.int32)
 
     # pad batch to the sublane tile and n_in to the lane tile
-    bb = min(block_b, max(SUBLANE, b))
-    bb = -(-bb // SUBLANE) * SUBLANE
-    b_pad = -(-b // bb) * bb
-    n_in_pad = -(-n_in // LANE) * LANE
+    bb = round_up(min(block_b, max(SUBLANE, b)), SUBLANE)
+    b_pad = round_up(b, bb)
+    n_in_pad = round_up(n_in, LANE)
     if b_pad != b or n_in_pad != n_in:
         values = jnp.pad(values, ((0, b_pad - b), (0, n_in_pad - n_in)))
         mask = jnp.pad(mask, ((0, b_pad - b), (0, n_in_pad - n_in)))
 
     grid = (b_pad // bb, n_out // block_n)
     out_v, out_m = pl.pallas_call(
-        functools.partial(_kernel, block_n=block_n, fill=fill),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((bb, n_in_pad), lambda i, j, src: (i, 0)),
-                pl.BlockSpec((bb, n_in_pad), lambda i, j, src: (i, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((bb, block_n), lambda i, j, src: (i, j)),
-                pl.BlockSpec((bb, block_n), lambda i, j, src: (i, j)),
-            ],
-        ),
+        functools.partial(_kernel, fill=fill),
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((1, block_n), lambda i, j: (0, j)),
+            pl.BlockSpec((bb, n_in_pad), lambda i, j: (i, 0)),
+            pl.BlockSpec((bb, n_in_pad), lambda i, j: (i, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((bb, block_n), lambda i, j: (i, j)),
+            pl.BlockSpec((bb, block_n), lambda i, j: (i, j)),
+        ],
         out_shape=[
             jax.ShapeDtypeStruct((b_pad, n_out), values.dtype),
             jax.ShapeDtypeStruct((b_pad, n_out), jnp.int8),
         ],
         interpret=interpret,
-    )(src, values, mask)
-    return out_v[:b], out_m[:b]
+    )(src.reshape(1, n_out), values, mask)
+    return out_v[:b].astype(dtype), out_m[:b]

@@ -57,7 +57,7 @@ def moe_combine(
     block_t: int = 256,
     block_d: int = 256,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     t, e, c = combine.shape
     e2, c2, d = expert_out.shape

@@ -6,8 +6,12 @@ payload through the MXU.  It exists so the benchmark harness can report the
 paper's A/B -- compacted gather vs. matrix operator -- at the kernel level.
 
 The one-hot matrix is built on the fly inside the kernel from the same
-scalar-prefetched ``src`` vector (building it in HBM would hand the gather
-version a free win on bytes); the MXU contraction is the cost difference.
+``src`` row tile as the gather kernel (building it in HBM would hand the
+gather version a free win on bytes); the MXU contraction is the cost
+difference.  It is built transposed, (N_in, block_n), so ``src`` stays a
+lane row and the contraction is a plain (bb, N_in) @ (N_in, block_n).  The
+f32 contraction runs at ``HIGHEST`` precision: with one nonzero term per
+output, that reproduces the payload exactly.
 """
 
 from __future__ import annotations
@@ -18,12 +22,10 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from .tiling import LANE, SUBLANE, round_up
 
 __all__ = ["onehot_map"]
-
-LANE = 128
-SUBLANE = 8
 
 
 def _kernel(
@@ -33,26 +35,17 @@ def _kernel(
     out_v_ref: Any,
     out_m_ref: Any,
     *,
-    block_n: int,
     fill: float,
 ) -> None:
-    j = pl.program_id(1)
-    idx = src_ref[pl.ds(j * block_n, block_n)]  # (block_n,)
+    idx = src_ref[...]  # (1, block_n); src = -1 columns are all-zero
     vals = vals_ref[...].astype(jnp.float32)  # (bb, n_in_pad)
     mask = mask_ref[...].astype(jnp.float32)
-    n_in_pad = vals.shape[1]
-    # one-hot (block_n, n_in_pad); src = -1 rows are all-zero
-    cols = jax.lax.broadcasted_iota(jnp.int32, (block_n, n_in_pad), 1)
-    m = (idx[:, None] == cols).astype(jnp.float32)
-    out_v = jax.lax.dot_general(
-        vals, m, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (bb, block_n)
-    out_m = (
-        jax.lax.dot_general(
-            mask, m, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        > 0.5
-    )
+    n_in_pad, block_n = vals.shape[1], idx.shape[1]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (n_in_pad, block_n), 0)
+    m = (rows == idx).astype(jnp.float32)  # (n_in_pad, block_n) one-hot
+    hi = jax.lax.Precision.HIGHEST
+    out_v = jnp.dot(vals, m, precision=hi, preferred_element_type=jnp.float32)
+    out_m = jnp.dot(mask, m, precision=hi, preferred_element_type=jnp.float32) > 0.5
     out_v_ref[...] = jnp.where(out_m, out_v, fill).astype(out_v_ref.dtype)
     out_m_ref[...] = out_m.astype(jnp.int8)
 
@@ -66,7 +59,7 @@ def onehot_map(
     block_b: int = 256,
     block_n: int = LANE,
     fill: float = 0.0,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Same contract as :func:`repro.kernels.masked_gather.masked_gather`."""
     b, n_in = values.shape
@@ -74,32 +67,29 @@ def onehot_map(
     if n_out % block_n:
         raise ValueError(f"N_out={n_out} not a multiple of block_n={block_n}")
     mask = mask.astype(jnp.int8)
-    bb = min(block_b, max(SUBLANE, b))
-    bb = -(-bb // SUBLANE) * SUBLANE
-    b_pad = -(-b // bb) * bb
-    n_in_pad = -(-n_in // LANE) * LANE
+    bb = round_up(min(block_b, max(SUBLANE, b)), SUBLANE)
+    b_pad = round_up(b, bb)
+    n_in_pad = round_up(n_in, LANE)
     if b_pad != b or n_in_pad != n_in:
         values = jnp.pad(values, ((0, b_pad - b), (0, n_in_pad - n_in)))
         mask = jnp.pad(mask, ((0, b_pad - b), (0, n_in_pad - n_in)))
     grid = (b_pad // bb, n_out // block_n)
     out_v, out_m = pl.pallas_call(
-        functools.partial(_kernel, block_n=block_n, fill=fill),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((bb, n_in_pad), lambda i, j, src: (i, 0)),
-                pl.BlockSpec((bb, n_in_pad), lambda i, j, src: (i, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((bb, block_n), lambda i, j, src: (i, j)),
-                pl.BlockSpec((bb, block_n), lambda i, j, src: (i, j)),
-            ],
-        ),
+        functools.partial(_kernel, fill=fill),
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((1, block_n), lambda i, j: (0, j)),
+            pl.BlockSpec((bb, n_in_pad), lambda i, j: (i, 0)),
+            pl.BlockSpec((bb, n_in_pad), lambda i, j: (i, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((bb, block_n), lambda i, j: (i, j)),
+            pl.BlockSpec((bb, block_n), lambda i, j: (i, j)),
+        ],
         out_shape=[
             jax.ShapeDtypeStruct((b_pad, n_out), values.dtype),
             jax.ShapeDtypeStruct((b_pad, n_out), jnp.int8),
         ],
         interpret=interpret,
-    )(src, values, mask)
+    )(src.reshape(1, n_out), values, mask)
     return out_v[:b], out_m[:b]

@@ -1,9 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Backend dispatch: Pallas-TPU kernels compile for the TPU target; on any
-other backend (this container is CPU) they execute in ``interpret=True``
-mode -- same kernel body, Python semantics -- or fall back to the pure-jnp
-oracle for speed.  ``impl`` lets benchmarks force a path.
+Backend dispatch: ``impl="auto"`` runs the Pallas kernel, compiled by
+Mosaic, on a TPU, and the pure-jnp reference (``kernels/ref.py``) only on
+the CPU.  A forced kernel ``impl`` on the CPU runs the same kernel body
+under the Pallas interpreter (``interpret=True``), which is how the tests
+hold each kernel to its reference.
 
 Dispatch handles, not results: every ``dmm_apply*`` returns its output
 arrays WITHOUT blocking on them -- under jax's async dispatch they are
@@ -152,7 +153,6 @@ def _sharded_program(
     underneath is keyed only on operand shapes -- same retrace discipline as
     the replicated fused path (bucketed shapes -> a handful of entries).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if impl == "ref":
@@ -166,12 +166,12 @@ def _sharded_program(
             _segmented_gather_shard, fill=fill, interpret=not on_tpu()
         )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P(), P(axis), P(axis), P(axis)),
         out_specs=(P(axis), P(axis)),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -365,7 +365,6 @@ def _columnar_sharded_program(
     replicated inside the same jit, then shard_map fans the per-shard
     routing and block-table slice out exactly like
     :func:`_sharded_program`."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if impl == "ref":
@@ -379,12 +378,12 @@ def _columnar_sharded_program(
             _densify_map_shard, fill=fill, interpret=not on_tpu()
         )
 
-    inner = shard_map(
+    inner = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P(), P(axis), P(axis), P(axis)),
         out_specs=(P(axis), P(axis)),
-        check_rep=False,
+        check_vma=False,
     )
 
     def fn(packed, uid_slot, uid_col, src3d, *, n_items, n_events, n_rows, k, n_shards):

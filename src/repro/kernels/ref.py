@@ -110,8 +110,9 @@ def onehot_map_ref(
     0/1 matrix-vector product.  Numerically identical to masked_gather_ref."""
     n_in = values.shape[1]
     m = (src[:, None] == jnp.arange(n_in, dtype=src.dtype)[None, :]).astype(jnp.float32)
-    out_v = jnp.einsum("qp,bp->bq", m, values.astype(jnp.float32))
-    out_m = jnp.einsum("qp,bp->bq", m, mask.astype(jnp.float32)) > 0.5
+    hi = jax.lax.Precision.HIGHEST  # f32 products; the TPU default rounds to bf16
+    out_v = jnp.einsum("qp,bp->bq", m, values.astype(jnp.float32), precision=hi)
+    out_m = jnp.einsum("qp,bp->bq", m, mask.astype(jnp.float32), precision=hi) > 0.5
     out_v = jnp.where(out_m, out_v, fill).astype(values.dtype)
     return out_v, out_m.astype(jnp.int8)
 

@@ -29,10 +29,14 @@ states -- too large for SMEM) and only the lane tile ``j`` of all blocks is
 resident per grid cell.
 
 Grid: (S_pad / block_s, W / block_n).  Each cell gathers a (block_s, block_n)
-output tile: pick the block rows of ``src2d``, pick the event rows of
-``values``/``mask`` (both fit VMEM whole -- chunk batches are O(100s) rows of
-O(100s) lanes), then a lane-axis ``take_along_axis`` exactly like the
-single-block kernel.  Pad slots (src = -1, or padding rows) come out invalid.
+output tile.  The payload and its mask sit whole in VMEM (chunk batches are
+O(1000s) rows of 128 lanes), single-buffered.  Mosaic has no vector row
+gather, so the tile's block rows and event rows are copied one routing
+scalar at a time into VMEM scratch (:func:`repro.kernels.tiling.gather_rows`),
+then a lane-axis gather (:func:`repro.kernels.tiling.lane_gather`) maps them
+exactly like the single-block kernel.  Pad slots (src = -1, or padding
+rows) come out invalid.  The mask travels as int8 and widens to int32 on
+device: dynamic single-row loads need 32-bit rows.
 
 Roofline: same O(S * (N_in + W)) bytes and zero FLOPs as the per-block path,
 but amortised into one kernel -- the win is dispatch/launch overhead and the
@@ -49,10 +53,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["segmented_gather", "segmented_gather_shard"]
+from .tiling import LANE, SUBLANE, gather_rows, lane_gather, round_up, vmem_limit
 
-LANE = 128
-SUBLANE = 8
+__all__ = ["segmented_gather", "segmented_gather_shard"]
 
 
 def _kernel(
@@ -63,23 +66,21 @@ def _kernel(
     mask_ref: Any,
     out_v_ref: Any,
     out_m_ref: Any,
+    src_buf: Any,
+    v_buf: Any,
+    m_buf: Any,
     *,
     block_s: int,
     fill: float,
 ) -> None:
-    i = pl.program_id(0)
-    rows = rows_ref[pl.ds(i * block_s, block_s)]  # (block_s,) int32 from SMEM
-    blks = blks_ref[pl.ds(i * block_s, block_s)]  # (block_s,) int32 from SMEM
-    src_tile = src2d_ref[...]  # (n_blocks_pad, block_n) lane tile j of all blocks
-    vals = vals_ref[...]  # (B_pad, n_in_pad) whole chunk payload
-    mask = mask_ref[...]  # (B_pad, n_in_pad) int8
-    src = jnp.take(src_tile, blks, axis=0)  # (block_s, block_n)
+    base = pl.program_id(0) * block_s
+    gather_rows(blks_ref, base, block_s, [(src_buf, src2d_ref)])
+    gather_rows(rows_ref, base, block_s, [(v_buf, vals_ref), (m_buf, mask_ref)])
+    src = src_buf[...]  # (block_s, block_n)
     valid = src >= 0
     safe = jnp.where(valid, src, 0)
-    v_rows = jnp.take(vals, rows, axis=0)  # (block_s, n_in_pad)
-    m_rows = jnp.take(mask, rows, axis=0)
-    g_v = jnp.take_along_axis(v_rows, safe, axis=1)
-    g_m = jnp.take_along_axis(m_rows, safe, axis=1)
+    g_v = lane_gather(v_buf[...], safe)
+    g_m = lane_gather(m_buf[...], safe)
     ok = (g_m != 0) & valid
     out_v_ref[...] = jnp.where(ok, g_v, jnp.asarray(fill, g_v.dtype))
     out_m_ref[...] = ok.astype(jnp.int8)
@@ -98,11 +99,11 @@ def segmented_gather(
     block_s: int = 256,
     block_n: int = LANE,
     fill: float = 0.0,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Map every (event, block) pair of a chunk in one kernel launch.
 
-    values: (B, N_in), mask: (B, N_in), rows/blks: (S,) int32,
+    values: (B, N_in) 32-bit, mask: (B, N_in), rows/blks: (S,) int32,
     src2d: (n_blocks_pad, W) int32 with n_blocks_pad % 8 == 0 and
     W % block_n == 0.  Returns ((S, W) values, (S, W) int8 mask); output row
     ``s`` is event row ``rows[s]`` mapped through block ``blks[s]``.
@@ -114,16 +115,16 @@ def segmented_gather(
         raise ValueError(f"W={w} not a multiple of block_n={block_n}")
     if n_blocks_pad % SUBLANE:
         raise ValueError(f"n_blocks_pad={n_blocks_pad} not a multiple of {SUBLANE}")
-    mask = mask.astype(jnp.int8)
+    if values.dtype.itemsize != 4:
+        raise ValueError(f"values must be a 32-bit dtype, got {values.dtype}")
+    mask = mask.astype(jnp.int32)
 
     # pad the chunk to tile boundaries (callers bucket to powers of two, so
     # these pads are usually no-ops and the jit cache keys recur)
-    s8 = -(-s // SUBLANE) * SUBLANE
-    bs = min(block_s, s8)
-    bs = -(-bs // SUBLANE) * SUBLANE
-    s_pad = -(-s // bs) * bs
-    b_pad = -(-b // SUBLANE) * SUBLANE
-    n_in_pad = -(-n_in // LANE) * LANE
+    bs = round_up(min(block_s, round_up(s, SUBLANE)), SUBLANE)
+    s_pad = round_up(s, bs)
+    b_pad = round_up(b, SUBLANE)
+    n_in_pad = round_up(n_in, LANE)
     if s_pad != s:
         rows = jnp.pad(rows, (0, s_pad - s))
         blks = jnp.pad(blks, (0, s_pad - s))
@@ -132,6 +133,7 @@ def segmented_gather(
         mask = jnp.pad(mask, ((0, b_pad - b), (0, n_in_pad - n_in)))
 
     grid = (s_pad // bs, w // block_n)
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     out_v, out_m = pl.pallas_call(
         functools.partial(_kernel, block_s=bs, fill=fill),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -139,18 +141,28 @@ def segmented_gather(
             grid=grid,
             in_specs=[
                 pl.BlockSpec((n_blocks_pad, block_n), lambda i, j, rows, blks: (0, j)),
-                pl.BlockSpec((b_pad, n_in_pad), lambda i, j, rows, blks: (0, 0)),
-                pl.BlockSpec((b_pad, n_in_pad), lambda i, j, rows, blks: (0, 0)),
+                whole,
+                whole,
             ],
             out_specs=[
                 pl.BlockSpec((bs, block_n), lambda i, j, rows, blks: (i, j)),
                 pl.BlockSpec((bs, block_n), lambda i, j, rows, blks: (i, j)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bs, block_n), jnp.int32),
+                pltpu.VMEM((bs, n_in_pad), values.dtype),
+                pltpu.VMEM((bs, n_in_pad), jnp.int32),
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((s_pad, w), values.dtype),
             jax.ShapeDtypeStruct((s_pad, w), jnp.int8),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit(
+                values.nbytes, mask.nbytes, 2 * n_blocks_pad * block_n * 4
+            )
+        ),
         interpret=interpret,
     )(rows, blks, src2d, values, mask)
     return out_v[:s], out_m[:s]
@@ -166,7 +178,7 @@ def segmented_gather_shard(
     block_s: int = 256,
     block_n: int = LANE,
     fill: float = 0.0,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Per-shard body of the *sharded* fused engine -- runs INSIDE shard_map.
 

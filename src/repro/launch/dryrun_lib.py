@@ -228,8 +228,6 @@ def _compile_cell(
 
 def _extract_cost(compiled) -> Dict[str, float]:
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # this JAX returns [dict]; newer, dict
-        ca = ca[0] if ca else {}
     out = {
         "flops": float(ca.get("flops", 0.0)),
         "bytes_accessed": float(ca.get("bytes accessed", 0.0)),

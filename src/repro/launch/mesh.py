@@ -7,23 +7,9 @@ device state -- the dry-run must set XLA_FLAGS *before* the first jax call.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_local_mesh", "make_etl_mesh"]
-
-
-def _make_mesh(shape, axes):
-    """``jax.make_mesh`` across JAX versions.
-
-    Newer JAX grows ``jax.sharding.AxisType`` and a ``make_mesh`` kwarg for
-    it; this version has neither, and passing the kwarg (or touching the
-    missing enum) dies at mesh construction.  Explicit axis types only pick
-    Auto-vs-Explicit sharding mode, and Auto is the default, so the fallback
-    is simply to omit them.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -35,7 +21,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
@@ -43,7 +29,9 @@ def make_local_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     if data * model > n:
         raise ValueError(f"need {data*model} devices, have {n}")
-    return _make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh(
+        (data, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )
 
 
 def make_etl_mesh(shards: int = 0):

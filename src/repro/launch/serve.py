@@ -271,7 +271,7 @@ def _etl_replicated(n_requests: int, vocab: int, max_len: int = 16,
     return sink.prompts
 
 
-def main() -> None:
+def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo_1b")
     ap.add_argument("--smoke", action="store_true")
@@ -302,7 +302,7 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--cache-len", type=int, default=256)
     ap.add_argument("--max-new", type=int, default=16)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.etl and args.shards > 1:
         # must land before the first jax import: device topology is pinned
@@ -316,9 +316,18 @@ def main() -> None:
     import jax
 
     import repro.configs as configs
+    from repro.launch.compile_cache import use_compile_cache
     from repro.models import model as M
     from repro.serve.decode import ServeConfig, Server
 
+    if args.etl and args.replicated and jax.default_backend() == "tpu":
+        raise SystemExit(
+            "--replicated starts follower processes that import JAX, and a "
+            "TPU belongs to one process: they cannot share the chip this "
+            "process holds.  Replicas as devices of one process are ROADMAP "
+            "R5; run --replicated on the CPU backend."
+        )
+    use_compile_cache()
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     sc = ServeConfig(batch=args.batch, cache_len=args.cache_len, max_new=args.max_new)

@@ -166,9 +166,7 @@ def _moe_ep_local(p_local: Dict, x: jax.Array, cfg: ModelConfig, axis: str):
     this shard's E_loc experts.  Experts are sharded over ``axis``."""
     T, D = x.shape
     E, k = cfg.n_experts, cfg.top_k
-    from ..sharding.specs import lax_axis_size
-
-    n_shards = lax_axis_size(axis)
+    n_shards = jax.lax.axis_size(axis)
     E_loc = E // n_shards
     C = _capacity(T, cfg)  # capacity per (expert, source shard)
     # route locally against the full router (router weights replicated)
@@ -210,8 +208,6 @@ def moe_apply(
         mesh = sh.mesh
         axis = sh.model_axis
         dp_axes = sh.data_axes  # ('data',) or ('pod', 'data')
-        from jax.experimental.shard_map import shard_map
-
         def local(p_local, xl):
             xl2 = xl.reshape(-1, D)
             out, probs, experts = _moe_ep_local(p_local, xl2, cfg, axis)
@@ -224,12 +220,12 @@ def moe_apply(
             "w_gate": P(axis, None, None),
             "w_out": P(axis, None, None),
         }
-        out, aux = shard_map(
+        out, aux = jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(p_spec, P(dp_axes, None, None)),
             out_specs=(P(dp_axes, None, None), P()),
-            check_rep=False,
+            check_vma=False,
         )(p, x)
         return out, jnp.mean(aux)
     if impl == "dmm":

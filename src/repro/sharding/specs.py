@@ -30,23 +30,8 @@ __all__ = [
     "ShardingPolicy",
     "make_policy",
     "param_spec_tree",
-    "lax_axis_size",
     "dmm_table_sharding",
 ]
-
-
-def lax_axis_size(axes) -> int:
-    """``jax.lax.axis_size`` across JAX versions (use inside shard_map/pmap).
-
-    This JAX version predates ``lax.axis_size``; ``psum`` of a Python
-    constant is statically folded to ``size * x`` (the classic spelling), so
-    the result is a plain int usable in shapes.  ``axes`` is one axis name
-    or a tuple of them.
-    """
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axes)
-    return jax.lax.psum(1, axes)
 
 
 def dmm_table_sharding(mesh: Mesh, axis: str = "data") -> NamedSharding:

@@ -122,8 +122,6 @@ def make_dp_train_step(cfg: ModelConfig, tc: TrainConfig, mesh, data_axes=("data
     Params/opt state replicated; the batch is sharded over ``data_axes``.
     Exercises the compressed DP all-reduce wire format end-to-end.
     """
-    from jax.experimental.shard_map import shard_map
-
     def local_step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(M.loss_fn)(params, cfg, batch, None)
         if tc.opt.compress_grads:
@@ -142,7 +140,7 @@ def make_dp_train_step(cfg: ModelConfig, tc: TrainConfig, mesh, data_axes=("data
         return jax.tree_util.tree_map(lambda _: spec, tree)
 
     def step(params, opt_state, batch):
-        return shard_map(
+        return jax.shard_map(
             local_step,
             mesh=mesh,
             in_specs=(
@@ -155,7 +153,7 @@ def make_dp_train_step(cfg: ModelConfig, tc: TrainConfig, mesh, data_axes=("data
                 spec_like(opt_state, rep),
                 {"loss": rep, "grad_norm": rep, "lr": rep},
             ),
-            check_rep=False,
+            check_vma=False,
         )(params, opt_state, batch)
 
     return jax.jit(step)

@@ -143,11 +143,9 @@ def compress_grads_int8(
         q = jnp.clip(jnp.round(total / gscale), -127, 127).astype(jnp.int8)
         err = total - q.astype(jnp.float32) * gscale
         summed = jax.lax.psum(q.astype(jnp.int32), data_axes)
-        from ..sharding.specs import lax_axis_size
-
         n = 1
         for a in data_axes:
-            n *= lax_axis_size(a)
+            n *= jax.lax.axis_size(a)
         mean = summed.astype(jnp.float32) * gscale / n
         return mean.astype(gl.dtype), err
 

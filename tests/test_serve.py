@@ -1,6 +1,7 @@
 """Serving layer: greedy decode, continuous batching server."""
 
 import numpy as np
+import pytest
 import jax
 import jax.numpy as jnp
 
@@ -31,6 +32,21 @@ def test_server_completes_all_requests():
     server.run(n_steps=200)
     assert all(rid in server.done for rid in rids)
     assert all(len(server.done[rid]) == 5 for rid in rids)
+
+
+def test_replicated_etl_refuses_a_tpu_before_touching_it(monkeypatch):
+    """Follower processes cannot share the chip the parent holds: on a TPU
+    backend ``serve --etl --replicated`` exits before any parameter lands
+    on the device."""
+    from repro.launch import serve
+
+    def no_params(*a, **k):
+        raise AssertionError("init_params ran before the refusal")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(M, "init_params", no_params)
+    with pytest.raises(SystemExit, match="one process"):
+        serve.main(["--smoke", "--etl", "--instances", "2", "--replicated"])
 
 
 def test_server_slot_reuse():
