@@ -1,0 +1,8 @@
+"""Host time in the engine's ``densify`` (routing and packing) per event
+written in the window."""
+
+
+def read(ctx):
+    if not ctx.events_in_window:
+        return None
+    return ctx.spans.total("densify", ctx.t0, ctx.tw) / ctx.events_in_window * 1e6
