@@ -117,6 +117,7 @@ from ..kernels.ops import (
     dmm_apply_fused,
     dmm_apply_sharded,
 )
+from . import tracing
 from .events import CDCEvent, ColumnarChunk, columnarize
 from .plan import ColdColumn, PlanEpoch, PlanManager
 
@@ -396,6 +397,7 @@ class _ChunkLayout:
     out_keys: np.ndarray  # (S,) i64
 
 
+@tracing.traced("densify.layout")
 def _chunk_layout(
     plan: Any,
     tri: TriagedChunk,
@@ -515,6 +517,7 @@ def _to_device(*arrays: np.ndarray) -> Tuple[Any, ...]:  # metl: allow[transfer-
     return tuple(jnp.asarray(a) for a in arrays)
 
 
+@tracing.traced("densify.pack")
 def _pack_columnar(
     layout: _ChunkLayout, rows_flat: np.ndarray, blks_flat: np.ndarray
 ) -> Tuple[np.ndarray, Tuple[int, int, int, int]]:
@@ -698,6 +701,7 @@ def _emit_cold(
     return rows
 
 
+@tracing.traced("emit.rows")
 def _emit_rows(plan, ov, om, blk_ids, out_keys, stats) -> List[CanonicalRow]:
     """Row emission shared by the fused and sharded engines: one
     ``any``/``nonzero`` over the gathered output mask, then slice each
@@ -1029,6 +1033,7 @@ class FusedEngine(MappingEngine):
         self.device_densify = device_densify
         self.min_device_events = min_device_events
 
+    @tracing.traced("densify")
     def densify(self, groups: Groups) -> Any:
         tri = as_triaged(groups)
         if tri is None:
@@ -1061,6 +1066,7 @@ class FusedEngine(MappingEngine):
             cold=cold,
         )
 
+    @tracing.traced("dispatch")
     def dispatch(self, dense) -> DispatchHandle:
         if dense.row_ids.size == 0:  # cold-only chunk: nothing resident
             return DispatchHandle(outputs=None, dense=dense)
@@ -1093,13 +1099,15 @@ class FusedEngine(MappingEngine):
         self.stats["dispatches"] += 1
         return DispatchHandle(outputs=outputs, dense=dense)
 
+    @tracing.traced("emit")
     def emit(self, handle: DispatchHandle) -> List[CanonicalRow]:
         dense = handle.dense
         rows: List[CanonicalRow] = []
         if handle.outputs is not None:
             s = dense.row_ids.size
-            ov = np.asarray(handle.outputs[0])[:s]  # metl: allow[host-sync-in-hot-path] the engine sync point
-            om = np.asarray(handle.outputs[1])[:s]  # metl: allow[host-sync-in-hot-path] the engine sync point
+            with tracing.span("emit.sync"):
+                ov = np.asarray(handle.outputs[0])[:s]  # metl: allow[host-sync-in-hot-path] the engine sync point
+                om = np.asarray(handle.outputs[1])[:s]  # metl: allow[host-sync-in-hot-path] the engine sync point
             rows = _emit_rows(
                 dense.plan, ov, om, dense.blk_ids, dense.out_keys, self.stats
             )
@@ -1177,6 +1185,7 @@ class ShardedEngine(MappingEngine):
             blks_sh[s, : len(idx)] = blk_ids[idx] - s * per
         return sel, rows_sh, blks_sh
 
+    @tracing.traced("densify")
     def densify(self, groups: Groups) -> Any:
         tri = as_triaged(groups)
         if tri is None:
@@ -1209,6 +1218,7 @@ class ShardedEngine(MappingEngine):
             cold=cold,
         )
 
+    @tracing.traced("dispatch")
     def dispatch(self, dense) -> DispatchHandle:
         if dense.row_ids.size == 0:  # cold-only chunk: nothing resident
             return DispatchHandle(outputs=None, dense=dense)
@@ -1240,6 +1250,7 @@ class ShardedEngine(MappingEngine):
         self.stats["dispatches"] += 1
         return DispatchHandle(outputs=outputs, dense=dense)
 
+    @tracing.traced("emit")
     def emit(self, handle: DispatchHandle) -> List[CanonicalRow]:
         dense = handle.dense
         rows: List[CanonicalRow] = []
@@ -1247,8 +1258,9 @@ class ShardedEngine(MappingEngine):
             sh = dense.plan
             # all-gather: pull every shard's emitted dense rows to the host
             # and scatter them back to the global output order
-            ov = np.asarray(handle.outputs[0])  # metl: allow[host-sync-in-hot-path] the engine sync point (all-gather)
-            om = np.asarray(handle.outputs[1])  # metl: allow[host-sync-in-hot-path] the engine sync point (all-gather)
+            with tracing.span("emit.sync"):
+                ov = np.asarray(handle.outputs[0])  # metl: allow[host-sync-in-hot-path] the engine sync point (all-gather)
+                om = np.asarray(handle.outputs[1])  # metl: allow[host-sync-in-hot-path] the engine sync point (all-gather)
             gv = np.zeros((dense.row_ids.size, sh.width), ov.dtype)
             gm = np.zeros((dense.row_ids.size, sh.width), om.dtype)
             for s, idx in enumerate(dense.shard_sel):
@@ -1338,6 +1350,7 @@ class BlocksEngine(MappingEngine):
             self._luts[(o, v)] = lut
         return lut
 
+    @tracing.traced("densify")
     def densify(self, groups) -> Optional[BlockDense]:
         tri = as_triaged(groups)
         if tri is None:
@@ -1360,6 +1373,7 @@ class BlocksEngine(MappingEngine):
             out.append(((o, v), chunk.keys[idx], vals, mask))
         return BlockDense(plan=self.plan, groups=out)
 
+    @tracing.traced("dispatch")
     def dispatch(self, dense: BlockDense) -> DispatchHandle:
         outputs = []
         for (o, v), keys, vals, mask in dense.groups:
@@ -1371,6 +1385,7 @@ class BlocksEngine(MappingEngine):
                 outputs.append((block, keys, ov, om))
         return DispatchHandle(outputs=outputs, dense=dense)
 
+    @tracing.traced("emit")
     def emit(self, handle: DispatchHandle) -> List[CanonicalRow]:
         rows: List[CanonicalRow] = []
         for block, keys, ov, om in handle.outputs:

@@ -55,6 +55,7 @@ from ..core.dmm import Message, map_message_dense
 from ..core.dmm_jax import CompiledDMM, FusedDMM, ShardedFusedDMM
 from ..core.registry import StaleStateError
 from ..core.state import StateCoordinator, SystemState
+from . import tracing
 from .engines import CanonicalRow, Groups, MappingEngine, TriagedChunk, make_engine
 from .events import CDCEvent, ColumnarChunk, columnarize
 
@@ -199,6 +200,7 @@ class METLApp:
         return False
 
     # -- triage + mapping --------------------------------------------------------
+    @tracing.traced("triage")
     def triage(
         self,
         events: Union[Iterable[CDCEvent], ColumnarChunk],

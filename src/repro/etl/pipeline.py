@@ -134,6 +134,7 @@ from typing import (
 
 import numpy as np
 
+from . import tracing
 from .batcher import CanonicalBatcher, tokenize_row
 from .control import ControlEvent
 from .engines import CanonicalRow
@@ -507,17 +508,33 @@ class Pipeline:
         # before dispatching (a sink went full); mapped first on resume so
         # backpressure never loses events
         self._pending: Optional[Tuple[Chunk, object]] = None
+        # sequence number of the next data chunk pulled: the chunk id that
+        # stamps each stage's span (repro.etl.tracing)
+        self._seq = 0
+        self._sink_spans = ["sink." + type(sink).__name__ for sink in self.sinks]
 
     # -- plumbing -------------------------------------------------------------
     def _fanout(self, rows: List[CanonicalRow]) -> None:
-        for sink in self.sinks:
-            sink.write(rows)
+        for sink, name in zip(self.sinks, self._sink_spans):
+            with tracing.span(name):
+                sink.write(rows)
 
     def _full(self) -> bool:
         return any(sink.full() for sink in self.sinks)
 
-    def _prepare(self, chunk: List[CDCEvent]) -> Any:
-        """Triage + densify one chunk (the host-side half of consume)."""
+    def _poll(self, it: Iterator[StreamItem]) -> Optional[StreamItem]:
+        """Pull the next stream item; a data chunk takes the next sequence
+        number (``self._seq - 1`` once pulled)."""
+        tracing.set_chunk(self._seq)
+        with tracing.span("pipeline.poll"):
+            item = next(it, None)
+        if item is not None and not isinstance(item, ControlEvent):
+            self._seq += 1
+        return item
+
+    def _prepare(self, chunk: List[CDCEvent], seq: int) -> Any:
+        """Triage + densify chunk ``seq`` (the host-side half of consume)."""
+        tracing.set_chunk(seq)
         return self.app.engine.densify(self.app.triage(chunk))
 
     # -- in-band control -------------------------------------------------------
@@ -531,7 +548,7 @@ class Pipeline:
     def _next_data(self, it: Iterator[StreamItem], st: PipelineStats) -> Optional[Chunk]:
         """Pull the next data chunk, applying any control events in-band."""
         while True:
-            item = next(it, None)
+            item = self._poll(it)
             if not isinstance(item, ControlEvent):
                 return item
             self._control(item, st)
@@ -572,6 +589,7 @@ class Pipeline:
             self._run_async(it, st)
         else:
             self._run_sync(it, st)
+        tracing.set_chunk(-1)
         return st
 
     def close(self) -> None:
@@ -581,12 +599,12 @@ class Pipeline:
         for sink in self.sinks:
             sink.close()
 
-    def _prepare_ahead(self, chunk):
+    def _prepare_ahead(self, chunk, seq: int):
         """Triage + densify the lookahead chunk while the previous one is in
         flight on device: inline by default (jax async dispatch supplies the
         concurrency), on the persistent worker thread when opted in."""
         if not self.densify_thread:
-            return self._prepare(chunk)
+            return self._prepare(chunk, seq)
         # do any lazy refresh (eviction -> recompile + parked replay) on the
         # MAIN thread before handing triage to the worker: the replay runs
         # dispatch/emit and would otherwise race the main thread's emit on
@@ -596,7 +614,7 @@ class Pipeline:
             self._pool = concurrent.futures.ThreadPoolExecutor(
                 max_workers=1, thread_name_prefix="metl-densify"
             )
-        return self._pool.submit(self._prepare, chunk)
+        return self._pool.submit(self._prepare, chunk, seq)
 
     @staticmethod
     def _resolve(dense):
@@ -623,6 +641,7 @@ class Pipeline:
                 return
             chunk, dense = self._pending
             self._pending = None
+            tracing.set_chunk(self._seq - 1)  # the last chunk pulled
             # the pending chunk was densified before the stop; its dense
             # form stays pinned to that epoch's plan even if control
             # applied in between (DenseChunk.plan)
@@ -636,7 +655,7 @@ class Pipeline:
             # never mapped -- silently skipped events on the next run
             if self._full():
                 break
-            item = next(it, None)
+            item = self._poll(it)
             if item is None:
                 break
             if isinstance(item, ControlEvent):
@@ -668,15 +687,30 @@ class Pipeline:
         if self._pending is not None:
             chunk, dense = self._pending
             self._pending = None
+            seq = self._seq - 1  # the last chunk pulled
         else:
             chunk = self._next_data(it, st)
             if chunk is None:
                 return
-            dense = self._prepare(chunk)
+            seq = self._seq - 1
+            dense = self._prepare(chunk, seq)
+        tracing.set_chunk(seq)
         handle = engine.dispatch(dense) if dense is not None else None
         while chunk is not None:
-            nxt = next(it, None)
-            if isinstance(nxt, ControlEvent):
+            # the lookahead: chunk N is in flight while N+1 is polled and
+            # prepared; its span is N's wait between dispatch and emit
+            with tracing.span("pipeline.lookahead"):
+                nxt = self._poll(it)
+                control = isinstance(nxt, ControlEvent)
+                # the overlap: N+1's host-side densification runs while N's
+                # dispatch is still in flight on device
+                ahead = (
+                    self._prepare_ahead(nxt, self._seq - 1)
+                    if nxt is not None and not control
+                    else None
+                )
+            tracing.set_chunk(seq)
+            if control:
                 # control boundary: drain the double buffer -- finish N on
                 # the old epoch, apply, then restart the overlap on the new
                 rows = engine.emit(handle) if handle is not None else []
@@ -692,12 +726,10 @@ class Pipeline:
                 # this triage runs the lazy refresh: recompile at the new
                 # epoch + parked-event replay (drained with this chunk's
                 # emit, exactly like the sync path's consume())
-                dense = self._prepare(chunk)
+                seq = self._seq - 1
+                dense = self._prepare(chunk, seq)
                 handle = engine.dispatch(dense) if dense is not None else None
                 continue
-            # the overlap: N+1's host-side densification runs while N's
-            # dispatch is still in flight on device
-            ahead = self._prepare_ahead(nxt) if nxt is not None else None
             rows = engine.emit(handle) if handle is not None else []
             dense_nxt = self._resolve(ahead) if ahead is not None else None
             # drain AFTER the lookahead triage completed (worker joined):
@@ -711,5 +743,6 @@ class Pipeline:
                     # keep the lookahead (already triaged) for resume
                     self._pending = (nxt, dense_nxt)
                 return
-            chunk, dense = nxt, dense_nxt
+            chunk, dense, seq = nxt, dense_nxt, self._seq - 1
+            tracing.set_chunk(seq)
             handle = engine.dispatch(dense) if dense is not None else None

@@ -237,6 +237,7 @@ def dmm_apply_sharded(
 # the whole buffer is one dtype (one transfer, no repacking on device).
 
 
+@jax.named_scope("uid_resolve")
 def _resolve_items(
     packed: jax.Array,
     uid_slot: jax.Array,
@@ -297,7 +298,7 @@ def _columnar_program(
     where XLA cannot alias it and would warn per call.
     """
 
-    def fn(packed, uid_slot, uid_col, src2d, *, n_items, n_events, n_rows, k):
+    def metl_map_chunk(packed, uid_slot, uid_col, src2d, *, n_items, n_events, n_rows, k):
         slot2d, x2d = _resolve_items(
             packed, uid_slot, uid_col, n_items=n_items, n_events=n_events, k=k
         )
@@ -311,7 +312,7 @@ def _columnar_program(
         )
 
     return jax.jit(
-        fn,
+        metl_map_chunk,
         static_argnames=("n_items", "n_events", "n_rows", "k"),
         donate_argnums=(0,) if donate else (),
     )
@@ -386,7 +387,9 @@ def _columnar_sharded_program(
         check_vma=False,
     )
 
-    def fn(packed, uid_slot, uid_col, src3d, *, n_items, n_events, n_rows, k, n_shards):
+    def metl_map_chunk_sharded(
+        packed, uid_slot, uid_col, src3d, *, n_items, n_events, n_rows, k, n_shards
+    ):
         slot2d, x2d = _resolve_items(
             packed, uid_slot, uid_col, n_items=n_items, n_events=n_events, k=k
         )
@@ -397,7 +400,7 @@ def _columnar_sharded_program(
         return inner(slot2d, x2d, rows, blks, src3d)
 
     return jax.jit(
-        fn,
+        metl_map_chunk_sharded,
         static_argnames=("n_items", "n_events", "n_rows", "k", "n_shards"),
         donate_argnums=(0,) if donate else (),
     )
