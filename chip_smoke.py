@@ -278,6 +278,9 @@ def check_phase(res: PhaseResult, want: List[List[Row]]) -> None:
             )
     require(res.info["dispatches"] == res.chunks,
             f"{spec.name}: {res.info['dispatches']} dispatches for {res.chunks} chunks")
+    require(res.info["readbacks_early"] == res.info["dispatches"],
+            f"{spec.name}: {res.info['readbacks_early']} read-backs started at dispatch "
+            f"for {res.info['dispatches']} dispatches")
     per_chunk = 1 if spec.device_densify else 4
     require(res.info["transfers"] == per_chunk * res.chunks,
             f"{spec.name}: {res.info['transfers']} transfers for {res.chunks} chunks")
@@ -338,6 +341,7 @@ def _report(res: PhaseResult, *extra: str) -> None:
     print(
         f"phase {res.spec.name}: events={res.events} chunks={res.chunks} "
         f"rows={n_rows} dispatches={res.info['dispatches']} "
+        f"readbacks_early={res.info['readbacks_early']} "
         f"transfers={res.info['transfers']} rebuilds={res.info['rebuilds']} "
         f"first_call_s={res.first_s:.3f} wall_s={res.wall_s:.3f} "
         f"oracle=identical kernel=tpu_custom_call {' '.join(extra)}".rstrip(),

@@ -14,6 +14,10 @@ sync point from an accident:
 
     ov = np.asarray(handle.outputs[0])[:s]  # metl: allow[host-sync-in-hot-path] the engine sync point
 
+Starting a non-blocking copy in ``dispatch`` (``.copy_to_host_async()``,
+as the engines' ``_start_readback`` does) is the intended pattern: it is no
+sync, so emit's read-back waits only for the rest of a copy under way.
+
 Scope: functions named ``dispatch`` / ``emit`` / ``_run_async`` and the
 ``dmm_apply*`` wrappers, in the ``repro.etl`` and ``repro.kernels``
 packages -- checked with the full strict/lenient heuristics -- PLUS

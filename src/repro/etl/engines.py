@@ -372,8 +372,10 @@ class DispatchHandle:
 
     ``outputs`` are unblocked jax arrays (futures under async dispatch) --
     or, for the per-block engine, a list of per-block output pairs.  The
-    handle is consumed exactly once by :meth:`MappingEngine.emit`, the only
-    stage that synchronises with the device.
+    fused and sharded engines have already started the outputs' copy to the
+    host (:func:`_start_readback`).  The handle is consumed exactly once by
+    :meth:`MappingEngine.emit`, the only stage that synchronises with the
+    device.
     """
 
     outputs: Any
@@ -515,6 +517,20 @@ def _to_device(*arrays: np.ndarray) -> Tuple[Any, ...]:  # metl: allow[transfer-
     conversion anywhere else on the hot path is an unaccounted transfer
     (the ``transfer-accounting`` analyzer rule flags exactly that)."""
     return tuple(jnp.asarray(a) for a in arrays)
+
+
+def _start_readback(outputs: Tuple[Any, ...], stats: collections.Counter) -> None:
+    """Start the device->host copy of a dispatch's outputs, without waiting.
+
+    ``copy_to_host_async`` only enqueues the transfer behind the launch;
+    ``emit``'s ``np.asarray`` later picks up the copy already under way.
+    In the double-buffered pipeline the read-back thus runs behind the next
+    chunk's poll, triage and densify instead of on ``emit``'s critical
+    path; in the sync path emit follows at once and nothing changes.
+    Counts one ``stats["readbacks_early"]`` per output set."""
+    for out in outputs:
+        out.copy_to_host_async()
+    stats["readbacks_early"] += 1
 
 
 @tracing.traced("densify.pack")
@@ -856,6 +872,10 @@ class MappingEngine:
           ``n_shards``    mesh shards the plan is partitioned over (1 when
                           replicated)
           ``dispatches``  cumulative device dispatches through this engine
+          ``readbacks_early``  cumulative dispatches whose output read-back
+                          ``dispatch`` started (fused/sharded engines; equals
+                          ``dispatches`` there, absent from the per-block
+                          engine)
           ``transfers``   cumulative host->device transfers (fused/sharded
                           engines; the per-block engine reports none)
           ``device_densify``  whether densification runs on device
@@ -1096,6 +1116,7 @@ class FusedEngine(MappingEngine):
             )
             outputs = dmm_apply_fused(jv, jm, jr, jb, fused.src2d, impl=impl)
             self.stats["transfers"] += 4  # vals, mask, rows, blks
+        _start_readback(outputs, self.stats)
         self.stats["dispatches"] += 1
         return DispatchHandle(outputs=outputs, dense=dense)
 
@@ -1105,6 +1126,7 @@ class FusedEngine(MappingEngine):
         rows: List[CanonicalRow] = []
         if handle.outputs is not None:
             s = dense.row_ids.size
+            # the rest of the read-back dispatch started (_start_readback)
             with tracing.span("emit.sync"):
                 ov = np.asarray(handle.outputs[0])[:s]  # metl: allow[host-sync-in-hot-path] the engine sync point
                 om = np.asarray(handle.outputs[1])[:s]  # metl: allow[host-sync-in-hot-path] the engine sync point
@@ -1121,6 +1143,7 @@ class FusedEngine(MappingEngine):
             "n_shards": 1,
             "device_densify": self.device_densify,
             "dispatches": int(self.stats["dispatches"]),
+            "readbacks_early": int(self.stats["readbacks_early"]),
             "transfers": int(self.stats["transfers"]),
             **self._manager_info(),
         }
@@ -1247,6 +1270,7 @@ class ShardedEngine(MappingEngine):
                 jv, jm, jr, jb, sh.src3d, mesh=sh.mesh, impl=impl
             )
             self.stats["transfers"] += 4
+        _start_readback(outputs, self.stats)
         self.stats["dispatches"] += 1
         return DispatchHandle(outputs=outputs, dense=dense)
 
@@ -1257,7 +1281,8 @@ class ShardedEngine(MappingEngine):
         if handle.outputs is not None:
             sh = dense.plan
             # all-gather: pull every shard's emitted dense rows to the host
-            # and scatter them back to the global output order
+            # (the copy dispatch started) and scatter them back to the
+            # global output order
             with tracing.span("emit.sync"):
                 ov = np.asarray(handle.outputs[0])  # metl: allow[host-sync-in-hot-path] the engine sync point (all-gather)
                 om = np.asarray(handle.outputs[1])  # metl: allow[host-sync-in-hot-path] the engine sync point (all-gather)
@@ -1279,6 +1304,7 @@ class ShardedEngine(MappingEngine):
             "n_shards": self.n_shards,
             "device_densify": self.device_densify,
             "dispatches": int(self.stats["dispatches"]),
+            "readbacks_early": int(self.stats["readbacks_early"]),
             "transfers": int(self.stats["transfers"]),
             **self._manager_info(),
         }

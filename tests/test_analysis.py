@@ -176,6 +176,24 @@ def test_host_sync_scalar_readback_in_dispatch(tmp_path):
     assert "host-sync-in-hot-path" in _rules_hit(rep)
 
 
+def test_host_sync_early_readback_in_dispatch(tmp_path):
+    """Starting the read-back in dispatch does not block; reading it does."""
+    rep = _run(
+        tmp_path,
+        "src/repro/etl/e.py",
+        "import numpy as np\n"
+        "\n"
+        "def dispatch(dense):\n"
+        "    handle = launch(dense)\n"
+        "    handle.outputs[0].copy_to_host_async()\n"  # line 5
+        "    handle.outputs[1].copy_to_host()\n"  # line 6
+        "    np.asarray(handle.outputs[1])\n"  # line 7
+        "    return handle\n",
+    )
+    hits = [f for f in rep.findings if f.rule == "host-sync-in-hot-path"]
+    assert sorted(f.line for f in hits) == [6, 7]
+
+
 def test_host_sync_out_of_scope_module(tmp_path):
     # same code outside repro.etl / repro.kernels is not this rule's business
     rep = _run(tmp_path, "scripts_dir/tool.py", _SYNC_FIRING)

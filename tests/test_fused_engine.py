@@ -8,6 +8,8 @@ Covers the acceptance surface of the fused refactor:
   * padded lane widths (CDM wider than one 128-lane tile);
   * parked-event replay after a state bump flows through the rebuilt engine;
   * dispatch count is constant per chunk (not O(#blocks));
+  * dispatch starts the outputs' read-back, and the double buffer stays
+    bit-exact with it (the case is shared with the sharded engine's tests);
   * the Pallas segmented-gather kernel matches the jnp oracle.
 """
 
@@ -20,7 +22,7 @@ from repro.core.dmm_jax import LANE, bucket_rows, compile_dpm, compile_fused
 from repro.core.registry import Registry
 from repro.core.state import StateCoordinator
 from repro.core.synthetic import ScenarioConfig, build_scenario
-from repro.etl import EventSource, METLApp
+from repro.etl import CollectSink, EventSource, ListSource, METLApp, Pipeline
 from repro.kernels import ops
 
 
@@ -201,6 +203,65 @@ def test_constant_dispatches_per_chunk():
     app._seen.clear()  # metl: allow[private-reach-in] deliberate dedup reset so the re-consumed chunk is not swallowed; reset_dedup() would also reset stats under test
     app.consume(src.slice(0, 100))
     assert ops.dispatch_count - before_ops == 1
+
+
+def _assert_rows_bit_identical(a, b):
+    assert a and len(a) == len(b)
+    for x, y in zip(a, b):
+        assert x[0] == y[0] and x[3] == y[3]  # route, event key
+        np.testing.assert_array_equal(x[1].view(np.int32), y[1].view(np.int32))
+        np.testing.assert_array_equal(x[2], y[2])
+
+
+def early_readback_case(engine="fused", mesh=None, device_densify=True):
+    """dispatch starts the copy of both outputs to the host before it
+    returns and counts it in ``readbacks_early``; sync and double-buffered
+    pipelines still write rows bit-identical to the host-densify oracle.
+    Also run by tests/test_sharded_engine.py, on a forced 4-device mesh."""
+    sc = build_scenario(ScenarioConfig(seed=47))
+    coord = StateCoordinator(sc.registry, sc.dpm)
+    src = EventSource(sc.registry, seed=5, p_duplicate=0.1)
+    chunks = [src.slice(k * 100, 100) for k in range(4)]
+    oracle = METLApp(coord, engine="fused")  # host densify, one chunk at a time
+    want = [r for c in chunks for r in oracle.consume(c)]
+
+    array_cls = type(jnp.zeros(1))
+    real = array_cls.copy_to_host_async
+    started = []
+
+    def spy(self):
+        started.append(self)
+        return real(self)
+
+    array_cls.copy_to_host_async = spy
+    try:
+        app = METLApp(coord, engine=engine, mesh=mesh, device_densify=device_densify)
+        handle = app.engine.dispatch(app.engine.densify(app.triage(chunks[0])))
+        assert len(started) == 2  # both outputs, before emit
+        assert all(a is b for a, b in zip(started, handle.outputs))
+        info = app.engine.info()
+        assert info["engine"] == engine
+        assert info["readbacks_early"] == info["dispatches"] == 1
+        app.engine.emit(handle)
+        for async_consume in (False, True):
+            app = METLApp(coord, engine=engine, mesh=mesh, device_densify=device_densify)
+            sink = CollectSink()
+            before = len(started)
+            pipe = Pipeline(ListSource(chunks), app, [sink], async_consume=async_consume)
+            pipe.run()
+            pipe.close()
+            info = app.engine.info()
+            assert info["dispatches"] == len(chunks)
+            assert info["readbacks_early"] == info["dispatches"]
+            assert len(started) - before == 2 * info["dispatches"]
+            _assert_rows_bit_identical(want, sink.rows)
+    finally:
+        array_cls.copy_to_host_async = real
+
+
+@pytest.mark.parametrize("device_densify", [False, True])
+def test_dispatch_starts_readback(device_densify):
+    early_readback_case("fused", device_densify=device_densify)
 
 
 def test_empty_chunk_dispatches_nothing():

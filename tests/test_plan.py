@@ -585,7 +585,7 @@ class TestPublish:
 REPLICATION_KEYS = {"role", "term", "log_offset", "lag_records"}
 FUSED_ALWAYS = {
     "engine", "impl", "n_shards", "device_densify", "dispatches",
-    "transfers", "plan_epoch", "rebuilds",
+    "readbacks_early", "transfers", "plan_epoch", "rebuilds",
 } | REPLICATION_KEYS
 BLOCKS_ALWAYS = {"engine", "impl", "n_shards", "dispatches", "plan_epoch",
                  "rebuilds"} | REPLICATION_KEYS

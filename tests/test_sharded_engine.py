@@ -8,7 +8,8 @@ Covers the acceptance surface of the sharding tentpole:
   * the device table really is distributed: each device holds only its
     (1, n_blocks_pad_loc, W) slice, ~ total/N bytes;
   * host-side partitioning reconstructs the replicated table exactly;
-  * 1-device mesh (or no mesh) falls back to the replicated fused path.
+  * 1-device mesh (or no mesh) falls back to the replicated fused path;
+  * dispatch starts the all-gather's read-back (the fused engine's case).
 
 The multi-device cases run in a *subprocess* via the shared forced-topology
 harness (tests/_subproc.py): jax pins the device count at first init and
@@ -16,6 +17,7 @@ the rest of the suite must see exactly one device.
 """
 
 import functools
+import os
 
 import numpy as np
 import pytest
@@ -23,6 +25,7 @@ import pytest
 from _subproc import run_sub as _run_sub
 
 run_sub = functools.partial(_run_sub, devices=4)
+TESTS = os.path.dirname(os.path.abspath(__file__))
 
 
 @pytest.mark.slow
@@ -111,6 +114,21 @@ def test_sharded_replay_and_state_bump():
         print("sharded replay OK")
     """)
     assert "sharded replay OK" in out
+
+
+@pytest.mark.parametrize("device_densify", [False, True])
+def test_sharded_dispatch_starts_readback(device_densify):
+    """The fused engine's early read-back case, on a 1x4 CPU mesh."""
+    out = run_sub(f"""
+        import sys
+        sys.path.insert(0, {TESTS!r})
+        from test_fused_engine import early_readback_case
+        from repro.launch.mesh import make_etl_mesh
+
+        early_readback_case("sharded", make_etl_mesh(4), device_densify={device_densify})
+        print("sharded early readback OK")
+    """)
+    assert "sharded early readback OK" in out
 
 
 def test_sharded_table_partitioning_host():
