@@ -96,9 +96,9 @@ def build_history(reg: dict) -> History:
     return History(n_entities=n_e, cdm_names=cdm_names, versions=versions, slot=slot)
 
 
-def register(history: History):
-    """The program's registry and DPM for a history (public API only)."""
-    from repro.core.dmm import MappingMatrix, transform_to_dpm
+def registry_for(history: History):
+    """The program's registry for a history (public API only): what a
+    producer's schema-registry client holds."""
     from repro.core.registry import Registry
 
     reg = Registry()
@@ -111,6 +111,14 @@ def register(history: History):
             prev = {a.name for a in latest.attributes}
             reg.evolve(reg.domain, o, keep=[n for n in names if n in prev],
                        add=[n for n in names if n not in prev])
+    return reg
+
+
+def register(history: History):
+    """The program's registry and DPM for a history (public API only)."""
+    from repro.core.dmm import MappingMatrix, transform_to_dpm
+
+    reg = registry_for(history)
     matrix = MappingMatrix(reg)
     for o, chain in history.versions.items():
         r = history.entity(o)
@@ -152,6 +160,16 @@ class Tables:
         self.cdm_pos.append(np.asarray([history.slot[o][n] for n in names], np.int32))
         self.n_out.append(len(history.cdm_names[r]))
         return len(self.cols) - 1
+
+    def version_cols(self) -> Dict[int, List[int]]:
+        """Each schema's column ids, version 1 first."""
+        cols: Dict[int, List[int]] = {}
+        for c, (o, v) in enumerate(self.cols):
+            lst = cols.setdefault(o, [])
+            if v != len(lst) + 1:
+                raise RuntimeError(f"column ({o}, {v}) out of order")
+            lst.append(c)
+        return cols
 
     def flat(self):
         """Column-major flat arrays: (start, count, uid_flat, pos_flat)."""

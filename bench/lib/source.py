@@ -75,6 +75,14 @@ class OpenLoopSource(Source):
     (``clock`` is when the poll took every event due by then),
     in order; the pipeline writes each chunk's rows once, in the same
     order, which is how a write is matched to its events.
+
+    ``control`` lists in-band control items as ``(first, at, item)``,
+    ``first`` ascending: ``item`` is yielded at its due offset ``at``,
+    before event ``first`` (the first event due at or after it), and no
+    poll spans it.  ``controls`` records ``(first, clock)`` of each yielded.
+    A backlog's passes after the first carry the stream's newest registry
+    state, where the first pass's control items left the program: the
+    topic's events re-produced at that state (their uids are the same).
     """
 
     def __init__(
@@ -88,8 +96,12 @@ class OpenLoopSource(Source):
         key_span: int = 0,
         clock=time.perf_counter,
         annotate: bool = False,
+        control: Sequence[Tuple[int, float, object]] = (),
     ) -> None:
         self.b = batch
+        self.newest = int(batch.state.max()) if batch.n else 0
+        self.control = list(control)
+        self.controls: List[Tuple[int, float]] = []
         self.annotate = annotate
         col_o = np.asarray([o for o, _ in cols], np.int64)
         col_v = np.asarray([v for _, v in cols], np.int64)
@@ -114,7 +126,8 @@ class OpenLoopSource(Source):
         lo, hi = b.offsets[i], b.offsets[i + 1]
         payload = {int(u): float(x) for u, x in zip(b.uid[lo:hi], b.val[lo:hi])}
         return CDCEvent(
-            key=int(b.key[i]) + key_shift, op="c", state=int(b.state[i]),
+            key=int(b.key[i]) + key_shift, op="c",
+            state=int(b.state[i]) if g < b.n else self.newest,
             schema_id=int(self.schema_id[i]), version=int(self.version[i]),
             before=None, after=payload, ts=g,
         )
@@ -132,7 +145,7 @@ class OpenLoopSource(Source):
             event_offsets=b.offsets[i : j + 1] - a,
             keys=b.key[i:j] + shift,
             bad=np.zeros(j - i, bool),
-            states=b.state[i:j],
+            states=b.state[i:j] if lo < b.n else np.full(j - i, self.newest, b.state.dtype),
             schema_ids=self.schema_id[i:j],
             versions=self.version[i:j],
         )
@@ -142,16 +155,25 @@ class OpenLoopSource(Source):
         end = None if self.cycle else n
         due_abs = self.due
         while True:
-            if end is not None and self.cursor >= end:
+            lo = self.cursor
+            k = len(self.controls)
+            ctl = self.control[k] if k < len(self.control) else None
+            if end is not None and lo >= end and ctl is None:
                 return
             if self.clock() >= self.stop_at:
                 return
-            lo = self.cursor
+            if ctl is not None and ctl[0] <= lo:
+                wait_until(self.t0 + ctl[1], self.clock)
+                self.controls.append((lo, self.clock()))
+                yield ctl[2]
+                continue
             limit = lo + self.max_poll
             if end is not None:
                 limit = min(limit, end)
             else:
                 limit = min(limit, (lo // n + 1) * n)
+            if ctl is not None:
+                limit = min(limit, ctl[0])
             with _span("bench:poll", self.annotate):
                 if self.cycle:
                     hi, now = limit, self.clock()

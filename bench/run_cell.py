@@ -45,7 +45,7 @@ import shutil  # noqa: E402
 import sys  # noqa: E402
 import types  # noqa: E402
 from pathlib import Path  # noqa: E402
-from typing import Any, Dict, List, Optional  # noqa: E402
+from typing import Any, Dict, List, Optional, Tuple  # noqa: E402
 
 BENCH = Path(__file__).resolve().parent
 REPO = BENCH.parent
@@ -98,21 +98,39 @@ WARM_MIXES = ({}, {"p_null": 0.25}, {"p_null": 0.5}, {"max_items": 8})
 
 
 class Counters:
-    """Backend compiles (and persistent-cache loads), from jax.monitoring."""
+    """Backend compiles, from jax.monitoring.
+
+    JAX records its backend-compile event around the persistent cache's
+    lookup, so a program loaded from that cache fires it as a compile does:
+    ``compiles`` counts both (``compiles_in_window.live`` reads it), and
+    ``loads`` the cache-hit events, which only a load records.  ``secs``
+    sums the backend-compile events' durations and ``ends`` holds the clock
+    at the end of each.  The churn cell's window runs with the cache off,
+    so there every event is a compile."""
 
     EVENT = "/jax/core/compile/backend_compile_duration"
+    HIT = "/jax/compilation_cache/cache_hits"
 
     def __init__(self) -> None:
         import jax
 
-        self.compiles = 0
+        self.compiles = self.loads = 0
+        self.secs = 0.0
+        self.ends: List[float] = []
         self.names: List[str] = []
+
+        def on_hit(event: str, **kw: Any) -> None:
+            if event == self.HIT:
+                self.loads += 1
 
         def on(event: str, secs: float, **kw: Any) -> None:
             if event == self.EVENT:
                 self.compiles += 1
+                self.secs += secs
+                self.ends.append(time.perf_counter())
                 self.names.append(str(kw.get("fun_name", "?")))
 
+        jax.monitoring.register_event_listener(on_hit)
         jax.monitoring.register_event_duration_secs_listener(on)
 
 
@@ -129,22 +147,22 @@ def use_compile_cache() -> str:
     return path
 
 
+def cache_off() -> None:
+    """Turn JAX's persistent cache off for the rest of the process: nothing
+    is loaded from it or written to it."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+
+
 def load_metric(name: str):
     path = BENCH / "metrics" / f"{name}.py"
     spec = importlib.util.spec_from_file_location(f"bench_metric_{name.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
-
-
-def _version_cols(tables: dep.Tables) -> Dict[int, List[int]]:
-    cols: Dict[int, List[int]] = {}
-    for c, (o, v) in enumerate(tables.cols):
-        lst = cols.setdefault(o, [])
-        if v != len(lst) + 1:
-            raise RuntimeError(f"column ({o}, {v}) out of order")
-        lst.append(c)
-    return cols
 
 
 def _app(deployment: dep.Deployment, engine: dict):
@@ -181,7 +199,7 @@ def _warm_chunks(rng, stream, weights, tables, sizes, state, key0):
 
     chunks = []
     for m, mix in enumerate(WARM_MIXES if len(set(sizes)) > 1 else ({},)):
-        batch = trf.generate(rng, {**stream, **mix}, weights, _version_cols(tables),
+        batch = trf.generate(rng, {**stream, **mix}, weights, tables.version_cols(),
                              tables.flat(), int(sum(sizes)), key0 + m * (1 << 36), state)
         src = OpenLoopSource(batch, tables.cols, np.zeros(batch.n), max_poll=max(sizes))
         edges = np.cumsum([0] + list(sizes))
@@ -239,12 +257,13 @@ def run(
     hits = _warm(app, engine, warm, counters)
     marks["warm-up"] = clock()
     log(f"warm-up: {len(warm)} chunks, {counters.compiles} compiles "
-        f"({len(hits)} chunks compiled), cache {cache_dir}")
+        f"({counters.loads} of them persistent-cache loads; {len(hits)} chunks compiled), "
+        f"cache {cache_dir}")
 
     # -- the window's traffic --------------------------------------------------
-    flat = tables.flat()
     arrival = traffic["arrival"]
     backlog = arrival["kind"] == "backlog"
+    churn = traffic.get("churn")
     if backlog:
         n = int(arrival["backlog_events"])
         if n % traffic["max_poll_records"]:
@@ -254,13 +273,35 @@ def run(
     else:
         due = trf.arrivals(rng, arrival, seconds)
         n = due.size
-    batch = trf.generate(rng, stream, weights, _version_cols(tables), flat, n, 0, real.state)
+    evolutions: List[Any] = []
+    if churn:
+        from bench.lib import churn as chn
+
+        batch, evolutions = chn.generate(rng, stream, weights, real, churn, due, seconds)
+        inband = [(e.first, e.at, chn.event(e)) for e in evolutions]
+    else:
+        batch = trf.generate(rng, stream, weights, tables.version_cols(), tables.flat(), n, 0,
+                             real.state)
+        inband = []
     marks["traffic"] = clock()
+    if churn and cache:
+        # a deployment meeting a new registry state has never compiled its
+        # programs: no run may load them from an earlier one
+        cache_off()
     table = TableSink()
     writes = WriteClock(clock, freeze=True)
     src = OpenLoopSource(batch, tables.cols, due, max_poll=traffic["max_poll_records"],
-                         cycle=backlog, key_span=batch.n, clock=clock, annotate=trace)
-    pipe = Pipeline(src, app, [table, writes], async_consume=engine["async_consume"])
+                         cycle=backlog, key_span=batch.n, clock=clock, annotate=trace,
+                         control=inband)
+    applies: List[Tuple[float, float]] = []
+
+    def apply_timed(event) -> None:  # the pipeline's default, timed
+        a = clock()
+        app.coordinator.apply(event, defer_frozen=True)
+        applies.append((a, clock()))
+
+    pipe = Pipeline(src, app, [table, writes], async_consume=engine["async_consume"],
+                    apply_control=apply_timed if churn else None)
     spans = None
     if trace:
         from bench.lib.spans import Spans
@@ -283,7 +324,7 @@ def run(
         f"{b} {marks[b] - marks[a]:.2f}" for a, b in zip(names[:-1], names[1:])))
 
     # -- the window ------------------------------------------------------------
-    compiles0 = counters.compiles
+    compiles0, loads0, secs0 = counters.compiles, counters.loads, counters.secs
     jax.config.update("jax_log_compiles", True)  # names what compiles in the window
     pauses = GcPauses(clock, annotate=trace)
     t0 = clock()
@@ -299,6 +340,8 @@ def run(
     pauses.close()
     jax.config.update("jax_log_compiles", False)
     compiles_window = counters.compiles - compiles0
+    loads_window = counters.loads - loads0
+    secs_window = counters.secs - secs0
     if compiles_window:
         log(f"compiled in the window: {counters.names[compiles0:]}")
     if trace:
@@ -335,9 +378,12 @@ def run(
                 + " ".join(f"{x:.2f}" for x in fifths))
         log(f"backlog at the close: {int(np.searchsorted(due, seconds)) - int(sizes_w[in_win].sum())}"
             f" events due and not yet written")
+    if evolutions:
+        _log_churn(evolutions, src.controls, applies, wt, counters.ends, t0, tw, log)
     log(f"window: {seconds} s, {len(polls)} chunks, {n_written} events written "
         f"({int(sizes_w[in_win].sum())} by the close), run ended {t_done - tw:+.3f} s "
-        f"after the close, {compiles_window} compiles in the window")
+        f"after the close, {compiles_window} compiles in the window ({loads_window} "
+        f"persistent-cache loads), {secs_window * 1e3:.1f} ms in them")
     log("collector in the window: " + pauses.summary(t0, tw))
     if in_win.sum() > 1:
         log(f"longest time between two writes in the window: "
@@ -377,8 +423,10 @@ def run(
     breakdown = None
     if not trace:
         for m in cell_metrics(bench, cell, "end_to_end"):
-            if m["name"] in values:
-                metrics[m["name"]] = {"value": values[m["name"]], "unit": m["unit"]}
+            # ``<quantity>.<traffic>`` is the quantity, named apart for a cell
+            v = values.get(m["name"], values.get(m["name"].split(".")[0]))
+            if v is not None:
+                metrics[m["name"]] = {"value": v, "unit": m["unit"]}
     else:
         from bench.lib import trace as tr
 
@@ -388,6 +436,8 @@ def run(
             cell=cell, t0=t0, tw=tw, seconds=seconds, spans=spans,
             events_in_window=int(sizes_w[in_win].sum()), chunks_in_window=int(in_win.sum()),
             poll_sizes=sizes_w[wt <= tw], compiles_in_window=compiles_window,
+            loads_in_window=loads_window, compile_s_in_window=secs_window,
+            evolutions_applied=len(src.controls),
             device_kind=dev.device_kind, reduction=None, kernel_bytes=None,
         )
         if open_ns is not None and ops:
@@ -417,6 +467,32 @@ def run(
     for k, v in check.items():
         log(f"check {k}: {v} (limit 0)")
     return result
+
+
+def _log_churn(evolutions, yielded, applies, writes, ends, t0, tw, log) -> None:
+    """Log where each evolution's time went, by the host's clock: the
+    source's yield, the pipeline's drain up to the coordinator's apply, the
+    apply, the next write, and the programs compiled before the next
+    evolution's apply (or the run's end)."""
+    ends, writes = np.asarray(ends), np.asarray(writes)
+    for k, e in enumerate(evolutions):
+        step = "never yielded"
+        if k < len(yielded) and k < len(applies):
+            (a, done), y = applies[k], yielded[k][1]
+            nxt = applies[k + 1][0] if k + 1 < len(applies) else np.inf
+            later = writes[writes > done]
+            w = later[0] if later.size else np.nan
+            c = int(((ends > done) & (ends < nxt)).sum())
+            step = (f"yielded {y - t0:.3f} s into the window (due {e.at:.3f} s), drain "
+                    f"{(a - y) * 1e3:.1f} ms, apply {(done - a) * 1e3:.1f} ms, "
+                    f"next write {(w - done) * 1e3:.1f} ms after it, {c} compiles before "
+                    f"the next evolution's apply")
+        log(f"evolution {k} before event {e.first}: schema {e.schema} keeps {len(e.keep)}, "
+            f"adds {len(e.add)}; {step}")
+    log("compiles end at (s into the window): " + " ".join(
+        f"{c - t0:.2f}" for c in ends if c > t0))
+    log(f"evolutions applied by the close: {sum(1 for _, d in applies if d <= tw)} of "
+        f"{len(evolutions)} ({len(applies)} by the run's end)")
 
 
 def _kernel_bytes(batch, tables, polls) -> int:

@@ -51,13 +51,13 @@ def test_reference_agrees_across_a_schema_evolution(small_spec):
         Pipeline(ListSource([chunk, *control]), app, [table],
                  async_consume=small_spec["engine"]["async_consume"]).run()
 
-    before = trf.generate(rng, stream, w, run_cell._version_cols(d.tables), d.tables.flat(),
+    before = trf.generate(rng, stream, w, d.tables.version_cols(), d.tables.flat(),
                           1500, 0, d.state)
     o = 3
     keep, add = d.history.versions[o][-1][1:], [f"s{o}.new0", f"s{o}.new1"]
     feed(before, SchemaEvolved(tree="domain", schema_id=o, keep=tuple(keep), add=tuple(add)))
     d.tables.add(d.history, d.coordinator.registry, o, d.history.evolve(o, keep, add))
-    after = trf.generate(rng, stream, w, run_cell._version_cols(d.tables), d.tables.flat(),
+    after = trf.generate(rng, stream, w, d.tables.version_cols(), d.tables.flat(),
                          1500, 10**6, d.state)
     assert (after.col == len(d.tables.cols) - 1).any()  # the new version carries traffic
     feed(after)
