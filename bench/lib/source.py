@@ -9,15 +9,19 @@ time, and its latency runs from there.
 
 Chunks are real ``ColumnarChunk``\\ s sliced from the pre-generated
 arrays.  The per-event ``CDCEvent`` objects the program needs only on its
-dead-letter and park paths are built when asked for.
+dead-letter and park paths are built when asked for.  A deployment kind
+(``bench/kinds/``) builds both from its own batch: the source hands it the
+builders as ``builders``, and builds EOS's float32 batch with
+:func:`columnar_chunk` and :func:`cdc_event` where none are given.
 """
 
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import gc
 import time
-from typing import List, Optional, Sequence, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -41,6 +45,56 @@ class LazyEvents(Sequence):
         if not -len(self) <= k < len(self):
             raise IndexError(k)
         return self.src.event(self.lo + (k % len(self)), self.key_shift)
+
+
+@dataclasses.dataclass
+class Routed(Batch):
+    """A float32 batch with each event's schema id and version, looked up
+    once from its column."""
+
+    schema_id: np.ndarray  # int64 (n,)
+    version: np.ndarray  # int64 (n,)
+
+
+def routed(batch: Batch, cols: List[Tuple[int, int]]) -> Routed:
+    """``batch`` with each event's (schema, version) from ``cols``, the
+    columns its ``col`` indexes."""
+    col_o = np.asarray([o for o, _ in cols], np.int64)
+    col_v = np.asarray([v for _, v in cols], np.int64)
+    fields = {f.name: getattr(batch, f.name) for f in dataclasses.fields(Batch)}
+    return Routed(**fields, schema_id=col_o[batch.col], version=col_v[batch.col])
+
+
+def columnar_chunk(b: Routed, lo: int, hi: int, key_shift: int, state: Optional[int],
+                   events: Sequence[CDCEvent]) -> ColumnarChunk:
+    """Events [lo, hi) of ``b`` as the program's chunk, keys shifted by
+    ``key_shift``; ``state`` None keeps each event's own registry state,
+    else every event carries ``state``."""
+    a, z = b.offsets[lo], b.offsets[hi]
+    return ColumnarChunk(
+        events=events,
+        uids=b.uid[a:z],
+        vals=b.val[a:z],
+        event_offsets=b.offsets[lo : hi + 1] - a,
+        keys=b.key[lo:hi] + key_shift,
+        bad=np.zeros(hi - lo, bool),
+        states=b.state[lo:hi] if state is None else np.full(hi - lo, state, b.state.dtype),
+        schema_ids=b.schema_id[lo:hi],
+        versions=b.version[lo:hi],
+    )
+
+
+def cdc_event(b: Routed, i: int, key_shift: int, state: Optional[int], ts: int) -> CDCEvent:
+    """Event ``i`` of ``b`` as the program's ``CDCEvent`` (see
+    :func:`columnar_chunk`)."""
+    lo, hi = b.offsets[i], b.offsets[i + 1]
+    payload = {int(u): float(x) for u, x in zip(b.uid[lo:hi], b.val[lo:hi])}
+    return CDCEvent(
+        key=int(b.key[i]) + key_shift, op="c",
+        state=int(b.state[i]) if state is None else state,
+        schema_id=int(b.schema_id[i]), version=int(b.version[i]),
+        before=None, after=payload, ts=ts,
+    )
 
 
 def wait_until(t: float, clock=time.perf_counter) -> None:
@@ -83,6 +137,12 @@ class OpenLoopSource(Source):
     A backlog's passes after the first carry the stream's newest registry
     state, where the first pass's control items left the program: the
     topic's events re-produced at that state (their uids are the same).
+
+    ``builders`` is a deployment kind's ``(chunk, event)``, called as
+    :func:`columnar_chunk` and :func:`cdc_event` are on ``batch``, which
+    then needs only ``n`` and ``state`` (each event's registry state) here;
+    ``cols`` is unused.  Without them ``batch`` is EOS's float32
+    :class:`Batch`, whose ``col`` indexes ``cols``.
     """
 
     def __init__(
@@ -97,16 +157,16 @@ class OpenLoopSource(Source):
         clock=time.perf_counter,
         annotate: bool = False,
         control: Sequence[Tuple[int, float, object]] = (),
+        builders: Optional[Tuple[Callable[..., ColumnarChunk], Callable[..., CDCEvent]]] = None,
     ) -> None:
+        if builders is None:
+            batch, builders = routed(batch, cols), (columnar_chunk, cdc_event)
         self.b = batch
+        self.build_chunk, self.build_event = builders
         self.newest = int(batch.state.max()) if batch.n else 0
         self.control = list(control)
         self.controls: List[Tuple[int, float]] = []
         self.annotate = annotate
-        col_o = np.asarray([o for o, _ in cols], np.int64)
-        col_v = np.asarray([v for _, v in cols], np.int64)
-        self.schema_id = col_o[batch.col]
-        self.version = col_v[batch.col]
         self.due = due
         self.max_poll = max_poll
         self.cycle = cycle
@@ -121,34 +181,16 @@ class OpenLoopSource(Source):
         self.t0, self.stop_at = t0, stop_at
 
     def event(self, g: int, key_shift: int) -> CDCEvent:
-        b = self.b
-        i = g % b.n
-        lo, hi = b.offsets[i], b.offsets[i + 1]
-        payload = {int(u): float(x) for u, x in zip(b.uid[lo:hi], b.val[lo:hi])}
-        return CDCEvent(
-            key=int(b.key[i]) + key_shift, op="c",
-            state=int(b.state[i]) if g < b.n else self.newest,
-            schema_id=int(self.schema_id[i]), version=int(self.version[i]),
-            before=None, after=payload, ts=g,
-        )
+        n = self.b.n
+        return self.build_event(self.b, g % n, key_shift, None if g < n else self.newest, g)
 
     def chunk(self, lo: int, hi: int) -> ColumnarChunk:
         """Events [lo, hi) of the stream, which lie in one pass of it."""
-        b = self.b
-        shift = (lo // b.n) * self.key_span
-        i, j = lo % b.n, (hi - 1) % b.n + 1
-        a, z = b.offsets[i], b.offsets[j]
-        return ColumnarChunk(
-            events=LazyEvents(self, lo, hi, shift),
-            uids=b.uid[a:z],
-            vals=b.val[a:z],
-            event_offsets=b.offsets[i : j + 1] - a,
-            keys=b.key[i:j] + shift,
-            bad=np.zeros(j - i, bool),
-            states=b.state[i:j] if lo < b.n else np.full(j - i, self.newest, b.state.dtype),
-            schema_ids=self.schema_id[i:j],
-            versions=self.version[i:j],
-        )
+        n = self.b.n
+        shift = (lo // n) * self.key_span
+        return self.build_chunk(self.b, lo % n, (hi - 1) % n + 1, shift,
+                                None if lo < n else self.newest,
+                                LazyEvents(self, lo, hi, shift))
 
     def poll(self):
         n = self.b.n

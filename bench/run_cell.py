@@ -6,12 +6,15 @@
 
 A cell is one entry of ``workloads`` in ``BENCHMARK.json``: a deployment
 (``bench/deployments/<config>.json``) under a traffic mix
-(``bench/traffic/<cell>.json``).  The run builds the deployment through the
-program's public API, generates every event of the window from ``--seed``,
-warms up every chunk shape the traffic will use (set-up), then drives
-``Pipeline`` over ``METLApp`` into a ``TableSink`` from an open-loop source
-for ``--seconds``.  After the window it compares every row the window
-produced with the plain reference (``bench/lib/reference.py``).
+(``bench/traffic/<cell>.json``).  The deployment file names its kind, and
+every step that depends on the deployment's shape goes through
+``bench/kinds/<kind>.py`` (see ``bench/kinds/__init__.py``).  The run builds
+the deployment through the program's public API, generates every event of
+the window from ``--seed``, warms up every chunk shape the traffic will use
+(set-up), then drives ``Pipeline`` over ``METLApp`` into a ``TableSink``
+from an open-loop source for ``--seconds``.  After the window it compares
+every row the window produced with the kind's plain reference (EOS's is
+``bench/lib/reference.py``).
 
 The ``TableSink`` keeps every row of the window for that comparison, which
 a deployment's sink, handing rows to its warehouse, would not.  So that
@@ -55,7 +58,6 @@ for p in (str(REPO / "src"), str(REPO)):
 
 import numpy as np  # noqa: E402
 
-from bench.lib import deployment as dep  # noqa: E402
 from bench.lib import traffic as trf  # noqa: E402
 
 WARM_KEYS = 1 << 40  # warm-up events' keys: apart from every window key
@@ -157,6 +159,16 @@ def cache_off() -> None:
     compilation_cache.reset_cache()
 
 
+def load_kind(spec: dict, where: str) -> types.ModuleType:
+    """The deployment kind that the deployment file ``where`` names."""
+    name = spec.get("kind")
+    if not isinstance(name, str) or not name.isidentifier() or not (
+            BENCH / "kinds" / f"{name}.py").is_file():
+        raise ValueError(f"{where}: unknown deployment kind {name!r} "
+                         f"(a kind is a module bench/kinds/<kind>.py)")
+    return importlib.import_module(f"bench.kinds.{name}")
+
+
 def load_metric(name: str):
     path = BENCH / "metrics" / f"{name}.py"
     spec = importlib.util.spec_from_file_location(f"bench_metric_{name.replace('.', '_')}", path)
@@ -165,7 +177,7 @@ def load_metric(name: str):
     return mod.read
 
 
-def _app(deployment: dep.Deployment, engine: dict):
+def _app(deployment, engine: dict):
     from repro.etl import METLApp
 
     return METLApp(
@@ -194,14 +206,15 @@ def _warm(app, engine: dict, chunks, counters: Counters) -> List[int]:
     return hits
 
 
-def _warm_chunks(rng, stream, weights, tables, sizes, state, key0):
+def _warm_chunks(kind, deployment, weights, rng, stream, sizes, key0):
     from bench.lib.source import OpenLoopSource
 
     chunks = []
     for m, mix in enumerate(WARM_MIXES if len(set(sizes)) > 1 else ({},)):
-        batch = trf.generate(rng, {**stream, **mix}, weights, tables.version_cols(),
-                             tables.flat(), int(sum(sizes)), key0 + m * (1 << 36), state)
-        src = OpenLoopSource(batch, tables.cols, np.zeros(batch.n), max_poll=max(sizes))
+        batch = kind.generate(deployment, weights, rng, {**stream, **mix}, int(sum(sizes)),
+                              key0 + m * (1 << 36))
+        src = OpenLoopSource(batch, None, np.zeros(batch.n), max_poll=max(sizes),
+                             builders=(kind.chunk, kind.event))
         edges = np.cumsum([0] + list(sizes))
         chunks += [src.chunk(int(a), int(b)) for a, b in zip(edges[:-1], edges[1:])]
     return chunks
@@ -216,6 +229,7 @@ def run(
     bench: Optional[dict] = None,
     deployment_spec: Optional[dict] = None,
     traffic: Optional[dict] = None,
+    kind: Optional[types.ModuleType] = None,
     control: str = "none",
     t_process: float = T_PROCESS,
     trace_dir: Optional[str] = None,
@@ -223,20 +237,26 @@ def run(
     log=lambda *a: print(*a, file=sys.stderr, flush=True),
 ) -> Dict[str, Any]:
     """One run of ``cell``; returns the result object (see module doc).
-    ``deployment_spec``/``traffic`` override the cell's files (tests run a
-    small deployment on the CPU this way, with ``cache=False`` to leave
-    JAX's persistent cache settings alone)."""
+    ``deployment_spec``/``traffic``/``kind`` override the cell's files and
+    the kind its deployment names (tests run a small deployment on the CPU
+    this way, with ``cache=False`` to leave JAX's persistent cache settings
+    alone)."""
     import jax
     from repro.etl import Pipeline, TableSink
 
-    from bench.lib import reference as ref
     from bench.lib.source import OpenLoopSource, WriteClock, latencies
     from bench.lib.spans import GcPauses
 
     bench = bench or load_benchmark()
     spec = cell_spec(bench, cell)
     traffic = traffic or trf.load_traffic(BENCH, cell)
-    dspec = deployment_spec or dep.load_spec(BENCH, spec["config"])
+    where = f"bench/deployments/{spec['config']}.json"
+    dspec = deployment_spec or json.loads((REPO / where).read_text())
+    kind = kind or load_kind(dspec, where)
+    churn = traffic.get("churn")
+    if churn and not hasattr(kind, "churn"):
+        raise ValueError(f"{cell}: its traffic has a churn block, and the deployment kind "
+                         f"{dspec.get('kind', kind.__name__)!r} has no churn")
     engine, stream = dspec["engine"], dspec["stream"]
     cache_dir = use_compile_cache() if cache else "off"
     counters = Counters()
@@ -245,15 +265,12 @@ def run(
 
     # -- the deployment, and the program's app over it ------------------------
     marks = {"start": clock()}
-    real = dep.build(dspec)
-    tables = real.tables
+    real = kind.build(dspec)
     marks["deployment"] = clock()
-    weights = trf.schema_weights(len(real.history.versions),
-                                 traffic.get("skew", {}).get("schema_zipf_s", 0.0),
-                                 dspec["registry"]["seed"])
+    weights = kind.weights(dspec, traffic)
     app = _app(real, engine)
     sizes = warm_sizes(traffic)
-    warm = _warm_chunks(rng, stream, weights, tables, sizes, real.state, WARM_KEYS)
+    warm = _warm_chunks(kind, real, weights, rng, stream, sizes, WARM_KEYS)
     hits = _warm(app, engine, warm, counters)
     marks["warm-up"] = clock()
     log(f"warm-up: {len(warm)} chunks, {counters.compiles} compiles "
@@ -263,7 +280,6 @@ def run(
     # -- the window's traffic --------------------------------------------------
     arrival = traffic["arrival"]
     backlog = arrival["kind"] == "backlog"
-    churn = traffic.get("churn")
     if backlog:
         n = int(arrival["backlog_events"])
         if n % traffic["max_poll_records"]:
@@ -273,16 +289,10 @@ def run(
     else:
         due = trf.arrivals(rng, arrival, seconds)
         n = due.size
-    evolutions: List[Any] = []
     if churn:
-        from bench.lib import churn as chn
-
-        batch, evolutions = chn.generate(rng, stream, weights, real, churn, due, seconds)
-        inband = [(e.first, e.at, chn.event(e)) for e in evolutions]
+        batch, inband = kind.churn(real, weights, rng, stream, churn, due, seconds)
     else:
-        batch = trf.generate(rng, stream, weights, tables.version_cols(), tables.flat(), n, 0,
-                             real.state)
-        inband = []
+        batch, inband = kind.generate(real, weights, rng, stream, n, 0), []
     marks["traffic"] = clock()
     if churn and cache:
         # a deployment meeting a new registry state has never compiled its
@@ -290,9 +300,9 @@ def run(
         cache_off()
     table = TableSink()
     writes = WriteClock(clock, freeze=True)
-    src = OpenLoopSource(batch, tables.cols, due, max_poll=traffic["max_poll_records"],
+    src = OpenLoopSource(batch, None, due, max_poll=traffic["max_poll_records"],
                          cycle=backlog, key_span=batch.n, clock=clock, annotate=trace,
-                         control=inband)
+                         control=inband, builders=(kind.chunk, kind.event))
     applies: List[Tuple[float, float]] = []
 
     def apply_timed(event) -> None:  # the pipeline's default, timed
@@ -378,8 +388,8 @@ def run(
                 + " ".join(f"{x:.2f}" for x in fifths))
         log(f"backlog at the close: {int(np.searchsorted(due, seconds)) - int(sizes_w[in_win].sum())}"
             f" events due and not yet written")
-    if evolutions:
-        _log_churn(evolutions, src.controls, applies, wt, counters.ends, t0, tw, log)
+    if inband:
+        _log_churn(inband, src.controls, applies, wt, counters.ends, t0, tw, log)
     log(f"window: {seconds} s, {len(polls)} chunks, {n_written} events written "
         f"({int(sizes_w[in_win].sum())} by the close), run ended {t_done - tw:+.3f} s "
         f"after the close, {compiles_window} compiles in the window ({loads_window} "
@@ -391,24 +401,14 @@ def run(
 
     # -- correct: every row against the reference -----------------------------
     t_ref = clock()
-    width = max(tables.n_out)
     passes = [(min(n_written - q * batch.n, batch.n), q * batch.n)
               for q in range(-(-n_written // batch.n))]
-
-    def reference(value_dtype=np.float32):
-        return ref.concat_rows(
-            [ref.expected_rows(batch, tables, 0, take, width, key_shift=shift,
-                               value_dtype=value_dtype) for take, shift in passes]
-            or [ref.empty_rows(width)])
-
-    want = reference()
-    if control == "bf16":
-        import ml_dtypes
-
-        got = reference(ml_dtypes.bfloat16)
+    want = kind.expected(real, batch, passes, "none")
+    if control == "none":
+        got = kind.program_rows(real, table.to_arrays())
     else:
-        got = ref.table_rows(table.to_arrays(), width)
-    check = ref.compare(want, got)
+        got = kind.expected(real, batch, passes, control)
+    check = kind.compare(want, got)
     check["events_unwritten"] = unwritten
     correct = all(v == 0 for v in check.values())
     log(f"reference: {want.n} rows expected, {got.n} rows written, "
@@ -449,7 +449,8 @@ def run(
             out_device["window_s"] = red.window_ns / 1e9
             breakdown = {"device_ops": [[n, s] for n, s in red.top_ops],
                          "idle_gaps": [[n, s] for n, s in red.idle_gaps]}
-        ctx.kernel_bytes = _kernel_bytes(batch, tables, [p for p, ok in zip(polls, in_win) if ok])
+        ctx.kernel_bytes = kind.kernel_bytes(real, batch,
+                                             [p for p, ok in zip(polls, in_win) if ok])
         for m in cell_metrics(bench, cell, "per_layer"):
             v = load_metric(m["name"])(ctx)
             if v is not None:
@@ -469,13 +470,15 @@ def run(
     return result
 
 
-def _log_churn(evolutions, yielded, applies, writes, ends, t0, tw, log) -> None:
-    """Log where each evolution's time went, by the host's clock: the
-    source's yield, the pipeline's drain up to the coordinator's apply, the
-    apply, the next write, and the programs compiled before the next
+def _log_churn(inband, yielded, applies, writes, ends, t0, tw, log) -> None:
+    """Log where each in-band evolution's time went, by the host's clock:
+    the source's yield, the pipeline's drain up to the coordinator's apply,
+    the apply, the next write, and the programs compiled before the next
     evolution's apply (or the run's end)."""
+    from repro.etl import SchemaEvolved
+
     ends, writes = np.asarray(ends), np.asarray(writes)
-    for k, e in enumerate(evolutions):
+    for k, (first, at, item) in enumerate(inband):
         step = "never yielded"
         if k < len(yielded) and k < len(applies):
             (a, done), y = applies[k], yielded[k][1]
@@ -483,37 +486,17 @@ def _log_churn(evolutions, yielded, applies, writes, ends, t0, tw, log) -> None:
             later = writes[writes > done]
             w = later[0] if later.size else np.nan
             c = int(((ends > done) & (ends < nxt)).sum())
-            step = (f"yielded {y - t0:.3f} s into the window (due {e.at:.3f} s), drain "
+            step = (f"yielded {y - t0:.3f} s into the window (due {at:.3f} s), drain "
                     f"{(a - y) * 1e3:.1f} ms, apply {(done - a) * 1e3:.1f} ms, "
                     f"next write {(w - done) * 1e3:.1f} ms after it, {c} compiles before "
                     f"the next evolution's apply")
-        log(f"evolution {k} before event {e.first}: schema {e.schema} keeps {len(e.keep)}, "
-            f"adds {len(e.add)}; {step}")
+        what = (f"schema {item.schema_id} keeps {len(item.keep)}, adds {len(item.add)}"
+                if isinstance(item, SchemaEvolved) else type(item).__name__)
+        log(f"evolution {k} before event {first}: {what}; {step}")
     log("compiles end at (s into the window): " + " ".join(
         f"{c - t0:.2f}" for c in ends if c > t0))
     log(f"evolutions applied by the close: {sum(1 for _, d in applies if d <= tw)} of "
-        f"{len(evolutions)} ({len(applies)} by the run's end)")
-
-
-def _kernel_bytes(batch, tables, polls) -> int:
-    """Bytes the mapping of the polled chunks needs (see kernel_bytes): an
-    event counts where it is its key's first delivery and its column maps
-    somewhere."""
-    from bench.lib.kernel_bytes import chunk_bytes
-
-    first = np.r_[True, batch.key[1:] != batch.key[:-1]]
-    mapped = np.asarray([bool((p >= 0).any()) for p in tables.cdm_pos])[batch.col]
-    sel = first & mapped
-    items = np.diff(batch.offsets)
-    n_out = np.asarray(tables.n_out)
-    total = 0
-    for lo, hi, _ in polls:
-        i, j = lo % batch.n, (hi - 1) % batch.n + 1
-        s = sel[i:j]
-        col = batch.col[i:j][s]
-        total += chunk_bytes(int(items[i:j][s].sum()), int(s.sum()), int(s.sum()),
-                             n_out[np.unique(col)], n_out[col])
-    return total
+        f"{len(inband)} ({len(applies)} by the run's end)")
 
 
 def main(argv: Optional[List[str]] = None) -> int:

@@ -271,6 +271,39 @@ def test_cells_without_churn_generate_what_they_did(cell, small_spec, small_traf
     assert h.hexdigest() == PINNED[cell]
 
 
+# sha256 of the churn cell's arrays at the test seed (warm-up chunks, the
+# window's batch and due times, then each in-band evolution), under either
+# kind of arrival, as the harness made them before each deployment named
+# its kind
+PINNED_CHURN = {
+    "backlog": "93bbf9296f97a812fb82151c5ce0ec49ec6024d425711f8bf050fef20953e725",
+    "open": "878baae425b8e8aae3043c2ed6d47a09a7ce7a8994c10b5cf6a1eef158d7f510",
+}
+
+
+def test_churn_cell_generates_what_it_did(small_spec, churn_traffic, request, monkeypatch):
+    seen = []
+    init = OpenLoopSource.__init__
+
+    def spy(self, batch, cols, due, **kw):
+        seen.append((batch, due, list(kw.get("control", ()))))
+        init(self, batch, cols, due, **kw)
+
+    monkeypatch.setattr(OpenLoopSource, "__init__", spy)
+    res = run_small(CELL, copy.deepcopy(small_spec), churn_traffic)
+    assert res["correct"]
+    h = hashlib.sha256()
+    for b, due, control in seen:
+        for f in ("key", "col", "state", "offsets", "pos", "uid", "val"):
+            h.update(np.ascontiguousarray(getattr(b, f)).tobytes())
+        h.update(np.ascontiguousarray(due).tobytes())
+        for first, at, ev in control:
+            h.update(repr((int(first), float(at), int(ev.schema_id), tuple(ev.keep),
+                           tuple(ev.add))).encode())
+    assert sum(len(c) for _, _, c in seen) >= 2
+    assert h.hexdigest() == PINNED_CHURN[request.node.callspec.params["churn_traffic"]]
+
+
 COUNT = """
 import sys
 sys.path[:0] = [{src!r}, {repo!r}]
