@@ -24,7 +24,9 @@ Every emitted row is compared with ``METLApp.consume_scalar`` (the
 per-event Algorithm-6 oracle) on the same events at the same state: keys
 and float32 value bits must be identical.  Each phase must show one
 dispatch per chunk, one host->device transfer per device-densified chunk,
-and a dispatch program that holds a compiled kernel (``tpu_custom_call``).
+and a dispatch program that holds a compiled kernel (``tpu_custom_call``),
+and the evolution must leave the plan's table shapes as they were
+(``table_shape_changes`` 0), so no chunk program recompiles for it.
 
 ``--chips 4`` runs only the sharded engine (``make_etl_mesh(4)``, host and
 device densify), compared row for row with the fused engine on the same
@@ -285,13 +287,17 @@ def check_phase(res: PhaseResult, want: List[List[Row]]) -> None:
     require(res.info["transfers"] == per_chunk * res.chunks,
             f"{spec.name}: {res.info['transfers']} transfers for {res.chunks} chunks")
     require(res.info["rebuilds"] >= 2, f"{spec.name}: no plan rebuild mid-stream")
+    require(res.info["table_shape_changes"] == 0,
+            f"{spec.name}: the evolution changed the plan's table shapes "
+            f"{res.info['table_shape_changes']} times (every chunk shape recompiles)")
 
 
 def check_kernels(interpret: bool = False, seed: int = 0) -> List[str]:
     """Each mapping kernel against its ``ref.py`` twin, bit for bit, at the
-    EOS plan's shapes (512 events, a 1256 x 128 block table)."""
+    EOS plan's shapes (512 events, a 2048 x 128 block table: 1,249 blocks
+    in their capacity bucket)."""
     rng = np.random.default_rng(seed)
-    b, n_in, w, n_blocks, s, k = 512, 128, 128, 1256, 512, 16
+    b, n_in, w, n_blocks, s, k = 512, 128, 128, bucket_rows(1249), 512, 16
     vals = rng.integers(1, 1_000_000, size=(b, n_in)).astype(np.float32)
     mask = (rng.random((b, n_in)) < 0.7).astype(np.int8)
     src2d = np.full((n_blocks, w), -1, np.int32)
@@ -342,6 +348,9 @@ def _report(res: PhaseResult, *extra: str) -> None:
         f"phase {res.spec.name}: events={res.events} chunks={res.chunks} "
         f"rows={n_rows} dispatches={res.info['dispatches']} "
         f"readbacks_early={res.info['readbacks_early']} "
+        f"table_shape_changes={res.info['table_shape_changes']} "
+        f"uid_capacity={res.info['uid_capacity']} "
+        f"block_capacity={res.info['block_capacity']} "
         f"transfers={res.info['transfers']} rebuilds={res.info['rebuilds']} "
         f"first_call_s={res.first_s:.3f} wall_s={res.wall_s:.3f} "
         f"oracle=identical kernel=tpu_custom_call {' '.join(extra)}".rstrip(),

@@ -35,16 +35,24 @@ chunk maps in ONE device dispatch (:func:`repro.kernels.ops.dmm_apply_fused`
 over :mod:`repro.kernels.segmented_gather`):
 
     src2d      (n_blocks_pad, W) int32   all block index vectors, stacked in
-               column order and right-padded with -1 to W = max(n_out_pad)
+               column order and right-padded with -1 to W = max(n_out_pad);
+               n_blocks_pad = bucket_rows(n_blocks), rows past n_blocks -1
     routes     block t emits to business entity routes[t] = (r, w)
     n_out      true (unpadded) output width per block
     columns    (o, v) -> FusedColumn: the column super-set iDCPM_v^o as
                global block ids plus the uid -> payload-slot lookup used for
                vectorised densification
+    uid_slot / uid_col  (bucket_rows(max_uid + 1),) int32 uid tables, -1
+               past max_uid
 
 Batch-shape bucketing (:func:`bucket_rows`, powers of two) keeps the set of
-operand shapes small so the jit cache is effectively keyed on (state,
-bucketed batch shape) and steady-state consume chunks never retrace.
+operand shapes small so steady-state consume chunks never retrace.  The
+plan's tables are sized by the same rule: a schema evolution adds a few
+fresh uids and blocks, which almost always stay inside the current
+capacity, so the new state's tables have the old state's shapes and every
+compiled chunk program is reused -- a new state costs a table upload, not
+a compile.  The padding is -1, which every lookup already reads as "no
+such uid / block".
 
 For CDMs too wide for one device, :class:`ShardedFusedDMM` (built by
 :func:`compile_fused_sharded`) partitions the flattened block table over the
@@ -53,7 +61,8 @@ blocks_per_shard``, and the stacked per-shard tables form
 
     src3d      (n_shards, n_blocks_pad_loc, W) int32, leading axis placed
                over the mesh ``data`` axis (NamedSharding), so each device
-               holds only its (1, n_blocks_pad_loc, W) slice
+               holds only its (1, n_blocks_pad_loc, W) slice;
+               n_blocks_pad_loc = bucket_rows(blocks_per_shard)
 
 executed per shard under ``shard_map``
 (:func:`repro.kernels.ops.dmm_apply_sharded`) -- still one dispatch per
@@ -125,7 +134,9 @@ def bucket_rows(n: int, floor: int = SUBLANE) -> int:
 
     The fused engine pads every per-chunk operand to a bucketed shape so a
     steady stream of slightly-varying chunk sizes hits a handful of jit-cache
-    entries instead of retracing per chunk.
+    entries instead of retracing per chunk.  The plan's uid tables and block
+    table take the same rule (their capacity), so they keep their shapes
+    across registry states until a table outgrows its bucket.
     """
     if n <= floor:
         return floor
@@ -298,20 +309,21 @@ class FusedDMM:
     n_in_pad: int  # uniform dense-payload width (lane multiple)
     width: int  # W: uniform output width = max n_out_pad (lane multiple)
     n_blocks: int  # true block count (src2d rows beyond this are -1 pad)
-    src2d: jax.Array  # int32 (n_blocks_pad, W), device-resident
+    src2d: jax.Array  # int32 (bucket_rows(n_blocks), W), device-resident
     routes: List[Tuple[int, int]]  # block t -> business entity (r, w)
     n_out: np.ndarray  # int32 (n_blocks,): true output width per block
     columns: Dict[Tuple[int, int], FusedColumn]
-    uid_slot: np.ndarray  # int32 (max_uid+1,): uid -> payload slot, -1 = none
-    uid_col: np.ndarray  # int32 (max_uid+1,): uid -> owning col_id, -1 = none
+    uid_slot: np.ndarray  # int32 (bucket_rows(max_uid+1),): uid -> payload slot, -1 = none
+    uid_col: np.ndarray  # int32 (bucket_rows(max_uid+1),): uid -> owning col_id, -1 = none
     # column col_id owns the contiguous global block range
     # [col_block_start[c], col_block_start[c] + col_block_count[c]) -- block
     # ids are assigned in column order, so per-column routing vectorises to
     # two repeats instead of a per-column python loop
     col_block_start: np.ndarray = None  # int32 (n_cols,)
     col_block_count: np.ndarray = None  # int32 (n_cols,)
-    # device-resident copies of the uid tables (uploaded once per state) for
-    # the device-densify path (repro.kernels.ops.dmm_apply_columnar)
+    # device-resident copies of the uid tables (uploaded once per state, same
+    # shapes across states while the capacity holds) for the device-densify
+    # path (repro.kernels.ops.dmm_apply_columnar)
     uid_slot_dev: Optional[jax.Array] = None
     uid_col_dev: Optional[jax.Array] = None
 
@@ -321,11 +333,13 @@ class FusedDMM:
 
 def _uid_tables_from(cols) -> Tuple[np.ndarray, np.ndarray]:
     """Dense global (uid -> payload slot, uid -> owning col_id) tables from
-    ``(uid_pos dict, col_id)`` pairs; -1 marks uids no column knows."""
+    ``(uid_pos dict, col_id)`` pairs; -1 marks uids no column knows.  The
+    tables hold ``bucket_rows(max_uid + 1)`` entries, so the fresh uids of
+    a schema evolution usually fit and the shapes stay put."""
     cols = list(cols)
     max_uid = max((int(u) for pos, _ in cols for u in pos), default=-1)
-    uid_slot = np.full(max_uid + 1, -1, dtype=np.int32)
-    uid_col = np.full(max_uid + 1, -1, dtype=np.int32)
+    uid_slot = np.full(bucket_rows(max_uid + 1), -1, dtype=np.int32)
+    uid_col = np.full(uid_slot.size, -1, dtype=np.int32)
     for pos, cid in cols:
         for u, k in pos.items():
             uid_slot[u] = k
@@ -397,8 +411,7 @@ def _fused_tables(
         (col.uid_pos, col.col_id) for col in columns.values()
     )
     n_blocks = len(routes)
-    n_blocks_pad = max(SUBLANE, -(-max(n_blocks, 1) // SUBLANE) * SUBLANE)
-    table = np.full((n_blocks_pad, width), -1, dtype=np.int32)
+    table = np.full((bucket_rows(n_blocks), width), -1, dtype=np.int32)
     if src_rows:
         table[:n_blocks] = np.stack(src_rows)
     n_out_arr = np.asarray(n_out, dtype=np.int32)
@@ -492,8 +505,8 @@ class ShardedFusedDMM:
     routes: List[Tuple[int, int]]  # global block t -> business entity (r, w)
     n_out: np.ndarray  # int32 (n_blocks,) true output width per block
     columns: Dict[Tuple[int, int], FusedColumn]
-    uid_slot: np.ndarray  # int32 (max_uid+1,): uid -> payload slot, -1 = none
-    uid_col: np.ndarray  # int32 (max_uid+1,): uid -> owning col_id, -1 = none
+    uid_slot: np.ndarray  # int32 (bucket_rows(max_uid+1),): uid -> payload slot, -1 = none
+    uid_col: np.ndarray  # int32 (bucket_rows(max_uid+1),): uid -> owning col_id, -1 = none
     col_block_start: np.ndarray = None  # int32 (n_cols,): see FusedDMM
     col_block_count: np.ndarray = None  # int32 (n_cols,)
     uid_slot_dev: Optional[jax.Array] = None  # device copies (once per state)
@@ -569,7 +582,7 @@ def _assemble_sharded(
     (table, routes, n_out, columns, n_in_pad, width, n_blocks, uid_slot,
      uid_col, cb_start, cb_count) = parts
     per = -(-max(n_blocks, 1) // n_shards)
-    per_pad = max(SUBLANE, -(-per // SUBLANE) * SUBLANE)
+    per_pad = bucket_rows(per)
     src3d_np = np.full((n_shards, per_pad, width), -1, dtype=np.int32)
     for s in range(n_shards):
         lo, hi = s * per, min((s + 1) * per, n_blocks)
@@ -654,11 +667,12 @@ def _vectorised_uid_tables(columns) -> Tuple[np.ndarray, np.ndarray]:
     uid is claimed by two columns and scatter order cannot matter."""
     cols = [c for c in columns if c.uids_arr is not None and c.uids_arr.size]
     if not cols:
-        return np.empty(0, dtype=np.int32), np.empty(0, dtype=np.int32)
+        empty = np.full(bucket_rows(0), -1, dtype=np.int32)
+        return empty, empty.copy()
     all_uids = np.concatenate([c.uids_arr for c in cols])
     sizes = np.asarray([c.uids_arr.size for c in cols], dtype=np.int64)
     col_ids = np.asarray([c.col_id for c in cols], dtype=np.int32)
-    uid_slot = np.full(int(all_uids.max()) + 1, -1, dtype=np.int32)
+    uid_slot = np.full(bucket_rows(int(all_uids.max()) + 1), -1, dtype=np.int32)
     uid_col = np.full(uid_slot.size, -1, dtype=np.int32)
     starts = np.repeat(np.cumsum(sizes) - sizes, sizes)
     uid_slot[all_uids] = (
@@ -738,8 +752,7 @@ def _spliced_tables(old, compiled: CompiledDMM, registry: Registry, touched, lan
             uids_arr=uids_arr,
         )
     n_blocks = len(routes)
-    n_blocks_pad = max(SUBLANE, -(-max(n_blocks, 1) // SUBLANE) * SUBLANE)
-    table = np.full((n_blocks_pad, width), -1, dtype=np.int32)
+    table = np.full((bucket_rows(n_blocks), width), -1, dtype=np.int32)
     if reuse_new:
         new_ids = np.concatenate([
             np.arange(s, s + ids.size, dtype=np.int64)

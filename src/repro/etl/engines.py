@@ -823,10 +823,11 @@ class MappingEngine:
     def _manager_info(self) -> Dict[str, Any]:
         """The manager-derived keys every engine's ``info()`` carries."""
         if self.manager is None:
-            m = {"plan_epoch": 0, "rebuilds": 0}
+            m = {"plan_epoch": 0, "rebuilds": 0, "table_shape_changes": 0}
         else:
             mi = self.manager.info()
-            m = {"plan_epoch": mi["plan_epoch"], "rebuilds": mi["rebuilds"]}
+            keys = ("plan_epoch", "rebuilds", "table_shape_changes")
+            m = {k: mi[k] for k in keys}
         # replication surface: prefer the manager's own coordinator, fall
         # back to the app-level observability binding; a bare engine with
         # neither reports "unbound" (explicitly NOT a leader claim)
@@ -885,6 +886,10 @@ class MappingEngine:
                           serve one state ``i``)
           ``rebuilds``    cumulative plan builds through the manager
                           (incremental splices + full rebuilds)
+          ``table_shape_changes``  installed plans whose device tables
+                          changed shape from the previous plan's (each
+                          recompiles every chunk shape; 0 while schema
+                          evolutions fit the tables' capacity)
           ``role``        control-plane role of the bound coordinator:
                           ``"leader"`` (any unreplicated or leader-bound
                           coordinator), ``"follower"`` (a replica fed by
@@ -910,6 +915,11 @@ class MappingEngine:
                                     residency policy: cold columns stay
                                     compacted-out and don't count)
           ``width``                 padded block-table row width (fused/
+                                    sharded only)
+          ``uid_capacity``          uid-table length, a capacity bucket
+                                    (fused/sharded only)
+          ``block_capacity``        block-table rows, a capacity bucket
+                                    (per shard times shards; fused/
                                     sharded only)
 
         ``Cluster.info()`` (:mod:`repro.etl.cluster`) aggregates these per
@@ -1155,6 +1165,8 @@ class FusedEngine(MappingEngine):
                 n_blocks=p.n_blocks,
                 blocks_per_shard=p.n_blocks,
                 width=p.width,
+                uid_capacity=int(p.uid_slot.size),
+                block_capacity=int(p.src2d.shape[0]),
                 table_bytes=table_bytes,
                 table_bytes_per_shard=table_bytes,
                 bytes_resident=(
@@ -1316,6 +1328,8 @@ class ShardedEngine(MappingEngine):
                 n_blocks=p.n_blocks,
                 blocks_per_shard=p.blocks_per_shard,
                 width=p.width,
+                uid_capacity=int(p.uid_slot.size),
+                block_capacity=p.n_shards * p.n_blocks_pad_loc,
                 table_bytes=table_bytes,
                 table_bytes_per_shard=p.table_bytes_per_shard,
                 bytes_resident=(

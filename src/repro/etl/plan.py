@@ -170,6 +170,17 @@ def _resident_compiled(
     )
 
 
+def _table_shapes(plan: Any) -> Optional[Tuple]:
+    """``(uid table shape, block table shape)`` of a fused or sharded plan:
+    the shapes the chunk programs are compiled for.  None for the per-block
+    engine's plan, which has no such tables."""
+    if isinstance(plan, FusedDMM):
+        return plan.uid_slot.shape, plan.src2d.shape
+    if isinstance(plan, ShardedFusedDMM):
+        return plan.uid_slot.shape, plan.src3d.shape
+    return None
+
+
 def _bytes_resident(kind: str, plan: Any) -> int:
     """Device-resident block-table bytes of one plan."""
     if kind == "sharded":
@@ -222,6 +233,10 @@ class PlanManager:
         self.incremental_rebuilds = 0
         self.last_rebuild_s = 0.0
         self.total_rebuild_s = 0.0
+        # device-table shapes of the last installed lease, and how often an
+        # install changed them (each change recompiles every chunk shape)
+        self._table_shapes: Optional[Tuple] = None
+        self.table_shape_changes = 0
         self._pool: Optional[ThreadPoolExecutor] = None
         self._prepared: Optional[Future] = None
         if background:
@@ -290,8 +305,12 @@ class PlanManager:
     # -- observability -------------------------------------------------------
     def info(self) -> Dict[str, Any]:
         """Manager-side keys merged into ``engine.info()``: ``plan_epoch``,
-        ``rebuilds``, ``bytes_resident``, plus rebuild-cost and tiering
-        detail."""
+        ``rebuilds``, ``table_shape_changes``, ``bytes_resident``, plus
+        rebuild-cost and tiering detail.
+
+        ``table_shape_changes`` counts installed leases whose device tables
+        (uid tables, block table) changed shape from the previous lease's:
+        each one recompiles every chunk shape."""
         with self._lock:
             lease = self._lease
             d: Dict[str, Any] = {
@@ -300,6 +319,7 @@ class PlanManager:
                 "incremental_rebuilds": self.incremental_rebuilds,
                 "last_rebuild_s": self.last_rebuild_s,
                 "total_rebuild_s": self.total_rebuild_s,
+                "table_shape_changes": self.table_shape_changes,
             }
             if lease is not None:
                 d["bytes_resident"] = lease.bytes_resident
@@ -340,6 +360,10 @@ class PlanManager:
             self.incremental_rebuilds += 1
         self.last_rebuild_s = lease.rebuild_s
         self.total_rebuild_s += lease.rebuild_s
+        shapes = _table_shapes(lease.plan)
+        if self._table_shapes is not None and shapes != self._table_shapes:
+            self.table_shape_changes += 1
+        self._table_shapes = shapes
         if self.publish and self.coordinator.is_control_writer:
             # the coordinator's single-writer apply logs the publication;
             # "plan" events bump nothing, so no eviction re-entrancy.  On a

@@ -123,8 +123,9 @@ def dmm_apply_fused(
       "auto"   Pallas kernel on TPU, oracle elsewhere
 
     The jit cache is keyed by operand shapes: (bucketed S, bucketed B,
-    n_in_pad) per chunk plus the state's (n_blocks_pad, W) table shape, so
-    steady-state consume traffic never retraces.
+    n_in_pad) per chunk plus the state's (n_blocks_pad, W) table shape,
+    bucketed too, so steady-state consume traffic never retraces and a new
+    state whose table fits the same bucket reuses the program.
 
     The returned ``(out_values, out_mask)`` are unblocked dispatch handles
     (async-dispatch futures); the caller's first host read is the sync
@@ -268,16 +269,11 @@ def _resolve_items(
     ix = jnp.where(item_valid, starts[:, None] + kk[None, :], 0)
     iu = jnp.take(uids, ix.reshape(-1), mode="clip").reshape(b, k)
     iv = jnp.take(vals, ix.reshape(-1), mode="clip").reshape(b, k)
-    nu = uid_slot.shape[0]
-    if nu == 0:
-        keep = jnp.zeros_like(item_valid)
-        slot = jnp.full((b, k), -1, jnp.int32)
-    else:
-        uid_ok = (iu >= 0) & (iu < nu)
-        su = jnp.where(uid_ok, iu, 0)
-        slot = jnp.take(uid_slot, su.reshape(-1), mode="clip").reshape(b, k)
-        owner = jnp.take(uid_col, su.reshape(-1), mode="clip").reshape(b, k)
-        keep = item_valid & uid_ok & (slot >= 0) & (owner == ev_col[:, None])
+    uid_ok = (iu >= 0) & (iu < uid_slot.shape[0])
+    su = jnp.where(uid_ok, iu, 0)
+    slot = jnp.take(uid_slot, su.reshape(-1), mode="clip").reshape(b, k)
+    owner = jnp.take(uid_col, su.reshape(-1), mode="clip").reshape(b, k)
+    keep = item_valid & uid_ok & (slot >= 0) & (owner == ev_col[:, None])
     slot2d = jnp.where(keep, slot, jnp.int32(-1))
     x2d = jnp.where(keep, iv, jnp.float32(0))
     return slot2d, x2d
@@ -337,9 +333,11 @@ def dmm_apply_columnar(
     above; ``n_items``/``n_events``/``n_rows``/``k`` are its bucketed
     section sizes, static per jit-cache entry); ``uid_slot``/``uid_col``/
     ``src2d`` are the plan's device-resident tables, uploaded once per
-    state.  Returns ((n_rows, W) values, (n_rows, W) int8 mask) as
-    unblocked dispatch handles -- rows past the true routing length are
-    garbage the caller slices off, exactly as in
+    state and sized to capacity buckets (:func:`repro.core.dmm_jax.
+    bucket_rows`), so successive states pass the same shapes and reuse
+    this program's compiled entries.  Returns ((n_rows, W) values,
+    (n_rows, W) int8 mask) as unblocked dispatch handles -- rows past the
+    true routing length are garbage the caller slices off, exactly as in
     :func:`dmm_apply_fused`.
 
     impl: "fused" (Pallas densify_map kernel) | "ref" (scatter-free jnp

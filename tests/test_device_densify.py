@@ -13,7 +13,10 @@ Covers the acceptance surface:
     transition (live schema evolution mid-stream) stay bit-exact;
   * out-of-range uid regression: never an index error, clamped out of the
     scatter, counted under stats["unknown_uid"] IDENTICALLY across the
-    blocks / fused / fused+device engines;
+    blocks / fused / fused+device engines, for a uid in the uid tables'
+    capacity padding as for one past their end;
+  * one program across registry states: ten schema evolutions keep the
+    plan's table shapes, so the columnar program never recompiles;
   * accounting: the device path makes exactly 1 host->device transfer and
     1 dispatch per chunk (host path: 4 transfers); small chunks fall back
     to the host scatter below min_device_events;
@@ -308,6 +311,111 @@ def test_out_of_range_uid_never_crashes_and_is_counted(world):
     assert stats["blocks"] == stats["fused"] == stats["device"]
     _assert_rows_equal(rows["fused"], rows["device"])
     _assert_rows_equal(rows["fused"], rows["blocks"])
+
+
+@pytest.mark.parametrize("where", ["in_padding", "past_capacity"])
+def test_uid_outside_the_plan_is_dropped_and_counted(world, where):
+    """The uid tables run past the largest known uid to their capacity
+    bucket.  A uid in that padding is dropped and counted under
+    ``stats["unknown_uid"]`` exactly like a uid past the table's end, on
+    every engine: blocks, host scatter, device densify and the device
+    app's small-chunk host fallback."""
+    sc, coord = world
+    reg = sc.registry
+    o = reg.domain.schema_ids()[0]
+    v = reg.domain.latest_version(o)
+    uids = reg.domain.get(o, v).uids
+    s = reg.state
+    probe = METLApp(coord, engine="fused")
+    probe.ensure_ready()
+    plan = probe.engine.plan
+    known = int(np.flatnonzero(plan.uid_col >= 0).max()) + 1
+    cap = plan.uid_slot.size
+    assert known < cap and np.all(plan.uid_slot[known:] == -1)
+    assert np.all(plan.uid_col[known:] == -1)
+    stray = known if where == "in_padding" else cap
+    clean = [_mk_event(1, o, v, {uids[0]: 2.0, uids[1]: 3.0}, s),
+             _mk_event(2, o, v, {uids[2]: 4.0}, s)]
+    dirty = [_mk_event(1, o, v, {uids[0]: 2.0, stray: 9.0, uids[1]: 3.0}, s),
+             _mk_event(2, o, v, {stray: 1.0, uids[2]: 4.0}, s)]
+    for name, make in (
+        ("blocks", lambda: METLApp(coord, engine="blocks")),
+        ("fused", lambda: METLApp(coord, engine="fused")),
+        ("device", lambda: _device_app(coord)),
+        ("fallback", lambda: _device_app(coord, min_device_events=32)),
+    ):
+        want, got = make(), make()
+        rows_want = want.consume(columnarize(clean))
+        t0 = got.stats["transfers"]
+        rows_got = got.consume(columnarize(dirty))
+        assert rows_want, name
+        _assert_rows_equal(rows_want, rows_got)
+        if name in ("device", "fallback"):  # the path the name promises
+            assert got.stats["transfers"] - t0 == (1 if name == "device" else 4)
+        assert (want.stats["unknown_uid"], got.stats["unknown_uid"]) == (0, 2), name
+
+
+def test_one_program_across_registry_states():
+    """Ten in-band schema evolutions, each adding fresh uids and a block,
+    leave the plan's device tables at one shape: the same-shaped chunk
+    after each reuses the columnar program compiled before the first, and
+    its rows equal the host scatter's at every state."""
+    from repro.core.dmm_jax import bucket_rows
+    from repro.etl.control import SchemaEvolved
+    from repro.kernels import ops
+
+    sc = build_scenario(ScenarioConfig(n_schemas=12, versions_per_schema=3, seed=74))
+    coord = StateCoordinator(sc.registry, sc.dpm)
+    reg = sc.registry
+    dev = _device_app(coord)
+    host = METLApp(coord, engine="fused")
+    o = reg.domain.schema_ids()[0]  # never evolved: its events keep one shape
+    v = reg.domain.latest_version(o)
+    uids = reg.domain.get(o, v).uids
+    program = ops._columnar_program("ref", 0.0, False)  # what dev runs on CPU
+
+    def consume(step):
+        evs = [
+            _mk_event(1000 * step + i, o, v,
+                      {u: float(1000 * step + 10 * i + j) for j, u in enumerate(uids)},
+                      reg.state)
+            for i in range(40)
+        ]
+        d0 = dev.stats["dispatches"]
+        rows = dev.consume(columnarize(evs))
+        assert dev.stats["dispatches"] - d0 == 1
+        assert dev.stats["transfers"] == dev.stats["dispatches"]  # device path
+        assert rows
+        _assert_rows_equal(host.consume(columnarize(evs)), rows)
+
+    def tables():
+        p = dev.engine.plan
+        return p.uid_slot_dev.shape, p.uid_col_dev.shape, p.src2d.shape
+
+    def needed():
+        p = dev.engine.plan
+        return int(np.flatnonzero(p.uid_col >= 0).max()) + 1, p.n_blocks
+
+    consume(0)
+    shapes, cache, first = tables(), program._cache_size(), needed()
+    assert shapes[0] == (bucket_rows(first[0]),)
+    assert shapes[2][0] == bucket_rows(first[1])
+    for step in range(1, 11):
+        schema = reg.domain.schema_ids()[step]
+        latest = reg.domain.latest_version(schema)
+        keep = tuple(a.name for a in reg.domain.get(schema, latest).attributes)[1:]
+        coord.apply(SchemaEvolved(tree="domain", schema_id=schema, keep=keep,
+                                  add=(f"fresh{step}",)))
+        consume(step)
+        assert dev.engine.plan.state == reg.state
+        assert tables() == shapes, step
+        assert program._cache_size() == cache, step
+    last = needed()
+    # exact-length tables would have changed shape at every evolution
+    assert last[0] > first[0] and last[1] == first[1] + 10
+    info = dev.engine.info()
+    assert info["table_shape_changes"] == 0 and info["rebuilds"] == 11
+    assert (info["uid_capacity"], info["block_capacity"]) == (shapes[0][0], shapes[2][0])
 
 
 # ---------------------------------------------------------------------------

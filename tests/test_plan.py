@@ -20,6 +20,9 @@ Covers the acceptance surface of the plan-lifecycle tentpole:
     log, ``replay_control_log`` reproduces registry/state/DPM bit-exactly
     across them, and an in-flight epoch-pinned chunk drains on the OLD
     table with rows equal to the sync oracle;
+  * the plan's device tables are sized to capacity buckets: splice and
+    full rebuild agree padding included, the shapes hold across ten
+    evolutions, and ``table_shape_changes`` counts a bucket crossing;
   * satellite: the documented ``engine.info()`` / ``Cluster.info()`` key
     lists match what the engines actually return.
 """
@@ -225,6 +228,66 @@ class TestPlanManager:
                 compile_dpm(snap.dpm, coord.registry), coord.registry
             )
             _assert_plans_equal(lease.plan, oracle)
+
+
+@pytest.mark.parametrize("kind", ["fused", "sharded"])
+def test_padded_tables_splice_equals_full_rebuild(kind):
+    """Ten evolutions through an incremental and a full-rebuild manager:
+    the spliced tables equal the rebuilt ones, padding included, and every
+    table is its capacity bucket long with -1 past what is needed, so the
+    shapes hold across the ten states."""
+    from repro.core.dmm_jax import bucket_rows
+
+    sc = build_scenario(ScenarioConfig(n_schemas=12, versions_per_schema=3, seed=75))
+    coord = StateCoordinator(sc.registry, sc.dpm)
+    shards = 4 if kind == "sharded" else None
+    inc = PlanManager(kind=kind, n_shards=shards)
+    full = PlanManager(kind=kind, n_shards=shards, incremental=False)
+    shapes = set()
+    for step in range(11):
+        if step:
+            ev, _, _ = _evolve_event(coord.registry, step, f"p{step}")
+            coord.apply(ev)
+        a = inc.acquire(coord.snapshot(), coord.registry)
+        b = full.acquire(coord.snapshot(), coord.registry)
+        assert a.incremental == bool(step) and not b.incremental
+        _assert_plans_equal(a.plan, b.plan)
+        p = a.plan
+        known = int(np.flatnonzero(p.uid_col >= 0).max()) + 1
+        assert p.uid_slot.size == p.uid_col.size == bucket_rows(known)
+        assert np.all(p.uid_slot[known:] == -1) and np.all(p.uid_col[known:] == -1)
+        if kind == "sharded":
+            table = p.src3d
+            assert table.shape[1] == bucket_rows(p.blocks_per_shard)
+            rows = np.asarray(table)[:, p.blocks_per_shard:]
+        else:
+            table = p.src2d
+            assert table.shape[0] == bucket_rows(p.n_blocks)
+            rows = np.asarray(table)[p.n_blocks:]
+        assert np.all(rows == -1)
+        shapes.add((p.uid_slot.shape, table.shape))
+    assert len(shapes) == 1
+    assert inc.info()["table_shape_changes"] == full.info()["table_shape_changes"] == 0
+
+
+def test_table_shape_changes_counts_a_capacity_crossing():
+    """An evolution that takes the block table past its capacity bucket
+    changes the table's shape, and the manager counts it."""
+    sc, coord = _world(seed=71)
+    mgr = PlanManager(kind="fused")
+    lease = mgr.acquire(coord.snapshot(), coord.registry)
+    n = lease.plan.n_blocks
+    assert mgr.info()["table_shape_changes"] == 0
+    assert lease.plan.src2d.shape[0] == n  # a full bucket
+    ev, _, _ = _evolve_event(coord.registry, 0, "grow")
+    coord.apply(ev)
+    lease = mgr.acquire(coord.snapshot(), coord.registry)
+    assert lease.plan.n_blocks > n
+    assert mgr.info()["table_shape_changes"] == 1
+    assert lease.plan.src2d.shape[0] == 2 * n
+    # a same-state rebuild keeps the shapes: nothing more to count
+    mgr.repartition(coord.snapshot(), coord.registry)
+    assert mgr.info()["table_shape_changes"] == 1
 
 
 # ---------------------------------------------------------------------------
@@ -586,12 +649,13 @@ REPLICATION_KEYS = {"role", "term", "log_offset", "lag_records"}
 FUSED_ALWAYS = {
     "engine", "impl", "n_shards", "device_densify", "dispatches",
     "readbacks_early", "transfers", "plan_epoch", "rebuilds",
+    "table_shape_changes",
 } | REPLICATION_KEYS
 BLOCKS_ALWAYS = {"engine", "impl", "n_shards", "dispatches", "plan_epoch",
-                 "rebuilds"} | REPLICATION_KEYS
+                 "rebuilds", "table_shape_changes"} | REPLICATION_KEYS
 PLAN_KEYS = {"state", "n_blocks", "blocks_per_shard", "table_bytes",
              "table_bytes_per_shard", "bytes_resident"}
-FUSED_PLAN_KEYS = PLAN_KEYS | {"width"}
+FUSED_PLAN_KEYS = PLAN_KEYS | {"width", "uid_capacity", "block_capacity"}
 CLUSTER_KEYS = {
     "instances", "engine", "state", "states", "control_log", "dispatches",
     "events", "mapped", "dead_letter", "plan_epoch", "rebuilds",

@@ -5,7 +5,9 @@ for a topology that is described and not attached, and refuses what Mosaic
 would refuse on the chip (vector loads from SMEM, row gathers it cannot
 lower, more scoped VMEM than a core has).  Interpret-mode tests cannot see
 any of that.  Shapes are those of the 125-schema x 10-version EOS scenario
-(a 1256 x 128 block table, 11,228 uids) at 64-, 512- and 4096-event chunks.
+(1,249 blocks and 11,228 uids, in tables sized to their capacity buckets:
+a 2048 x 128 block table and 16,384 uids) at 64-, 512- and 4096-event
+chunks.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and a module that decided at import
@@ -30,7 +32,8 @@ from repro.kernels import ops
 from repro.kernels.masked_gather import masked_gather
 from repro.kernels.segmented_gather import segmented_gather
 
-N_BLOCKS_PAD, WIDTH, N_IN_PAD, N_UIDS, K = 1256, 128, 128, 11228, 16
+N_BLOCKS, WIDTH, N_IN_PAD, K = 1249, 128, 128, 16
+N_BLOCKS_PAD, N_UIDS = bucket_rows(N_BLOCKS), bucket_rows(11228)
 CHUNKS = [64, 512, 4096]
 
 
@@ -134,7 +137,7 @@ def test_sharded_columnar_program_compiles(topo, no_cache, kernels_compiled, eve
     ni, b, _, _ = _columnar_shapes(events)
     s_loc = bucket_rows(events // 4)
     total = 2 * ni + 3 * b + 2 * 4 * s_loc
-    per_pad = -(-N_BLOCKS_PAD // 4 // 8) * 8
+    per_pad = bucket_rows(-(-N_BLOCKS // 4))
     rep = NamedSharding(mesh, P())
     program = ops._columnar_sharded_program.__wrapped__(mesh, "data", "fused", 0.0, True)
     compiled = program.lower(
