@@ -6,20 +6,18 @@ Two halves:
    extraction attributes, ~1k CDM attributes, 10 versions per schema) and
    the Figure-5 worked example (30 -> 7 balanced, 30 -> 5+1 aggressive).
 
-2. **Production-scale soak** — an A/B/C run of the epoched plan lifecycle
+2. **Production-scale soak** — an A/B run of the epoched plan lifecycle
    (``repro.etl.plan.PlanManager``) at ``soak_config()`` scale (80 schemas
    x 6 versions ~= 480 live version columns; a 16x3 miniature under
    ``--smoke``) under continuous schema churn:
 
    * arm A: incremental recompaction (``recompile_columns`` + splice),
-   * arm B: full rebuild on every evolution (the bit-exactness oracle),
-   * arm C: incremental + hot/cold tiering pinned to latest versions only.
+   * arm B: full rebuild on every evolution (the bit-exactness oracle).
 
    Gates (GATE_FAILURES, fail the harness): A and B emit identical row
-   keys in order (zero dropped/duplicated rows across every cutover), C
-   matches A up to row order, C's device-resident bytes are strictly
-   below A's, and — full size only — A's amortised churn rebuild time and
-   p99 chunk latency beat/track B's.  Throughputs, the amortised rebuild
+   keys in order (zero dropped/duplicated rows across every cutover), and
+   — full size only — A's amortised churn rebuild time and p99 chunk
+   latency beat/track B's.  Throughputs, the amortised rebuild
    rate and the compaction ratio land in PERF_METRICS so
    ``scripts/perf_diff.py`` tracks them across trajectory artifacts.
 
@@ -48,7 +46,7 @@ from repro.core.synthetic import (
     churn_schedule,
     soak_config,
 )
-from repro.etl import EventSource, METLApp, PlanManager, TieringPolicy
+from repro.etl import EventSource, METLApp, PlanManager
 
 # harness contract (benchmarks/run.py): gates fail the run, perf metrics
 # feed scripts/perf_diff.py across BENCH_*.json artifacts
@@ -119,15 +117,12 @@ def _soak_arm(
     churn: int,
     every: int,
     incremental: bool = True,
-    tiering: TieringPolicy = None,
 ) -> dict:
     """One soak arm: fresh world, PlanManager-served fused engine, timed
     per-chunk consume with schema churn applied at chunk boundaries."""
     sc = build_scenario(cfg)
     coord = StateCoordinator(sc.registry, sc.dpm)
-    mgr = PlanManager(
-        kind="fused", coordinator=coord, incremental=incremental, tiering=tiering
-    )
+    mgr = PlanManager(kind="fused", coordinator=coord, incremental=incremental)
     app = METLApp(coord, plan_manager=mgr)  # builds + serves epoch 1
     t_first = mgr.info()["total_rebuild_s"]
     # identical schedule content across arms: same fresh registry, same seed
@@ -147,7 +142,7 @@ def _soak_arm(
         keys.extend(r[3] for r in rows)
     total_s = time.perf_counter() - t0
     minfo = mgr.info()
-    out = {
+    return {
         "keys": keys,
         "events_per_s": (n_chunks * size) / total_s,
         "mean_ms": float(np.mean(lat) * 1e3),
@@ -155,11 +150,7 @@ def _soak_arm(
         "first_build_ms": t_first * 1e3,
         "churn_rebuild_ms": (minfo["total_rebuild_s"] - t_first) * 1e3,
         "minfo": minfo,
-        "einfo": app.engine.info(),
-        "tier_misses": int(app.stats["tier_misses"]),
     }
-    mgr.close()
-    return out
 
 
 def _soak_rows(smoke: bool) -> list:
@@ -172,11 +163,6 @@ def _soak_rows(smoke: bool) -> list:
         cfg, n_chunks=n_chunks, size=size, churn=churn, every=every,
         incremental=False,
     )
-    tier = _soak_arm(
-        cfg, n_chunks=n_chunks, size=size, churn=churn, every=every,
-        incremental=True,
-        tiering=TieringPolicy(min_hits=10**9, pin_latest=True),
-    )
 
     # -- correctness gates (always) ------------------------------------------
     if not inc["keys"]:
@@ -185,11 +171,6 @@ def _soak_rows(smoke: bool) -> list:
         GATE_FAILURES.append(
             f"incremental soak dropped/duplicated/reordered rows vs the "
             f"full-rebuild oracle ({len(inc['keys'])} vs {len(full['keys'])} keys)"
-        )
-    if sorted(tier["keys"]) != sorted(inc["keys"]):
-        GATE_FAILURES.append(
-            f"tiered soak lost rows vs the all-hot plan "
-            f"({len(tier['keys'])} vs {len(inc['keys'])} keys)"
         )
     if inc["minfo"]["incremental_rebuilds"] != churn:
         GATE_FAILURES.append(
@@ -202,16 +183,6 @@ def _soak_rows(smoke: bool) -> list:
             "full-rebuild oracle arm took the incremental path "
             f"({full['minfo']['incremental_rebuilds']} times)"
         )
-    # -- residency gates (deterministic: only latest versions stay hot) -----
-    if tier["minfo"]["cold_columns"] == 0:
-        GATE_FAILURES.append("tiering policy kept every column resident")
-    if tier["einfo"]["bytes_resident"] >= inc["einfo"]["bytes_resident"]:
-        GATE_FAILURES.append(
-            f"tiered bytes_resident {tier['einfo']['bytes_resident']} not "
-            f"below all-hot {inc['einfo']['bytes_resident']}"
-        )
-    if tier["tier_misses"] == 0:
-        GATE_FAILURES.append("tiered soak never exercised the cold path")
     # -- latency/amortisation gates (full size only: smoke is jitter-bound) --
     if not smoke:
         if inc["churn_rebuild_ms"] >= full["churn_rebuild_ms"]:
@@ -228,7 +199,6 @@ def _soak_rows(smoke: bool) -> list:
 
     PERF_METRICS["soak_consume_incremental"] = inc["events_per_s"]
     PERF_METRICS["soak_consume_full_rebuild"] = full["events_per_s"]
-    PERF_METRICS["soak_consume_tiered"] = tier["events_per_s"]
     PERF_METRICS["soak_rebuilds_per_s"] = (inc["minfo"]["rebuilds"] - 1) / max(
         inc["churn_rebuild_ms"] / 1e3, 1e-9
     )
@@ -247,15 +217,6 @@ def _soak_rows(smoke: bool) -> list:
         full["mean_ms"] * 1e3,
         f"{full['events_per_s']:.0f} events/s, p99 {full['p99_ms']:.2f} ms/chunk, "
         f"churn rebuilds {full['churn_rebuild_ms']:.1f} ms",
-    ))
-    rows.append((
-        f"compaction/soak_tiered_{shape}",
-        tier["mean_ms"] * 1e3,
-        f"{tier['events_per_s']:.0f} events/s, "
-        f"bytes_resident {tier['einfo']['bytes_resident']}/"
-        f"{inc['einfo']['bytes_resident']} B, "
-        f"{tier['minfo']['cold_columns']} cold cols, "
-        f"{tier['tier_misses']} tier misses",
     ))
     return rows
 

@@ -2,12 +2,11 @@
 
 The paper measures 39 ms mean (10-20 ms warm) per CDC event on the JVM
 microservice.  Hardware differs; the comparable numbers are (a) the absolute
-per-event cost of the compacted-set formulation, (b) the A/B between the
-DMM gather path and the baseline matrix (one-hot matmul) path -- the paper's
-Algorithm 6 vs Algorithm 1 story -- plus the Pallas kernel variants, and
-(c) the **fused-engine A/B**: `METLApp` consume through the legacy
-one-dispatch-per-block path vs the fused one-dispatch-per-chunk path
-(events/s and device-dispatch counts for each), (d) the
+per-event cost of the compacted-set formulation -- the paper's Algorithm 6
+vs Algorithm 1 story -- (b) one compacted block applied to a batch through
+the XLA gather and the Pallas masked-gather kernel, (c) `METLApp` consume
+through the fused one-dispatch-per-chunk engine (events/s and
+device-dispatch count), (d) the
 **replicated-vs-sharded A/B**: the fused engine against `engine="sharded"`
 (block table partitioned over the mesh ``data`` axis) per shard count, with
 per-shard table bytes ~ total/N.  The sharded rows run in a subprocess with
@@ -76,12 +75,10 @@ PERF_METRICS: dict = {}
 def _dense_host_bytes(dense) -> int:
     """Host->device operand bytes one dispatch of this dense chunk ships."""
     from repro.core.dmm_jax import bucket_rows
-    from repro.etl.engines import BlockDense, ColumnarDense
+    from repro.etl.engines import ColumnarDense
 
     if isinstance(dense, ColumnarDense):
         return int(dense.packed.nbytes)  # the single packed transfer
-    if isinstance(dense, BlockDense):
-        return int(sum(v.nbytes + m.nbytes for _, _, v, m in dense.groups))
     return int(
         dense.vals.nbytes + dense.mask.nbytes + 2 * bucket_rows(dense.row_ids.size) * 4
     )
@@ -95,15 +92,8 @@ def _engine_metric(app: METLApp, chunk, *, label: str, dispatches: int, us: floa
     host_bytes = 0 if dense is None else _dense_host_bytes(dense)
     info = app.engine.info()
     width = info.get("width", 0)
-    if dense is not None and hasattr(dense, "groups"):
-        # per-block engine: outputs at each block's padded width
-        out_bytes = 0
-        for (o, v), keys, vals, mask in dense.groups:
-            for block in app.engine.plan.column(o, v):
-                out_bytes += int(keys.size) * int(block.src.size) * 5
-    else:
-        out_rows = 0 if dense is None else int(dense.row_ids.size)
-        out_bytes = out_rows * int(width) * 5  # f32 values + i8 mask
+    out_rows = 0 if dense is None else int(dense.row_ids.size)
+    out_bytes = out_rows * int(width) * 5  # f32 values + i8 mask
     n_events = len(chunk)
     entry = {
         "engine": label,
@@ -274,33 +264,22 @@ def run(smoke: bool = False) -> list:
     vals = jnp.asarray(rng.normal(size=(B, n_in)).astype(np.float32))
     mask = jnp.asarray((rng.random((B, n_in)) < 0.75).astype(np.int8))
     blk = compiled.column(o, v)[0]
-    for impl, label in [("ref", "xla_gather"), ("gather", "pallas_gather"),
-                        ("onehot", "pallas_onehot_matmul")]:
+    for impl, label in [("ref", "xla_gather"), ("gather", "pallas_gather")]:
         f = jax.jit(lambda v_, m_: ops.dmm_apply(v_, m_, blk.src, impl=impl))
         us = bench(f, vals, mask)
         rows.append((f"mapping/batched_{label}", us, f"{us/B:.3f} us/event, B={B}"))
 
-    # -- end-to-end METL app: per-block vs fused A/B --------------------------
+    # -- end-to-end METL app: fused consume ----------------------------------
     coord = StateCoordinator(reg, sc.dpm)
     src = EventSource(reg, seed=1)
     events = src.slice(0, n_events)
-
-    app_blocks = METLApp(coord, engine="blocks")
-    us_blocks, disp_blocks = _consume_bench(app_blocks, events, iters=iters)
-    rows.append((
-        f"mapping/metl_consume_perblock_{n_events}ev",
-        us_blocks,
-        f"{n_events / (us_blocks / 1e6):.0f} events/s, {disp_blocks} dispatches/chunk",
-    ))
-    PERF_METRICS["consume_perblock"] = n_events / (us_blocks / 1e6)
 
     app_fused = METLApp(coord, engine="fused")
     us_fused, disp_fused = _consume_bench(app_fused, events, iters=iters)
     rows.append((
         f"mapping/metl_consume_fused_{n_events}ev",
         us_fused,
-        f"{n_events / (us_fused / 1e6):.0f} events/s, {disp_fused} dispatch/chunk, "
-        f"{us_blocks / us_fused:.1f}x vs per-block",
+        f"{n_events / (us_fused / 1e6):.0f} events/s, {disp_fused} dispatch/chunk",
     ))
     PERF_METRICS["consume_fused"] = n_events / (us_fused / 1e6)
     if disp_fused > 1:
@@ -385,8 +364,6 @@ def run(smoke: bool = False) -> list:
                             dispatches=disp_hd, us=us_hd)
     m_dev = _engine_metric(app_dd, chunk_dd, label="fused-device-densify",
                            dispatches=disp_dd, us=us_dd)
-    _engine_metric(app_blocks, chunk_dd, label="per-block",
-                   dispatches=disp_blocks, us=us_blocks)
     rows.append((
         f"mapping/metl_consume_devdensify_{n_events}ev",
         us_dd,

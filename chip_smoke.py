@@ -65,7 +65,6 @@ from repro.etl.pipeline import EventChunkSource, Pipeline, RowSink, Source  # no
 from repro.kernels import ops, ref  # noqa: E402
 from repro.kernels.densify_map import densify_map  # noqa: E402
 from repro.kernels.masked_gather import masked_gather  # noqa: E402
-from repro.kernels.onehot_map import onehot_map  # noqa: E402
 from repro.kernels.segmented_gather import segmented_gather  # noqa: E402
 from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 
@@ -320,10 +319,6 @@ def check_kernels(interpret: bool = False, seed: int = 0) -> List[str]:
         "masked_gather": (
             masked_gather(vals, mask, src2d[3], interpret=interpret),
             ref.masked_gather_ref(vals, mask, src2d[3]),
-        ),
-        "onehot_map": (
-            onehot_map(vals, mask, src2d[3], interpret=interpret),
-            ref.onehot_map_ref(vals, mask, src2d[3]),
         ),
     }
     for name, ((kv, km), (rv, rm)) in cases.items():

@@ -39,7 +39,7 @@ def main() -> None:
     ap.add_argument("--chunk-size", type=int, default=256)
     ap.add_argument("--prompts", type=int, default=2000,
                     help="TokenizerSink limit (the backpressure bound)")
-    ap.add_argument("--engine", default="fused", choices=["fused", "blocks"])
+    ap.add_argument("--engine", default="fused", choices=["fused", "sharded"])
     ap.add_argument("--sync", action="store_true", help="disable the double buffer")
     args = ap.parse_args()
     use_compile_cache()

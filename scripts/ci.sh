@@ -78,9 +78,8 @@
 #    replication lag and failover time into the artifact.  bench_compaction
 #    gates the PlanManager soak: incremental recompaction must emit
 #    row-keys identical to the full-rebuild oracle across every churn
-#    cutover, the latest-pinned tiering arm must match up to row order
-#    while holding strictly fewer device-resident bytes, and (full size
-#    only) the amortised incremental rebuild time and p99 chunk latency
+#    cutover, and (full size only) the amortised incremental rebuild time
+#    and p99 chunk latency
 #    must beat/track the full-rebuild baseline.  The run goes through
 #    benchmarks/run.py --artifact, which writes a BENCH_<ts>.json
 #    trajectory artifact;
@@ -89,8 +88,8 @@
 #    checked in under benchmarks/trajectory/ and fails on a >20% drop
 #    (tolerance overridable via PERF_TOL);
 # 8. the ETL roofline over the fresh artifact: every engine configuration
-#    (per-block, fused host-densify, fused device-densify, sharded both
-#    ways) priced on the transfer/memory/launch walls on one chart.
+#    (fused host-densify, fused device-densify, sharded both ways) priced
+#    on the transfer/memory/launch walls on one chart.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 

@@ -86,8 +86,9 @@ dataflow map, built ONCE per run):
     (``compile_fused`` / ``compile_fused_sharded`` / ``recompile_columns``
     / ``splice_fused``), construct ``FusedDMM``/``ShardedFusedDMM``, or
     cut a ``PlanPublished`` event; ``compile_dpm`` stays free.
-    Motivation: PR 9's epoch counter, tiering residency, rebuild
-    accounting and PlanPublished replay all hang off one build path -- a
+    Motivation: PR 9's epoch counter, rebuild accounting, the
+    incremental-vs-full oracle and PlanPublished replay all hang off one
+    build path -- a
     hand-built plan is an unmanaged epoch that dodges every contract.
 
 Waivers: append ``# metl: allow[rule-id] reason`` to the offending line

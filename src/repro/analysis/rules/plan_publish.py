@@ -1,16 +1,16 @@
 """plan-publish-single-site: fused plans have ONE construction/publish site.
 
 PR 9's epoched plan lifecycle moves every fused-plan build behind
-``repro.etl.plan.PlanManager``: ``acquire``/``repartition`` are the only
-doors, ``_install`` is the only place a ``PlanPublished`` control event is
-cut, and the lowering primitives (``compile_fused`` /
-``compile_fused_sharded`` / ``recompile_columns`` / ``splice_fused`` and
-the ``FusedDMM`` / ``ShardedFusedDMM`` constructors) belong to
-``repro.core.dmm_jax``.  A plan built anywhere else is an unmanaged epoch:
-it carries no epoch number, its residency skips the tiering policy, its
-cutover is never published for replay, and the manager's ``rebuilds`` /
-``bytes_resident`` accounting silently lies.  The incremental/full
-bit-exactness contract is only enforced on builds the manager performs.
+``repro.etl.plan.PlanManager``: ``acquire`` is the only door, ``_install``
+is the only place a ``PlanPublished`` control event is cut, and the
+lowering primitives (``compile_fused`` / ``compile_fused_sharded`` /
+``recompile_columns`` / ``splice_fused`` and the ``FusedDMM`` /
+``ShardedFusedDMM`` constructors) belong to ``repro.core.dmm_jax``.  A
+plan built anywhere else is an unmanaged epoch: it carries no epoch
+number, its cutover is never published for replay, and the manager's
+``rebuilds`` / ``bytes_resident`` accounting silently lies.  The
+incremental/full bit-exactness contract is only enforced on builds the
+manager performs.
 
 Like ``single-writer-control``, the name is the contract: a call whose
 (import-resolved) target name is one of the restricted symbols fires on
@@ -76,8 +76,8 @@ class PlanPublishSingleSite(Rule):
     id = "plan-publish-single-site"
     title = "only PlanManager (repro.etl.plan) builds/publishes fused plans"
     motivation = (
-        "PR 9's epoch counter, tiering residency, rebuild accounting and "
-        "PlanPublished replay all hang off one build path; a plan "
+        "PR 9's epoch counter, rebuild accounting, the incremental-vs-full "
+        "oracle and PlanPublished replay all hang off one build path; a plan "
         "constructed elsewhere is an unmanaged epoch that dodges every "
         "one of those contracts"
     )
@@ -96,7 +96,6 @@ class PlanPublishSingleSite(Rule):
                 node,
                 f"{name}(...) outside {' / '.join(_OWNERS)}: fused plans "
                 "have one construction/publish site -- acquire an epoch "
-                "lease through PlanManager.acquire/repartition (or "
-                "PlanManager.repartition for a residency re-cut) instead "
-                "of lowering or publishing a plan by hand",
+                "lease through PlanManager.acquire instead of lowering or "
+                "publishing a plan by hand",
             )

@@ -53,7 +53,6 @@ _CALL_KINDS = {
     "MappingEngine": "engine",
     "FusedEngine": "engine",
     "ShardedEngine": "engine",
-    "BlocksEngine": "engine",
 }
 
 # annotation names -> kind (params and AnnAssign)
@@ -63,7 +62,6 @@ _ANNOT_KINDS = {
     "MappingEngine": "engine",
     "FusedEngine": "engine",
     "ShardedEngine": "engine",
-    "BlocksEngine": "engine",
 }
 
 # the known METLApp/engine private names, on ANY receiver -- the backstop

@@ -89,7 +89,6 @@ __all__ = [
     "SUBLANE",
     "pad_to_lane",
     "bucket_rows",
-    "uid_lookup_table",
     "CompactedBlockMap",
     "compile_block",
     "compile_dpm",
@@ -101,28 +100,12 @@ __all__ = [
     "FusedDMM",
     "ShardedFusedDMM",
     "compile_fused_sharded",
-    "global_uid_tables",
     "recompile_columns",
     "splice_fused",
 ]
 
 LANE = 128  # TPU vector lane width; last-dim tiles must be multiples of this
 SUBLANE = 8  # second-minor tile width; sublane axes pad to multiples of this
-
-
-def uid_lookup_table(uids) -> np.ndarray:
-    """Dense uid -> position table for vectorised densification.
-
-    ``lut[uid] = k`` for the k-th uid in ``uids``, -1 elsewhere.  Registry
-    uids are small sequential ints, so the dense table stays tiny; lookups
-    become one bounds-checked numpy gather instead of a per-item dict.get.
-    """
-    uids = np.asarray(list(uids), dtype=np.int64)
-    if uids.size == 0:
-        return np.empty(0, dtype=np.int32)
-    lut = np.full(int(uids.max()) + 1, -1, dtype=np.int32)
-    lut[uids] = np.arange(uids.size, dtype=np.int32)
-    return lut
 
 
 def pad_to_lane(n: int, lane: int = LANE) -> int:
@@ -345,21 +328,6 @@ def _uid_tables_from(cols) -> Tuple[np.ndarray, np.ndarray]:
             uid_slot[u] = k
             uid_col[u] = cid
     return uid_slot, uid_col
-
-
-def global_uid_tables(
-    compiled: CompiledDMM, registry: Registry
-) -> Tuple[np.ndarray, np.ndarray]:
-    """The fused plan's global uid tables, derivable from any engine's plan.
-
-    Column ids follow ``compiled.by_column`` insertion order -- the same
-    order :func:`_fused_tables` assigns -- so the ``blocks`` engine can
-    account ``stats["unknown_uid"]`` identically to the fused/sharded
-    engines without materialising a fused plan."""
-    return _uid_tables_from(
-        ({u: k for k, u in enumerate(registry.domain.get(o, v).uids)}, cid)
-        for cid, (o, v) in enumerate(compiled.by_column)
-    )
 
 
 def _fused_tables(
@@ -806,11 +774,10 @@ def splice_fused(
     :class:`ShardedFusedDMM` (the result keeps the same flavour, mesh and
     shard count); ``touched`` is the changed-column set (see
     :func:`recompile_columns`).  Columns absent from ``compiled`` (deleted
-    versions, or columns a residency policy keeps compacted-out) simply
-    drop out of the new table; columns absent from the OLD plan are rebuilt
-    from scratch.  The whole old table is bulk-copied with one fancy-index
-    gather, so splice cost scales with the touched columns plus O(columns),
-    not with total attributes.
+    versions) simply drop out of the new table; columns absent from the
+    OLD plan are rebuilt from scratch.  The whole old table is bulk-copied
+    with one fancy-index gather, so splice cost scales with the touched
+    columns plus O(columns), not with total attributes.
 
     Bit-exact with a from-scratch :func:`compile_fused` /
     :func:`compile_fused_sharded` of the same ``compiled`` -- the full

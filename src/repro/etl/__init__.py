@@ -11,14 +11,8 @@ from .control import (  # noqa: F401
     VersionDeleted,
     replay_control_log,
 )
-from .plan import (  # noqa: F401
-    ColdColumn,
-    PlanEpoch,
-    PlanManager,
-    TieringPolicy,
-)
+from .plan import PlanEpoch, PlanManager  # noqa: F401
 from .engines import (  # noqa: F401
-    BlocksEngine,
     FusedEngine,
     MappingEngine,
     ShardedEngine,

@@ -41,7 +41,7 @@ events), ``dispatches`` / ``events`` / ``mapped`` / ``dead_letter``
 (summed over instances), ``plan_epoch`` (max per-instance plan-manager
 epoch), ``rebuilds`` (plan builds summed over instances),
 ``bytes_resident`` (device-resident plan bytes summed over instances --
-the cluster's total table footprint under the residency policy),
+the cluster's total table footprint),
 ``role`` / ``term`` / ``log_offset`` / ``lag_records`` (replication
 surface from :meth:`StateCoordinator.replication_info`: the control-plane
 role -- ``"leader"`` for any unreplicated or leader-bound coordinator,

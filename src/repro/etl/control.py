@@ -218,8 +218,9 @@ class PlanPublished(ControlEvent):
     control timeline the cutover happened.
 
     ``epoch`` is the manager's monotone build counter (NOT the registry
-    state ``i`` -- several epochs can serve one state when the residency
-    policy repartitions); ``state`` is the state the plan was built for;
+    state ``i`` -- a rebuild after ``PlanManager.invalidate`` serves the
+    same state under a new epoch); ``state`` is the state the plan was
+    built for;
     ``incremental`` tells a splice (:func:`repro.core.dmm_jax.splice_fused`)
     from a full rebuild, with ``touched_columns`` columns re-lowered;
     ``bytes_resident`` / ``n_blocks`` describe the published table and

@@ -18,16 +18,14 @@ through four explicit stages:
 Densification is **pure numpy over columnar chunks**: triage produces a
 :class:`TriagedChunk` -- a :class:`~repro.etl.events.ColumnarChunk` (flat
 ``uids`` / ``vals`` item columns + CSR ``event_offsets``) plus per-(schema,
-version) event-index arrays -- and every engine scatters straight from the
-columns through the plan's precomputed global uid -> (slot, owning column)
-dense tables (``FusedDMM.uid_slot`` / ``uid_col``; the blocks engine builds
-per-column tables).  No per-item python runs on the hot thread, and
-the numpy scatter releases the GIL, which is what makes the pipeline's
-``densify_thread=True`` overlap a win instead of a convoy.  Legacy dict
-``Groups`` (``(o, v) -> [CDCEvent]``) are still accepted everywhere and are
-lifted through :func:`repro.etl.events.columnarize` on entry; the pre-
-columnar dict walk survives as :func:`densify_chunk_dicts`, the bit-
-exactness oracle and the benchmark's A/B baseline.
+version) event-index arrays -- and both engines lay the chunk out
+(:func:`_chunk_layout`) against the plan's precomputed global uid ->
+(slot, owning column) dense tables (``FusedDMM.uid_slot`` / ``uid_col``).
+No per-item python runs on the hot thread.  Legacy dict ``Groups``
+(``(o, v) -> [CDCEvent]``) are still accepted everywhere and are lifted
+through :func:`repro.etl.events.columnarize` on entry; the pre-columnar
+dict walk survives as :func:`densify_chunk_dicts`, the bit-exactness
+oracle.
 
 The stage boundary is the seam the streaming pipeline
 (:mod:`repro.etl.pipeline`) exploits for double-buffered async consume:
@@ -37,54 +35,38 @@ densification can overlap chunk N's device execution.  Each
 bump between stages can never mix plans.
 
 Engines register by name (:func:`register_engine`) and are built through
-:func:`make_engine`, which also resolves the legacy routing rules:``impl=
-"onehot"`` has no fused realisation and routes to the per-block engine, and
-``engine="sharded"`` on a 1-shard (or absent) mesh degenerates to the
-replicated fused engine.
+:func:`make_engine`, which turns ``engine="sharded"`` on a 1-shard (or
+absent) mesh into the replicated fused engine.
 
 Built-in engines:
 
-  ``fused``    :class:`FusedEngine` -- the whole chunk is densified into one
-      payload tensor and mapped across ALL its blocks in ONE device dispatch
-      (:func:`repro.kernels.ops.dmm_apply_fused` over the state's
-      :class:`repro.core.dmm_jax.FusedDMM` block table);
+  ``fused``    :class:`FusedEngine` -- the whole chunk is mapped across ALL
+      its blocks in ONE device dispatch over the state's
+      :class:`repro.core.dmm_jax.FusedDMM` block table;
 
   ``sharded``  :class:`ShardedEngine` -- the fused path with the block table
       partitioned over the mesh ``data`` axis
       (:class:`repro.core.dmm_jax.ShardedFusedDMM`); per-shard routing is
       split host-side in densify (overlappable), one shard_map launch per
-      chunk, emitted rows all-gathered in emit -- bit-exact with ``fused``;
+      chunk, emitted rows all-gathered in emit -- bit-exact with ``fused``.
 
-  ``blocks``   :class:`BlocksEngine` -- the legacy per-block path (one
-      masked gather per compacted block per column), kept for A/B
-      benchmarking and as the only realisation of ``impl="onehot"``.
+Each engine has one chunk path, densify -> dispatch -> emit, with the
+densification in one of two places:
 
-With ``device_densify=True`` (fused and sharded) densification itself moves
-on-device: densify shrinks to routing + packing the raw columnar (uid,
-value) items into ONE flat int32 buffer (:class:`ColumnarDense`), and the
-single dispatch resolves uids, densifies, and maps in one fused program
-(:func:`repro.kernels.ops.dmm_apply_columnar` over the plan-global
-``uid_slot`` / ``uid_col`` tables + the fused block table).  No host
-scatter, no mostly-zero dense payload on the PCIe link -- the host path
-stays as the bit-exactness oracle and the small-chunk fallback
-(``min_device_events``).
+  * host scatter (the default): densify scatters the chunk into a dense
+    ``(B, n_in_pad)`` payload (:class:`DenseChunk`) and dispatch runs
+    :func:`repro.kernels.ops.dmm_apply_fused` (or ``dmm_apply_sharded``);
+  * device densify (``device_densify=True``): densify packs the raw
+    columnar (uid, value) items and the routing into ONE flat int32
+    buffer (:class:`ColumnarDense`), and the single dispatch resolves
+    uids, densifies and maps in one program
+    (:func:`repro.kernels.ops.dmm_apply_columnar`, the ``densify_map``
+    kernel).  Chunks below ``min_device_events`` selected events take the
+    host scatter, which also stays the bit-exactness oracle.
 
-Where each configuration sits, measured per 512-event chunk (full-shape
-``benchmarks/bench_mapping.py``; roofline = ``repro.launch.roofline --etl``
-over the checked-in ``benchmarks/trajectory/BENCH_*.json``):
-
-    engine                 disp/chunk  host B/chunk  roofline position
-    blocks (per-block)         274        19,550     launch-bound (274 x ~6us)
-    fused, host densify          1       331,776     transfer-bound (20.7us PCIe)
-    fused, device densify        1        43,008     launch-bound (~6us)
-    sharded, host densify        1       331,776     transfer-bound
-    sharded, device densify      1        43,008     launch-bound
-
-The device-densify packed buffer is ~7.7x smaller than the dense payload it
-replaces, which moves the wall off the PCIe link: the roofline events/s
-ceiling rises 3.5x (2.5e7 -> 8.5e7 at 512-event chunks), and even on CPU
-(no PCIe boundary, the scatter just moves between equally-fast paths) the
-measured end-to-end consume is 1.4x faster.
+Either way emit reads the outputs back and builds the rows in one place,
+:func:`_emit_rows`.  Measurements of these paths on the chip are in
+``PERF.md``.
 
 ``info()`` is the public observability surface (engine name, shard count,
 block count, device-resident table bytes, cumulative dispatches/transfers,
@@ -101,17 +83,10 @@ from typing import Any, Dict, List, Optional, Tuple, Type
 import numpy as np
 import jax.numpy as jnp
 
-from ..core.dmm_jax import (
-    CompiledDMM,
-    apply_compacted,
-    bucket_rows,
-    global_uid_tables,
-    uid_lookup_table,
-)
+from ..core.dmm_jax import CompiledDMM, bucket_rows
 from ..core.registry import Registry
 from ..core.state import SystemState
 from ..kernels.ops import (
-    dmm_apply,
     dmm_apply_columnar,
     dmm_apply_columnar_sharded,
     dmm_apply_fused,
@@ -119,7 +94,7 @@ from ..kernels.ops import (
 )
 from . import tracing
 from .events import CDCEvent, ColumnarChunk, columnarize
-from .plan import ColdColumn, PlanEpoch, PlanManager
+from .plan import PlanEpoch, PlanManager
 
 __all__ = [
     "CanonicalRow",
@@ -129,12 +104,10 @@ __all__ = [
     "densify_chunk_dicts",
     "DenseChunk",
     "ColumnarDense",
-    "ColdDense",
     "DispatchHandle",
     "MappingEngine",
     "FusedEngine",
     "ShardedEngine",
-    "BlocksEngine",
     "ENGINES",
     "register_engine",
     "make_engine",
@@ -147,6 +120,9 @@ CanonicalRow = Tuple[Tuple[int, int], np.ndarray, np.ndarray, int]
 Groups = Dict[Tuple[int, int], List[CDCEvent]]
 # legacy triaged-chunk form: (schema o, version v) -> mappable events, in
 # arrival order; accepted by every densify and lifted via as_triaged()
+
+IMPLS = ("auto", "ref", "fused")
+# the kernel implementations an engine's ``impl`` selects (repro.kernels.ops)
 
 
 @dataclasses.dataclass
@@ -266,8 +242,8 @@ def _count_unknown_uids(
     Covers uids beyond the plan's dense-table range (an event racing ahead
     of a schema evolution) and in-range holes (e.g. a deleted version's
     attributes).  Counted over ALL triaged events against the plan-GLOBAL
-    uid -> owning-column table, so every engine -- fused, sharded, blocks,
-    with or without device densify -- reports the identical
+    uid -> owning-column table, so every engine -- fused or sharded, with
+    or without device densify -- reports the identical
     ``stats["unknown_uid"]``.  The items themselves are clamped out of the
     scatter (host) / compare-accumulate (device); they never crash."""
     if not by_column:
@@ -281,21 +257,6 @@ def _count_unknown_uids(
 
 
 @dataclasses.dataclass
-class ColdDense:
-    """One tier-miss column of a chunk, densified at the column's true
-    width against the epoch-pinned :class:`~repro.etl.plan.ColdColumn`
-    host lease.  The residency policy compacted the column OUT of the
-    device table, so emit serves it through the per-block
-    :func:`repro.core.dmm_jax.apply_compacted` fallback -- the documented
-    slow path a miss pays."""
-
-    col: ColdColumn  # epoch-pinned (carries the column's compacted blocks)
-    keys: np.ndarray  # (n,) i64 event keys
-    vals: np.ndarray  # (n, n_in) f32
-    mask: np.ndarray  # (n, n_in) i8
-
-
-@dataclasses.dataclass
 class DenseChunk:
     """One densified chunk: payload tensors plus (row, block) routing.
 
@@ -305,10 +266,7 @@ class DenseChunk:
     ``i``.  This pin is what keeps the pipeline's double-buffered async
     consume bit-exact across a mid-stream schema evolution: a control event
     may recompile the engine while chunk N is on device, but chunk N emits
-    against its own epoch's plan.  With residency tiering active, ``cold``
-    carries the chunk's tier-miss columns (also epoch-pinned, through their
-    :class:`ColdDense` leases); their rows are emitted host-side AFTER the
-    resident rows.
+    against its own epoch's plan.
     """
 
     plan: Any
@@ -321,7 +279,6 @@ class DenseChunk:
     shard_sel: Optional[List[np.ndarray]] = None
     rows_sh: Optional[np.ndarray] = None  # (n_shards, S_loc) i32
     blks_sh: Optional[np.ndarray] = None  # (n_shards, S_loc) i32
-    cold: Optional[List[ColdDense]] = None  # tier-miss columns (if any)
 
     @property
     def epoch(self) -> Optional[int]:
@@ -359,7 +316,6 @@ class ColumnarDense:
     out_keys: np.ndarray
     shard_sel: Optional[List[np.ndarray]] = None
     n_shards: int = 1
-    cold: Optional[List[ColdDense]] = None  # tier-miss columns (if any)
 
     @property
     def epoch(self) -> Optional[int]:
@@ -370,10 +326,9 @@ class ColumnarDense:
 class DispatchHandle:
     """An in-flight device dispatch.
 
-    ``outputs`` are unblocked jax arrays (futures under async dispatch) --
-    or, for the per-block engine, a list of per-block output pairs.  The
-    fused and sharded engines have already started the outputs' copy to the
-    host (:func:`_start_readback`).  The handle is consumed exactly once by
+    ``outputs`` are unblocked jax arrays (futures under async dispatch)
+    whose copy to the host dispatch has already started
+    (:func:`_start_readback`).  The handle is consumed exactly once by
     :meth:`MappingEngine.emit`, the only stage that synchronises with the
     device.
     """
@@ -414,11 +369,10 @@ def _chunk_layout(
     segmented aranges in legacy emission order (per column, per block, per
     event).  Also accounts ``stats["unknown_uid"]`` when ``stats`` is given
     (over ALL triaged events, mappable or not -- see
-    :func:`_count_unknown_uids`); with residency tiering the resident plan's
-    ``uid_col`` covers only the hot columns, so engines pass the FULL
-    column set's table via ``uid_col``.  Returns None for an unmappable
-    chunk (zero dispatches) -- exactly the legacy behaviour: columns with
-    no mapping paths contribute no output rows.
+    :func:`_count_unknown_uids`) against ``uid_col``, the plan's own
+    table unless given.  Returns None for an unmappable chunk (zero
+    dispatches) -- exactly the legacy behaviour: columns with no mapping
+    paths contribute no output rows.
     """
     chunk = tri.chunk
     if stats is not None:
@@ -466,7 +420,9 @@ def _densify_host(plan: Any, layout: _ChunkLayout) -> DenseChunk:
     """Host-side densification of a laid-out chunk: one CSR gather
     (:func:`_event_items`), one resolve through the plan's global uid
     tables (the owner comparison reproduces the legacy per-column
-    ``uid_pos.get`` semantics for stray uids), one numpy scatter."""
+    ``uid_pos.get`` semantics for stray uids), one numpy scatter.
+    Bit-exact with the dict walk (:func:`densify_chunk_dicts`), and the
+    bit-exactness oracle for the device-densify path."""
     chunk, sel = layout.chunk, layout.sel
     vals = np.zeros((bucket_rows(sel.size), plan.n_in_pad), np.float32)
     mask = np.zeros_like(vals, dtype=np.int8)
@@ -490,21 +446,6 @@ def _densify_host(plan: Any, layout: _ChunkLayout) -> DenseChunk:
         blk_ids=layout.blk_ids,
         out_keys=layout.out_keys,
     )
-
-
-def _densify_chunk(plan, groups, stats=None) -> Optional[DenseChunk]:
-    """Chunk densification shared by the fused and sharded engines: the
-    vectorised layout pass (:func:`_chunk_layout`) plus the host numpy
-    scatter (:func:`_densify_host`).  Bit-exact with the dict walk
-    (:func:`densify_chunk_dicts`) and the bit-exactness ORACLE for the
-    device-densify path; returns None for an unmappable chunk."""
-    tri = as_triaged(groups)
-    if tri is None:
-        return None
-    layout = _chunk_layout(plan, tri, stats)
-    if layout is None:
-        return None
-    return _densify_host(plan, layout)
 
 
 def _to_device(*arrays: np.ndarray) -> Tuple[Any, ...]:  # metl: allow[transfer-accounting] the engines' ONE accounted conversion site: every caller increments stats["transfers"] alongside
@@ -629,94 +570,6 @@ def densify_chunk_dicts(plan: Any, groups: Groups) -> Optional[DenseChunk]:  # m
     )
 
 
-def _densify_cold(
-    lease: Optional[PlanEpoch],
-    tri: TriagedChunk,
-    stats: collections.Counter,
-) -> Optional[List[ColdDense]]:
-    """Densify the chunk's tier-miss columns (those the residency policy
-    compacted out of the device table) at their true width against the
-    lease's host-side :class:`~repro.etl.plan.ColdColumn`s.  Same columnar
-    scatter as the hot path, accounted under ``stats["tier_misses"]``
-    (per missed event).  Returns None when the chunk touches no cold
-    column (the universal case without tiering)."""
-    if lease is None or not lease.cold:
-        return None
-    chunk = tri.chunk
-    out: List[ColdDense] = []
-    for ov, idx in tri.by_column.items():
-        col = lease.cold.get(ov)
-        if col is None:
-            continue
-        vals = np.zeros((idx.size, col.n_in), np.float32)
-        mask = np.zeros((idx.size, col.n_in), np.int8)
-        ev_rows, item_idx = _event_items(chunk, idx)
-        if item_idx.size:
-            slots = _uid_slots(col.lut, chunk.uids[item_idx])
-            keep = slots >= 0
-            if keep.any():
-                vals[ev_rows[keep], slots[keep]] = chunk.vals[item_idx[keep]]
-                mask[ev_rows[keep], slots[keep]] = 1
-        stats["tier_misses"] += int(idx.size)
-        out.append(
-            ColdDense(col=col, keys=chunk.keys[idx], vals=vals, mask=mask)
-        )
-    return out or None
-
-
-def _cold_only_chunk(
-    plan: Any, cold: List[ColdDense]
-) -> DenseChunk:
-    """A chunk whose every mappable column is cold: empty resident routing
-    (dispatch skips the device launch entirely), rows come from the
-    fallback alone."""
-    return DenseChunk(
-        plan=plan,
-        vals=np.zeros((0, 0), np.float32),
-        mask=np.zeros((0, 0), np.int8),
-        row_ids=np.empty(0, np.int32),
-        blk_ids=np.empty(0, np.int32),
-        out_keys=np.empty(0, np.int64),
-        cold=cold,
-    )
-
-
-def _emit_cold(
-    cold: Optional[List[ColdDense]], stats: collections.Counter
-) -> List[CanonicalRow]:
-    """Serve a chunk's tier-miss columns through the per-block
-    :func:`repro.core.dmm_jax.apply_compacted` fallback, appended AFTER the
-    resident rows in per-column, per-block, per-event order (the legacy
-    block-engine order; consumers needing cross-tier ordering sort by event
-    key)."""
-    rows: List[CanonicalRow] = []
-    if not cold:
-        return rows
-    for cd in cold:
-        stats["transfers"] += 2  # vals+mask cross per cold column
-        for block in cd.col.blocks:
-            ov_, om_ = apply_compacted(block, cd.vals, cd.mask)
-            # the tier-miss fallback is the documented synchronous slow
-            # path: read back eagerly, block by block
-            ov_ = np.asarray(ov_)
-            om_ = np.asarray(om_)
-            r, w = block.key[2], block.key[3]
-            for b in range(cd.keys.size):
-                if om_[b].any():  # only non-empty outgoing messages
-                    rows.append(
-                        (
-                            (r, w),
-                            ov_[b, : block.n_out],
-                            om_[b, : block.n_out],
-                            int(cd.keys[b]),
-                        )
-                    )
-                    stats["mapped"] += 1
-                else:
-                    stats["empty"] += 1
-    return rows
-
-
 @tracing.traced("emit.rows")
 def _emit_rows(plan, ov, om, blk_ids, out_keys, stats) -> List[CanonicalRow]:
     """Row emission shared by the fused and sharded engines: one
@@ -745,12 +598,11 @@ class MappingEngine:
     itself is never built here -- ``compile`` ACQUIRES it from the engine's
     :class:`~repro.etl.plan.PlanManager` (the single construction site; the
     ``plan-publish-single-site`` analyzer rule holds the line), which owns
-    epochs, incremental recompaction, residency tiering and the optional
-    background recompactor.  An engine without an explicitly bound manager
-    gets a private default on first compile.  ``stats`` is the shared
-    counter the owning :class:`~repro.etl.metl.METLApp` injects, so
-    engine-side accounting (``dispatches`` / ``mapped`` / ``empty``) lands
-    in the app's ``stats``.
+    epochs and incremental recompaction.  An engine without an explicitly
+    bound manager gets a private default on first compile.  ``stats`` is
+    the shared counter the owning :class:`~repro.etl.metl.METLApp`
+    injects, so engine-side accounting (``dispatches`` / ``mapped`` /
+    ``empty``) lands in the app's ``stats``.
     """
 
     name: str = "base"
@@ -763,6 +615,13 @@ class MappingEngine:
         stats: Optional[collections.Counter] = None,
         manager: Optional[PlanManager] = None,
     ) -> None:
+        if impl not in IMPLS:
+            raise ValueError(
+                f"unknown impl {impl!r}: the engines run {IMPLS} -- 'fused' "
+                "is the segmented_gather / densify_map kernels, 'ref' their "
+                "pure-jnp twins, 'auto' the kernels on a TPU and the twins "
+                "elsewhere"
+            )
         self.impl = impl
         self.stats = stats if stats is not None else collections.Counter()
         self.compiled: Optional[CompiledDMM] = None
@@ -772,7 +631,6 @@ class MappingEngine:
         # replication surface info() reports when the manager carries none
         self.coordinator: Optional[Any] = None
         self.lease: Optional[PlanEpoch] = None
-        self._stats_uid_col: Optional[np.ndarray] = None
 
     # -- plan lifecycle -----------------------------------------------------
     @property
@@ -796,7 +654,6 @@ class MappingEngine:
         self.lease = lease
         self.compiled = lease.compiled
         self.plan = lease.plan
-        self._on_plan(lease, registry)
         return self.plan
 
     def evict(self) -> None:
@@ -806,19 +663,6 @@ class MappingEngine:
         self.compiled = None
         self.plan = None
         self.lease = None
-        self._stats_uid_col = None
-
-    def _on_plan(self, lease: PlanEpoch, registry: Registry) -> None:
-        """Post-acquire hook: refresh engine-side state derived from a new
-        lease (subclasses extend)."""
-        # the resident plan's uid tables cover hot columns only; unknown-uid
-        # accounting must keep seeing the FULL column set when tiering has
-        # compacted some columns out
-        self._stats_uid_col = (
-            global_uid_tables(lease.compiled, registry)[1]
-            if lease.cold
-            else None
-        )
 
     def _manager_info(self) -> Dict[str, Any]:
         """The manager-derived keys every engine's ``info()`` carries."""
@@ -874,13 +718,9 @@ class MappingEngine:
                           replicated)
           ``dispatches``  cumulative device dispatches through this engine
           ``readbacks_early``  cumulative dispatches whose output read-back
-                          ``dispatch`` started (fused/sharded engines; equals
-                          ``dispatches`` there, absent from the per-block
-                          engine)
-          ``transfers``   cumulative host->device transfers (fused/sharded
-                          engines; the per-block engine reports none)
+                          ``dispatch`` started (equals ``dispatches``)
+          ``transfers``   cumulative host->device transfers
           ``device_densify``  whether densification runs on device
-                          (fused/sharded engines)
           ``plan_epoch``  the plan manager's monotone build counter (0
                           before the first acquire; several epochs can
                           serve one state ``i``)
@@ -911,16 +751,12 @@ class MappingEngine:
           ``table_bytes``           device-resident block-table bytes, total
           ``table_bytes_per_shard`` per-shard slice bytes (~ total/N sharded)
           ``bytes_resident``        device-resident block-table bytes the
-                                    lease actually holds (tracks the
-                                    residency policy: cold columns stay
-                                    compacted-out and don't count)
-          ``width``                 padded block-table row width (fused/
-                                    sharded only)
+                                    lease holds (the whole table:
+                                    equals ``table_bytes``)
+          ``width``                 padded block-table row width
           ``uid_capacity``          uid-table length, a capacity bucket
-                                    (fused/sharded only)
           ``block_capacity``        block-table rows, a capacity bucket
-                                    (per shard times shards; fused/
-                                    sharded only)
+                                    (per shard times shards)
 
         ``Cluster.info()`` (:mod:`repro.etl.cluster`) aggregates these per
         instance."""
@@ -956,23 +792,20 @@ def make_engine(
     """Resolve an engine name (or pass through an instance) to a ready
     :class:`MappingEngine`.
 
-    Legacy routing rules, preserved from the pre-protocol METLApp:
-
-      * ``impl="onehot"`` only exists as a per-block kernel, so it routes to
-        the ``blocks`` engine rather than silently changing the benched path;
-      * ``engine="sharded"`` needs >1 shard on the mesh ``data`` axis;
-        otherwise it degenerates to the replicated fused engine.
+    ``engine="sharded"`` needs >1 shard on the mesh ``data`` axis;
+    otherwise it degenerates to the replicated fused engine.  ``impl`` is
+    one of :data:`IMPLS`; any other value raises (the engine constructor
+    checks it) instead of silently running a different path.
 
     ``device_densify=True`` moves chunk densification on-device
     (:class:`ColumnarDense` / :func:`repro.kernels.ops.dmm_apply_columnar`);
-    only the fused and sharded engines realise it, and ``impl="onehot"``
-    (which routes to the per-block engine) cannot -- both misconfigurations
-    raise instead of silently benching a different path.
+    only the fused and sharded engines realise it, and asking any other
+    registered engine for it raises.
 
     ``manager`` binds an explicit :class:`~repro.etl.plan.PlanManager`
-    (tiering / background recompaction / coordinator-published epochs);
-    its ``kind`` must match the engine the routing rules resolve to.
-    Without one the engine builds a private default on first compile.
+    (e.g. coordinator-published epochs); its ``kind`` must match the
+    engine the routing rules resolve to.  Without one the engine builds a
+    private default on first compile.
     """
     if isinstance(engine, MappingEngine):
         # an instance carries its own impl/mesh; silently overriding (or
@@ -1006,13 +839,6 @@ def make_engine(
         raise ValueError(
             f"unknown engine {engine!r} (registered: {sorted(ENGINES)})"
         )
-    if impl == "onehot" and engine in ("fused", "sharded"):
-        if device_densify:
-            raise ValueError(
-                "device_densify=True has no onehot realisation (impl='onehot' "
-                "routes to the per-block engine)"
-            )
-        return ENGINES["blocks"](impl=impl, stats=stats, manager=manager)
     if engine == "sharded":
         n_shards = int(mesh.shape["data"]) if mesh is not None else 1
         if n_shards <= 1:
@@ -1068,14 +894,11 @@ class FusedEngine(MappingEngine):
         tri = as_triaged(groups)
         if tri is None:
             return None
-        layout = _chunk_layout(self.plan, tri, self.stats, self._stats_uid_col)
-        cold = _densify_cold(self.lease, tri, self.stats)
+        layout = _chunk_layout(self.plan, tri, self.stats)
         if layout is None:
-            return _cold_only_chunk(self.plan, cold) if cold else None
+            return None
         if not self.device_densify or layout.sel.size < self.min_device_events:
-            dense = _densify_host(self.plan, layout)
-            dense.cold = cold
-            return dense
+            return _densify_host(self.plan, layout)
         s = layout.row_ids.size
         s_pad = bucket_rows(s)
         rows = np.zeros(s_pad, np.int32)
@@ -1093,15 +916,11 @@ class FusedEngine(MappingEngine):
             row_ids=layout.row_ids,
             blk_ids=layout.blk_ids,
             out_keys=layout.out_keys,
-            cold=cold,
         )
 
     @tracing.traced("dispatch")
     def dispatch(self, dense) -> DispatchHandle:
-        if dense.row_ids.size == 0:  # cold-only chunk: nothing resident
-            return DispatchHandle(outputs=None, dense=dense)
         fused = dense.plan
-        impl = {"gather": "fused"}.get(self.impl, self.impl)
         if isinstance(dense, ColumnarDense):
             outputs = dmm_apply_columnar(
                 dense.packed,
@@ -1112,7 +931,7 @@ class FusedEngine(MappingEngine):
                 n_events=dense.n_events,
                 n_rows=dense.n_rows,
                 k=dense.k,
-                impl=impl,
+                impl=self.impl,
             )
             self.stats["transfers"] += 1  # the packed buffer is the chunk
         else:
@@ -1124,7 +943,7 @@ class FusedEngine(MappingEngine):
                 np.pad(dense.row_ids, (0, s_pad - s)),
                 np.pad(dense.blk_ids, (0, s_pad - s)),
             )
-            outputs = dmm_apply_fused(jv, jm, jr, jb, fused.src2d, impl=impl)
+            outputs = dmm_apply_fused(jv, jm, jr, jb, fused.src2d, impl=self.impl)
             self.stats["transfers"] += 4  # vals, mask, rows, blks
         _start_readback(outputs, self.stats)
         self.stats["dispatches"] += 1
@@ -1133,18 +952,14 @@ class FusedEngine(MappingEngine):
     @tracing.traced("emit")
     def emit(self, handle: DispatchHandle) -> List[CanonicalRow]:
         dense = handle.dense
-        rows: List[CanonicalRow] = []
-        if handle.outputs is not None:
-            s = dense.row_ids.size
-            # the rest of the read-back dispatch started (_start_readback)
-            with tracing.span("emit.sync"):
-                ov = np.asarray(handle.outputs[0])[:s]  # metl: allow[host-sync-in-hot-path] the engine sync point
-                om = np.asarray(handle.outputs[1])[:s]  # metl: allow[host-sync-in-hot-path] the engine sync point
-            rows = _emit_rows(
-                dense.plan, ov, om, dense.blk_ids, dense.out_keys, self.stats
-            )
-        rows.extend(_emit_cold(dense.cold, self.stats))
-        return rows
+        s = dense.row_ids.size
+        # the rest of the read-back dispatch started (_start_readback)
+        with tracing.span("emit.sync"):
+            ov = np.asarray(handle.outputs[0])[:s]  # metl: allow[host-sync-in-hot-path] the engine sync point
+            om = np.asarray(handle.outputs[1])[:s]  # metl: allow[host-sync-in-hot-path] the engine sync point
+        return _emit_rows(
+            dense.plan, ov, om, dense.blk_ids, dense.out_keys, self.stats
+        )
 
     def info(self) -> Dict[str, Any]:
         d: Dict[str, Any] = {
@@ -1169,11 +984,7 @@ class FusedEngine(MappingEngine):
                 block_capacity=int(p.src2d.shape[0]),
                 table_bytes=table_bytes,
                 table_bytes_per_shard=table_bytes,
-                bytes_resident=(
-                    self.lease.bytes_resident
-                    if self.lease is not None
-                    else table_bytes
-                ),
+                bytes_resident=table_bytes,
             )
         return d
 
@@ -1225,15 +1036,13 @@ class ShardedEngine(MappingEngine):
         tri = as_triaged(groups)
         if tri is None:
             return None
-        layout = _chunk_layout(self.plan, tri, self.stats, self._stats_uid_col)
-        cold = _densify_cold(self.lease, tri, self.stats)
+        layout = _chunk_layout(self.plan, tri, self.stats)
         if layout is None:
-            return _cold_only_chunk(self.plan, cold) if cold else None
+            return None
         sel, rows_sh, blks_sh = self._shard_split(layout.row_ids, layout.blk_ids)
         if not self.device_densify or layout.sel.size < self.min_device_events:
             dense = _densify_host(self.plan, layout)
             dense.shard_sel, dense.rows_sh, dense.blks_sh = sel, rows_sh, blks_sh
-            dense.cold = cold
             return dense
         # per-shard routing rides flattened in the packed buffer; the kernel
         # side reshapes to (n_shards, S_loc) and shard_map fans it out
@@ -1250,15 +1059,11 @@ class ShardedEngine(MappingEngine):
             out_keys=layout.out_keys,
             shard_sel=sel,
             n_shards=self.n_shards,
-            cold=cold,
         )
 
     @tracing.traced("dispatch")
     def dispatch(self, dense) -> DispatchHandle:
-        if dense.row_ids.size == 0:  # cold-only chunk: nothing resident
-            return DispatchHandle(outputs=None, dense=dense)
         sh = dense.plan
-        impl = {"gather": "fused"}.get(self.impl, self.impl)
         if isinstance(dense, ColumnarDense):
             outputs = dmm_apply_columnar_sharded(
                 dense.packed,
@@ -1271,7 +1076,7 @@ class ShardedEngine(MappingEngine):
                 n_rows=dense.n_rows,
                 k=dense.k,
                 n_shards=dense.n_shards,
-                impl=impl,
+                impl=self.impl,
             )
             self.stats["transfers"] += 1
         else:
@@ -1279,7 +1084,7 @@ class ShardedEngine(MappingEngine):
                 dense.vals, dense.mask, dense.rows_sh, dense.blks_sh
             )
             outputs = dmm_apply_sharded(
-                jv, jm, jr, jb, sh.src3d, mesh=sh.mesh, impl=impl
+                jv, jm, jr, jb, sh.src3d, mesh=sh.mesh, impl=self.impl
             )
             self.stats["transfers"] += 4
         _start_readback(outputs, self.stats)
@@ -1289,25 +1094,21 @@ class ShardedEngine(MappingEngine):
     @tracing.traced("emit")
     def emit(self, handle: DispatchHandle) -> List[CanonicalRow]:
         dense = handle.dense
-        rows: List[CanonicalRow] = []
-        if handle.outputs is not None:
-            sh = dense.plan
-            # all-gather: pull every shard's emitted dense rows to the host
-            # (the copy dispatch started) and scatter them back to the
-            # global output order
-            with tracing.span("emit.sync"):
-                ov = np.asarray(handle.outputs[0])  # metl: allow[host-sync-in-hot-path] the engine sync point (all-gather)
-                om = np.asarray(handle.outputs[1])  # metl: allow[host-sync-in-hot-path] the engine sync point (all-gather)
-            gv = np.zeros((dense.row_ids.size, sh.width), ov.dtype)
-            gm = np.zeros((dense.row_ids.size, sh.width), om.dtype)
-            for s, idx in enumerate(dense.shard_sel):
-                gv[idx] = ov[s, : len(idx)]
-                gm[idx] = om[s, : len(idx)]
-            rows = _emit_rows(
-                sh, gv, gm, dense.blk_ids, dense.out_keys, self.stats
-            )
-        rows.extend(_emit_cold(dense.cold, self.stats))
-        return rows
+        sh = dense.plan
+        # all-gather: pull every shard's emitted dense rows to the host
+        # (the copy dispatch started) and scatter them back to the
+        # global output order
+        with tracing.span("emit.sync"):
+            ov = np.asarray(handle.outputs[0])  # metl: allow[host-sync-in-hot-path] the engine sync point (all-gather)
+            om = np.asarray(handle.outputs[1])  # metl: allow[host-sync-in-hot-path] the engine sync point (all-gather)
+        gv = np.zeros((dense.row_ids.size, sh.width), ov.dtype)
+        gm = np.zeros((dense.row_ids.size, sh.width), om.dtype)
+        for s, idx in enumerate(dense.shard_sel):
+            gv[idx] = ov[s, : len(idx)]
+            gm[idx] = om[s, : len(idx)]
+        return _emit_rows(
+            sh, gv, gm, dense.blk_ids, dense.out_keys, self.stats
+        )
 
     def info(self) -> Dict[str, Any]:
         d: Dict[str, Any] = {
@@ -1332,136 +1133,6 @@ class ShardedEngine(MappingEngine):
                 block_capacity=p.n_shards * p.n_blocks_pad_loc,
                 table_bytes=table_bytes,
                 table_bytes_per_shard=p.table_bytes_per_shard,
-                bytes_resident=(
-                    self.lease.bytes_resident
-                    if self.lease is not None
-                    else table_bytes
-                ),
-            )
-        return d
-
-
-# -- the legacy per-block engine ----------------------------------------------
-
-
-@dataclasses.dataclass
-class BlockDense:
-    """Per-column dense payloads for the legacy engine: one (keys, vals,
-    mask) triple per (schema, version) group, mapped block-by-block in
-    dispatch (``keys`` carries the event key per dense row)."""
-
-    plan: CompiledDMM
-    groups: List[Tuple[Tuple[int, int], np.ndarray, np.ndarray, np.ndarray]]
-
-
-@register_engine("blocks")
-class BlocksEngine(MappingEngine):
-    """Legacy engine: one device dispatch per block per (o, v) group.  Kept
-    for A/B benchmarking and as the only realisation of ``impl="onehot"``.
-    Densification is the same columnar numpy scatter as the fused engines
-    (shared :func:`_event_items` / :func:`_uid_slots`), just per column at
-    the column's true width instead of one fused payload tensor.
-    """
-
-    plan_kind = "blocks"
-
-    def __init__(
-        self, *, impl: str = "auto",
-        stats: Optional[collections.Counter] = None,
-        manager: Optional[PlanManager] = None,
-    ) -> None:
-        super().__init__(impl=impl, stats=stats, manager=manager)
-        self._registry: Optional[Registry] = None
-        self._luts: Dict[Tuple[int, int], np.ndarray] = {}
-        self._uid_col_global: Optional[np.ndarray] = None
-
-    def _on_plan(self, lease: PlanEpoch, registry: Registry) -> None:
-        super()._on_plan(lease, registry)
-        self._registry = registry
-        self._luts = {}  # uid -> slot tables are per registry state
-        # plan-global uid -> owning-column table, so stats["unknown_uid"] is
-        # counted identically to the fused engines (which carry it on the plan)
-        self._uid_col_global = global_uid_tables(lease.compiled, registry)[1]
-
-    def _column_lut(self, o: int, v: int) -> np.ndarray:
-        lut = self._luts.get((o, v))
-        if lut is None:
-            lut = uid_lookup_table(self._registry.domain.get(o, v).uids)
-            self._luts[(o, v)] = lut
-        return lut
-
-    @tracing.traced("densify")
-    def densify(self, groups) -> Optional[BlockDense]:
-        tri = as_triaged(groups)
-        if tri is None:
-            return None
-        chunk = tri.chunk
-        _count_unknown_uids(self._uid_col_global, chunk, tri.by_column, self.stats)
-        out = []
-        for (o, v), idx in tri.by_column.items():
-            idx = np.asarray(idx, dtype=np.int64)
-            n_in = len(self._registry.domain.get(o, v).uids)
-            vals = np.zeros((idx.size, n_in), np.float32)
-            mask = np.zeros((idx.size, n_in), np.int8)
-            ev_rows, item_idx = _event_items(chunk, idx)
-            if item_idx.size:
-                slots = _uid_slots(self._column_lut(o, v), chunk.uids[item_idx])
-                keep = slots >= 0
-                if keep.any():
-                    vals[ev_rows[keep], slots[keep]] = chunk.vals[item_idx[keep]]
-                    mask[ev_rows[keep], slots[keep]] = 1
-            out.append(((o, v), chunk.keys[idx], vals, mask))
-        return BlockDense(plan=self.plan, groups=out)
-
-    @tracing.traced("dispatch")
-    def dispatch(self, dense: BlockDense) -> DispatchHandle:
-        outputs = []
-        for (o, v), keys, vals, mask in dense.groups:
-            jv, jm = _to_device(vals, mask)
-            self.stats["transfers"] += 2  # per-group vals+mask (legacy path)
-            for block in dense.plan.column(o, v):
-                ov, om = dmm_apply(jv, jm, block.src, impl=self.impl)
-                self.stats["dispatches"] += 1
-                outputs.append((block, keys, ov, om))
-        return DispatchHandle(outputs=outputs, dense=dense)
-
-    @tracing.traced("emit")
-    def emit(self, handle: DispatchHandle) -> List[CanonicalRow]:
-        rows: List[CanonicalRow] = []
-        for block, keys, ov, om in handle.outputs:
-            ov, om = np.asarray(ov), np.asarray(om)  # metl: allow[host-sync-in-hot-path] the engine sync point
-            r, w = block.key[2], block.key[3]
-            for b in range(keys.size):
-                if om[b].any():  # only non-empty outgoing messages
-                    rows.append(
-                        ((r, w), ov[b, : block.n_out], om[b, : block.n_out], int(keys[b]))
-                    )
-                    self.stats["mapped"] += 1
-                else:
-                    self.stats["empty"] += 1
-        return rows
-
-    def info(self) -> Dict[str, Any]:
-        d: Dict[str, Any] = {
-            "engine": self.name,
-            "impl": self.impl,
-            "n_shards": 1,
-            "dispatches": int(self.stats["dispatches"]),
-            **self._manager_info(),
-        }
-        if self.plan is not None:
-            blocks = [b for col in self.plan.by_column.values() for b in col]
-            table_bytes = int(sum(b.src.nbytes for b in blocks))
-            d.update(
-                state=self.plan.state,
-                n_blocks=self.plan.n_blocks,
-                blocks_per_shard=self.plan.n_blocks,
-                table_bytes=table_bytes,
-                table_bytes_per_shard=table_bytes,
-                bytes_resident=(
-                    self.lease.bytes_resident
-                    if self.lease is not None
-                    else table_bytes
-                ),
+                bytes_resident=table_bytes,
             )
         return d

@@ -13,7 +13,7 @@ two since the engine/pipeline redesign:
 
   * **The mapping itself lives behind the MappingEngine protocol**
     (:mod:`repro.etl.engines`): ``compile / densify / dispatch / emit``
-    plus ``info()``.  ``METLApp(engine="fused"|"sharded"|"blocks")`` resolves
+    plus ``info()``.  ``METLApp(engine="fused"|"sharded")`` resolves
     a registered engine through :func:`repro.etl.engines.make_engine`
     (strings keep working; engine *instances* plug in custom
     implementations), and :meth:`METLApp.consume` is now just
@@ -83,12 +83,11 @@ class METLApp:
         self.mesh = mesh
         self.stats = collections.Counter()
         # engine resolution: strings go through the registry factory (which
-        # also applies the legacy impl="onehot" -> blocks and 1-shard
-        # sharded -> fused routing); instances are adopted as-is and share
-        # the app's stats counter.  plan_manager binds an explicit
-        # repro.etl.plan.PlanManager (incremental recompaction is on by
-        # default either way; an explicit manager adds residency tiering,
-        # background recompaction and PlanPublished control events)
+        # also applies the 1-shard sharded -> fused routing); instances are
+        # adopted as-is and share the app's stats counter.  plan_manager
+        # binds an explicit repro.etl.plan.PlanManager (incremental
+        # recompaction is on by default either way; an explicit manager
+        # adds PlanPublished control events)
         self.engine = make_engine(
             engine, impl=impl, mesh=mesh, device_densify=device_densify,
             stats=self.stats, manager=plan_manager,
@@ -280,19 +279,12 @@ class METLApp:
                     stats["dead_lettered"] += 1
                 continue
             by_column[(schema_ids[e], versions[e])].append(e)
-        tri = TriagedChunk(
+        return TriagedChunk(
             chunk=chunk,
             by_column={
                 ov: np.asarray(idx, dtype=np.int64) for ov, idx in by_column.items()
             },
         )
-        # residency tiering: triage is where every mappable event passes, so
-        # the per-(o, v) hit counters feeding the plan manager's hot/cold
-        # policy are folded in here (no-op without a tiering policy)
-        mgr = self.engine.manager
-        if mgr is not None and mgr.tiering is not None and tri.by_column:
-            mgr.record_hits(tri.by_column)
-        return tri
 
     def consume(
         self, events: Union[Iterable[CDCEvent], ColumnarChunk]
@@ -302,9 +294,8 @@ class METLApp:
         Triage (dedup / state check / parking) is per event; the mapping is
         chunk-batched through the engine's densify -> dispatch -> emit
         stages, with densification running as pure numpy over the chunk's
-        columnar (uid, value) arrays.  The fused engine issues a constant number of device
-        dispatches per chunk (one, when any mappable event is present); the
-        legacy per-block engine issues one per (column, block) pair.
+        columnar (uid, value) arrays.  The engine issues one device dispatch
+        per chunk when any mappable event is present, none otherwise.
 
         If the triage tripped a lazy refresh that replayed parked events,
         their rows are delivered first (they are the older events).

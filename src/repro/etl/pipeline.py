@@ -93,10 +93,8 @@ triggers goes through the engine's :class:`~repro.etl.plan.PlanManager`:
 by default an incremental recompaction (only the evolution's touched
 columns are re-lowered and spliced into the previous epoch's fused table,
 :func:`repro.core.dmm_jax.recompile_columns` / ``splice_fused``), not a
-full rebuild -- and with ``background=True`` the manager prepares the next
-epoch on a worker thread the moment the eviction fan-out fires, so the
-boundary's lazy recompile usually finds the table ready.  The epoch pin
-above is exactly what lets the in-flight chunk drain on the OLD epoch's
+full rebuild, run synchronously by the boundary's lazy recompile.  The
+epoch pin above is exactly what lets the in-flight chunk drain on the OLD epoch's
 table while the next chunk densifies against the new one; a manager bound
 with ``publish=True`` records each cutover in the control log as a
 :class:`~repro.etl.control.PlanPublished` event (see docs/plan_lifecycle

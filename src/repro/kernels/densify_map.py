@@ -33,7 +33,7 @@ O(B*K + S*W) instead of O(B*n_in_pad + S*W).
 Duplicate slots within one event resolve last-writer-wins (ascending j),
 exactly the numpy fancy-index semantics of the host scatter
 (``vals[r, c] = ...``), which keeps this path bit-exact with the host
-``_densify_chunk`` + segmented-gather oracle.
+``_densify_host`` + segmented-gather oracle.
 
 Grid: (S_pad / block_s, W / block_n); ``rows``/``blks`` are scalar-prefetch
 operands (SMEM), ``src2d`` contributes one lane tile of all blocks per grid

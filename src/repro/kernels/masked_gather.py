@@ -24,9 +24,9 @@ TPU adaptation (vs. the paper's JVM hashmap lookups):
 Roofline: the gather moves O(B * (N_in + N_out)) bytes and does no FLOPs --
 it is memory-bound by construction, which is exactly the paper's claim that
 the DMM turns a matrix operator into data movement proportional to the
-*dense* content.  The baseline one-hot matmul kernel
-(:mod:`repro.kernels.onehot_map`) moves the same bytes but burns
-O(B * N_in * N_out) MXU FLOPs; benchmarks/bench_mapping.py reports the A/B.
+*dense* content.  The baseline one-hot matmul
+(:func:`repro.core.dmm_jax.apply_onehot`) moves the same bytes but burns
+O(B * N_in * N_out) FLOPs.
 """
 
 from __future__ import annotations

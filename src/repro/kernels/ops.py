@@ -34,7 +34,6 @@ from .densify_map import (
     densify_map as _densify_map_kernel,
     densify_map_shard as _densify_map_shard,
 )
-from .onehot_map import onehot_map as _onehot_map_kernel
 from .moe_combine import moe_combine as _moe_combine_kernel
 from .flash_attention import flash_attention as _flash_attention_kernel
 
@@ -72,7 +71,6 @@ def dmm_apply(
 
     impl:
       "gather"        Pallas masked-gather kernel (the DMM path)
-      "onehot"        Pallas one-hot matmul kernel (the baseline path)
       "ref"           pure-jnp oracle (XLA gather)
       "auto"          Pallas kernel on TPU, oracle elsewhere
     """
@@ -81,15 +79,13 @@ def dmm_apply(
     if impl == "auto":
         impl = "gather" if on_tpu() else "ref"
     if impl == "ref":
-        # eager on purpose: the legacy per-block engine does not bucket its
-        # batch shapes, so a jit here would retrace per (group, block) shape
+        # eager on purpose: callers do not bucket their batch shapes, so a
+        # jit here would retrace per (batch, block) shape
         return _ref.masked_gather_ref(values, mask, src, fill=fill)
     if impl == "gather":
         return _masked_gather_kernel(
             values, mask, src, fill=fill, interpret=not on_tpu()
         )
-    if impl == "onehot":
-        return _onehot_map_kernel(values, mask, src, fill=fill, interpret=not on_tpu())
     raise ValueError(f"unknown impl {impl!r}")
 
 
@@ -255,7 +251,7 @@ def _resolve_items(
     :func:`repro.kernels.densify_map.densify_map`.  An item is dropped when
     its CSR slot is padding, its uid is out of table range or unknown, or
     its uid belongs to a different column than the event's (the host
-    ``_densify_chunk`` owner-check semantics).
+    ``_densify_host`` owner-check semantics).
     """
     ni, b = n_items, n_events
     uids = packed[:ni]

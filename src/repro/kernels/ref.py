@@ -16,7 +16,6 @@ __all__ = [
     "masked_gather_ref",
     "segmented_gather_ref",
     "densify_map_ref",
-    "onehot_map_ref",
     "moe_combine_ref",
 ]
 
@@ -101,20 +100,6 @@ def densify_map_ref(
         acc = jnp.where(m, ex[:, j][:, None], acc)
         hit = hit | m
     return acc, hit.astype(jnp.int8)
-
-
-def onehot_map_ref(
-    values: jax.Array, mask: jax.Array, src: jax.Array, *, fill: float = 0.0
-) -> Tuple[jax.Array, jax.Array]:
-    """Baseline (paper Algorithm-1 world): apply the mapping as an explicit
-    0/1 matrix-vector product.  Numerically identical to masked_gather_ref."""
-    n_in = values.shape[1]
-    m = (src[:, None] == jnp.arange(n_in, dtype=src.dtype)[None, :]).astype(jnp.float32)
-    hi = jax.lax.Precision.HIGHEST  # f32 products; the TPU default rounds to bf16
-    out_v = jnp.einsum("qp,bp->bq", m, values.astype(jnp.float32), precision=hi)
-    out_m = jnp.einsum("qp,bp->bq", m, mask.astype(jnp.float32), precision=hi) > 0.5
-    out_v = jnp.where(out_m, out_v, fill).astype(values.dtype)
-    return out_v, out_m.astype(jnp.int8)
 
 
 def moe_combine_ref(

@@ -27,10 +27,9 @@ no meaningful FLOPs, so the engine walls are
     memory   = device bytes touched per chunk / HBM_bw
     launch   = dispatches per chunk * kernel launch overhead (~6 us)
 
-and the interesting spread is WHERE each engine sits: per-block is
-launch-bound (O(blocks) dispatches), fused host-densify is transfer-bound
-(the dense (B, n_in_pad) payload is mostly-zero PCIe traffic), and fused
-device-densify is the only configuration whose transfer term shrinks to the
+and the interesting spread is WHERE each engine sits: fused host-densify
+is transfer-bound (the dense (B, n_in_pad) payload is mostly-zero PCIe
+traffic), and fused device-densify is the only configuration whose transfer term shrinks to the
 raw columnar items -- on accelerator hardware that moves the wall from the
 PCIe link to the (far faster) HBM, which is the tentpole's 2x at ETL chunk
 sizes.  Events/s ceilings reported per engine are chunk_events / wall.

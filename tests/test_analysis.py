@@ -1054,7 +1054,7 @@ def test_plan_publish_clean_twin_manager_lease(tmp_path):
         tmp_path,
         "benchmarks/bench.py",
         "from repro.core.dmm_jax import compile_dpm\n"
-        "from repro.etl import METLApp, PlanManager, TieringPolicy\n"
+        "from repro.etl import METLApp, PlanManager\n"
         "mgr = PlanManager(kind='fused', coordinator=coord)\n"
         "app = METLApp(coord, plan_manager=mgr)\n"
         "lease = mgr.acquire(snap, reg)\n"

@@ -69,7 +69,7 @@ def test_check_phase_catches_a_wrong_value():
 
 def test_kernels_match_ref_twins_interpreted():
     assert cs.check_kernels(interpret=True) == [
-        "segmented_gather", "densify_map", "masked_gather", "onehot_map",
+        "segmented_gather", "densify_map", "masked_gather",
     ]
 
 

@@ -8,10 +8,11 @@ bugfix sweep that rides along:
     random payloads including empty / all-None / foreign-uid / unmappable
     events, for the fused plan -- bit-exact DenseChunk fields;
   * consume parity: columnar chunks vs legacy event lists produce identical
-    rows AND stats for the fused and blocks engines (the sharded engine
-    shares _densify_chunk and is parity-tested in test_sharded_engine.py);
+    rows AND stats with host and with device densify (the sharded engine
+    shares _chunk_layout and is parity-tested in test_sharded_engine.py);
   * non-numeric payload values (str / bool / Decimal) are routed to the
-    dead-letter path with a counted stat -- identically across engines --
+    dead-letter path with a counted stat -- identically on both densify
+    paths --
     instead of crashing or silently truncating inside the float32 scatter;
   * Source.reset_offset: a finished ListSource / EventChunkSource cursor is
     resettable and re-slices deterministically (the dead-letter replay
@@ -34,6 +35,7 @@ from repro.etl import (
     ColumnarChunk,
     EventChunkSource,
     EventSource,
+    FusedEngine,
     ListSource,
     METLApp,
     Pipeline,
@@ -50,6 +52,16 @@ def world():
     sc = build_scenario(ScenarioConfig(seed=61))
     coord = StateCoordinator(sc.registry, sc.dpm)
     return sc, coord
+
+
+def _app(coord, device_densify):
+    """A fused app densifying on the host, or on the device for every
+    chunk (no small-chunk fallback, so a two-event chunk takes it too)."""
+    if not device_densify:
+        return METLApp(coord)
+    return METLApp(
+        coord, engine=FusedEngine(device_densify=True, min_device_events=0)
+    )
 
 
 def _mk_event(key, o, v, payload, state):
@@ -182,14 +194,14 @@ def test_densify_oracle_hypothesis(world):
     check()
 
 
-@pytest.mark.parametrize("engine", ["fused", "blocks"])
-def test_consume_parity_columnar_vs_legacy(world, engine):
+@pytest.mark.parametrize("device_densify", [False, True], ids=["fused", "device"])
+def test_consume_parity_columnar_vs_legacy(world, device_densify):
     """Same events, columnar chunk vs legacy list: identical rows and stats
-    for every engine (the stats-parity acceptance assertion)."""
+    on both densify paths (the stats-parity acceptance assertion)."""
     sc, coord = world
     src = EventSource(sc.registry, seed=7, p_duplicate=0.1)
-    a = METLApp(coord, engine=engine)
-    b = METLApp(coord, engine=engine)
+    a = _app(coord, device_densify)
+    b = _app(coord, device_densify)
     for k in range(3):
         rows_legacy = a.consume(src.slice(k * 150, 150))
         rows_col = b.consume(src.slice_columnar(k * 150, 150))
@@ -199,17 +211,18 @@ def test_consume_parity_columnar_vs_legacy(world, engine):
 
 
 def test_fused_blocks_stats_parity_on_columnar(world):
-    """Across engines: the engine-side stats (mapped/empty) agree on the
-    same columnar stream, as they did on the legacy path."""
+    """Across densify paths: the engine-side stats (mapped/empty) agree on
+    the same columnar stream, host scatter against device densify."""
     sc, coord = world
     src = EventSource(sc.registry, seed=8, p_duplicate=0.05)
-    apps = {e: METLApp(coord, engine=e) for e in ("fused", "blocks")}
+    apps = {"host": _app(coord, False), "device": _app(coord, True)}
     rows = {}
     for e, app in apps.items():
         rows[e] = [r for k in range(3) for r in app.consume(src.slice_columnar(k * 100, 100))]
-    _assert_rows_equal(rows["fused"], rows["blocks"])
+    _assert_rows_equal(rows["host"], rows["device"])
     for k in ("events", "duplicates", "mapped", "empty", "stale"):
-        assert apps["fused"].stats[k] == apps["blocks"].stats[k], k
+        assert apps["host"].stats[k] == apps["device"].stats[k], k
+    assert apps["device"].stats["transfers"] == apps["device"].stats["dispatches"] == 3
 
 
 def test_empty_and_unmappable_chunks(world):
@@ -235,13 +248,13 @@ def test_empty_and_unmappable_chunks(world):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("engine", ["fused", "blocks"])
+@pytest.mark.parametrize("device_densify", [False, True], ids=["fused", "device"])
 @pytest.mark.parametrize(
     "badval", ["3.5", True, decimal.Decimal("1.25"), object()]
 )
-def test_bad_payload_routed_to_dead_letter(world, engine, badval):
+def test_bad_payload_routed_to_dead_letter(world, device_densify, badval):
     sc, coord = world
-    app = METLApp(coord, engine=engine)
+    app = _app(coord, device_densify)
     o = sc.registry.domain.schema_ids()[0]
     v = sc.registry.domain.versions(o)[-1]
     uids = sc.registry.domain.get(o, v).uids
@@ -272,12 +285,12 @@ def test_bad_payload_stats_identical_across_engines(world):
         _mk_event(4, o, v, {uids[1]: 6.0}, s),
     ]
     stats = {}
-    for e in ("fused", "blocks"):
-        app = METLApp(coord, engine=e)
+    for e, device_densify in (("host", False), ("device", True)):
+        app = _app(coord, device_densify)
         app.consume(columnarize(evs))
         stats[e] = {k: app.stats[k] for k in ("bad_payload", "dead_lettered", "mapped")}
         assert app.stats["bad_payload"] == 2
-    assert stats["fused"] == stats["blocks"]
+    assert stats["host"] == stats["device"]
 
 
 # ---------------------------------------------------------------------------

@@ -8,7 +8,7 @@ Covers the acceptance surface of the control-plane tentpole:
     and the DPM bit-exactly (closure records are flagged non-replayable);
   * a mid-stream SchemaEvolved applied through the IN-BAND control path
     yields bit-identical canonical rows to the same scenario run with
-    out-of-band apply_update + manual refresh (fused and blocks engines,
+    out-of-band apply_update + manual refresh (host and device densify,
     sync and async consume; the sharded engine in a forced-topology
     subprocess);
   * freeze/thaw during a running pipeline: data flows inside the window, a
@@ -198,11 +198,11 @@ class TestControlLogReplay:
 # ---------------------------------------------------------------------------
 
 
-def _run_out_of_band(seed, engine, n_chunks, size, boundary):
+def _run_out_of_band(seed, engine, n_chunks, size, boundary, device_densify=False):
     """The oracle: same chunk grid, manual apply_update + refresh."""
     sc = build_scenario(ScenarioConfig(seed=seed))
     coord = StateCoordinator(sc.registry, sc.dpm)
-    app = METLApp(coord, engine=engine)
+    app = METLApp(coord, engine=engine, device_densify=device_densify)
     src = EventSource(sc.registry, seed=5)
     ev, o, v = _evolve_event(coord.registry, 0, "mid")
     rows = []
@@ -218,10 +218,12 @@ def _run_out_of_band(seed, engine, n_chunks, size, boundary):
     return rows, app
 
 
-def _run_in_band(seed, engine, n_chunks, size, boundary, async_consume):
+def _run_in_band(
+    seed, engine, n_chunks, size, boundary, async_consume, device_densify=False
+):
     sc = build_scenario(ScenarioConfig(seed=seed))
     coord = StateCoordinator(sc.registry, sc.dpm)
-    app = METLApp(coord, engine=engine)
+    app = METLApp(coord, engine=engine, device_densify=device_densify)
     ev, _, _ = _evolve_event(coord.registry, 0, "mid")
     sink = CollectSink()
     pipe = Pipeline(
@@ -237,19 +239,22 @@ def _run_in_band(seed, engine, n_chunks, size, boundary, async_consume):
 STAT_KEYS = ("events", "duplicates", "mapped", "empty", "dispatches", "stale")
 
 
-@pytest.mark.parametrize("engine", ["fused", "blocks"])
+@pytest.mark.parametrize("device_densify", [False, True], ids=["fused", "device"])
 @pytest.mark.parametrize("async_consume", [False, True])
-def test_inband_evolution_matches_out_of_band_oracle(engine, async_consume):
+def test_inband_evolution_matches_out_of_band_oracle(device_densify, async_consume):
     """The acceptance oracle: a mid-stream SchemaEvolved through the in-band
     control path is bit-identical to out-of-band apply_update + refresh."""
-    rows_oob, app_oob = _run_out_of_band(81, engine, 6, 64, 3)
-    rows_ib, app_ib = _run_in_band(81, engine, 6, 64, 3, async_consume)
+    rows_oob, app_oob = _run_out_of_band(
+        81, "fused", 6, 64, 3, device_densify=device_densify
+    )
+    rows_ib, app_ib = _run_in_band(
+        81, "fused", 6, 64, 3, async_consume, device_densify=device_densify
+    )
     assert len(rows_oob) > 0
     _assert_rows_equal(rows_oob, rows_ib)
     for k in STAT_KEYS:
         assert app_oob.stats[k] == app_ib.stats[k], k
-    if engine == "fused":
-        assert app_ib.stats["dispatches"] == 6  # still 1/chunk across the epoch
+    assert app_ib.stats["dispatches"] == 6  # still 1/chunk across the epoch
 
 
 @pytest.mark.slow

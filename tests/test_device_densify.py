@@ -8,13 +8,13 @@ Covers the acceptance surface:
     synthetic stream (duplicates + stale events) at several chunk sizes;
   * property test (hypothesis): random payloads -- empty / all-None /
     foreign-uid / out-of-range-uid / bad (non-numeric) values -- across
-    engine pairs and chunk sizes, device rows+stats == host oracle;
+    chunk sizes, device rows+stats == host oracle;
   * parked-event replay (events from the app's future) and an epoch
     transition (live schema evolution mid-stream) stay bit-exact;
   * out-of-range uid regression: never an index error, clamped out of the
-    scatter, counted under stats["unknown_uid"] IDENTICALLY across the
-    blocks / fused / fused+device engines, for a uid in the uid tables'
-    capacity padding as for one past their end;
+    scatter, counted under stats["unknown_uid"] IDENTICALLY with host and
+    with device densify, for a uid in the uid tables' capacity padding as
+    for one past their end;
   * one program across registry states: ten schema evolutions keep the
     plan's table shapes, so the columnar program never recompiles;
   * accounting: the device path makes exactly 1 host->device transfer and
@@ -177,14 +177,9 @@ def test_device_densify_hypothesis(world):
         chunk = columnarize(list(events))
         host = METLApp(coord, engine="fused")
         dev = _device_app(coord)
-        blk = METLApp(coord, engine="blocks")
         rows_h = host.consume(chunk)
         _assert_rows_equal(rows_h, dev.consume(chunk))
         _assert_stats_equal(host, dev)
-        # the blocks engine agrees on the shared accounting too
-        blk.consume(chunk)
-        for k in ("events", "mapped", "empty", "bad_payload", "unknown_uid"):
-            assert host.stats[k] == blk.stats[k], k
 
     check()
 
@@ -300,7 +295,6 @@ def test_out_of_range_uid_never_crashes_and_is_counted(world):
     stats = {}
     rows = {}
     for name, app in (
-        ("blocks", METLApp(coord, engine="blocks")),
         ("fused", METLApp(coord, engine="fused")),
         ("device", _device_app(coord)),
     ):
@@ -308,9 +302,8 @@ def test_out_of_range_uid_never_crashes_and_is_counted(world):
         stats[name] = {k: app.stats[k]
                        for k in ("unknown_uid", "mapped", "empty", "events")}
         assert app.stats["unknown_uid"] == 3, name
-    assert stats["blocks"] == stats["fused"] == stats["device"]
+    assert stats["fused"] == stats["device"]
     _assert_rows_equal(rows["fused"], rows["device"])
-    _assert_rows_equal(rows["fused"], rows["blocks"])
 
 
 @pytest.mark.parametrize("where", ["in_padding", "past_capacity"])
@@ -318,8 +311,8 @@ def test_uid_outside_the_plan_is_dropped_and_counted(world, where):
     """The uid tables run past the largest known uid to their capacity
     bucket.  A uid in that padding is dropped and counted under
     ``stats["unknown_uid"]`` exactly like a uid past the table's end, on
-    every engine: blocks, host scatter, device densify and the device
-    app's small-chunk host fallback."""
+    every path: host scatter, device densify and the device app's
+    small-chunk host fallback."""
     sc, coord = world
     reg = sc.registry
     o = reg.domain.schema_ids()[0]
@@ -339,7 +332,6 @@ def test_uid_outside_the_plan_is_dropped_and_counted(world, where):
     dirty = [_mk_event(1, o, v, {uids[0]: 2.0, stray: 9.0, uids[1]: 3.0}, s),
              _mk_event(2, o, v, {stray: 1.0, uids[2]: 4.0}, s)]
     for name, make in (
-        ("blocks", lambda: METLApp(coord, engine="blocks")),
         ("fused", lambda: METLApp(coord, engine="fused")),
         ("device", lambda: _device_app(coord)),
         ("fallback", lambda: _device_app(coord, min_device_events=32)),

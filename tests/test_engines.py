@@ -2,12 +2,13 @@
 (densify -> dispatch -> emit), info() observability, custom engines.
 
 Covers the acceptance surface of the API redesign:
-  * all registered engines pass the scalar-oracle bit-exactness check
-    through the protocol (``engine=`` string kwargs still accepted);
+  * the fused engine, with host and with device densify, passes the
+    scalar-oracle bit-exactness check through the protocol (``engine=``
+    string kwargs still accepted);
   * the staged path (triage -> densify -> dispatch -> emit) produces
     exactly what the one-shot ``consume`` produces;
-  * legacy routing rules survive the factory (impl="onehot" -> blocks,
-    sharded on a 1-shard mesh -> fused);
+  * the factory's routing rule (sharded on a 1-shard mesh -> fused), and
+    its refusal of an unknown ``impl`` such as ``"onehot"``;
   * dispatch returns an unblocked handle; emit is the only sync point;
   * ``info()`` exposes what launchers/benchmarks used to reach into
     private attributes for;
@@ -23,7 +24,6 @@ import jax
 from repro.core.state import StateCoordinator
 from repro.core.synthetic import ScenarioConfig, build_scenario
 from repro.etl import (
-    BlocksEngine,
     EventSource,
     FusedEngine,
     METLApp,
@@ -74,15 +74,16 @@ def _unique(events):
 
 def test_factory_resolves_builtin_names():
     assert isinstance(make_engine("fused"), FusedEngine)
-    assert isinstance(make_engine("blocks"), BlocksEngine)
+    assert {"fused", "sharded"} <= set(ENGINES) and "blocks" not in ENGINES
     with pytest.raises(ValueError):
         make_engine("warp")
 
 
 def test_factory_legacy_routing_rules():
-    # impl="onehot" has no fused realisation -> per-block engine
-    assert isinstance(make_engine("fused", impl="onehot"), BlocksEngine)
-    assert isinstance(make_engine("sharded", impl="onehot"), BlocksEngine)
+    # impl="onehot" has no realisation: refused, never rerouted
+    for engine in ("fused", "sharded"):
+        with pytest.raises(ValueError, match="onehot"):
+            make_engine(engine, impl="onehot")
     # sharded without a multi-shard mesh degenerates to replicated fused
     assert isinstance(make_engine("sharded", mesh=None), FusedEngine)
 
@@ -91,9 +92,9 @@ def test_instance_with_conflicting_kwargs_rejected():
     # silently dropping impl=/mesh= for an instance would run a different
     # path than requested
     with pytest.raises(ValueError):
-        make_engine(FusedEngine(), impl="onehot")
-    eng = FusedEngine(impl="onehot")
-    assert make_engine(eng, impl="onehot") is eng  # matching impl is fine
+        make_engine(FusedEngine(), impl="ref")
+    eng = FusedEngine(impl="ref")
+    assert make_engine(eng, impl="ref") is eng  # matching impl is fine
 
 
 def test_app_accepts_engine_instance_and_shares_stats():
@@ -129,13 +130,13 @@ def test_custom_engine_registration():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("engine", ["fused", "blocks"])
-def test_staged_protocol_matches_consume(engine):
+@pytest.mark.parametrize("device_densify", [False, True], ids=["fused", "device"])
+def test_staged_protocol_matches_consume(device_densify):
     """triage -> densify -> dispatch -> emit, called stage by stage, must
     reproduce consume() exactly (rows, order, stats)."""
     sc, coord = _world(seed=42)
-    ref = METLApp(coord, engine=engine)
-    staged = METLApp(coord, engine=engine)
+    ref = METLApp(coord, device_densify=device_densify)
+    staged = METLApp(coord, device_densify=device_densify)
     src = EventSource(sc.registry, seed=5)
     events = src.slice(0, 150)
 
@@ -156,10 +157,10 @@ def test_staged_protocol_matches_consume(engine):
         assert ref.stats[k] == staged.stats[k], k
 
 
-@pytest.mark.parametrize("engine", ["fused", "blocks"])
-def test_engine_bit_exact_with_scalar_oracle(engine):
+@pytest.mark.parametrize("device_densify", [False, True], ids=["fused", "device"])
+def test_engine_bit_exact_with_scalar_oracle(device_densify):
     sc, coord = _world(seed=43)
-    app = METLApp(coord, engine=engine)
+    app = METLApp(coord, device_densify=device_densify)
     src = EventSource(sc.registry, seed=6, p_duplicate=0.0)
     events = _unique(src.slice(0, 120))
     rows = app.consume(events)
@@ -219,12 +220,13 @@ def test_unmappable_chunk_densifies_to_none():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("engine", ["fused", "blocks"])
-def test_info_exposes_plan_and_accounting(engine):
+@pytest.mark.parametrize("device_densify", [False, True], ids=["fused", "device"])
+def test_info_exposes_plan_and_accounting(device_densify):
     sc, coord = _world(seed=47)
-    app = METLApp(coord, engine=engine)
+    app = METLApp(coord, device_densify=device_densify)
     info = app.engine.info()
-    assert info["engine"] == engine
+    assert info["engine"] == "fused"
+    assert info["device_densify"] is device_densify
     assert info["n_shards"] == 1
     assert info["n_blocks"] > 0
     assert info["table_bytes"] > 0

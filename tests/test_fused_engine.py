@@ -2,7 +2,8 @@
 pure Algorithm-6 oracle (``METLApp.consume_scalar``).
 
 Covers the acceptance surface of the fused refactor:
-  * fused consume == consume_scalar == legacy per-block consume, exactly;
+  * fused consume == consume_scalar, and host densify == device densify,
+    exactly;
   * multi-block columns (one schema feeding several business entities);
   * empty / null-block columns (events with no mapping paths);
   * padded lane widths (CDM wider than one 128-lane tile);
@@ -98,25 +99,26 @@ def test_fused_matches_scalar_oracle_synthetic():
 
 
 def test_fused_matches_legacy_engine_exactly():
-    """Same chunk through both engines: identical rows, identical order,
-    identical stats -- only the dispatch count differs."""
+    """Same chunk through host and device densify: identical rows,
+    identical order, identical stats and one dispatch each -- only the
+    transfers differ (four operands against one packed buffer)."""
     sc = build_scenario(ScenarioConfig(seed=42))
     coord = StateCoordinator(sc.registry, sc.dpm)
     fused = METLApp(coord, engine="fused")
-    blocks = METLApp(coord, engine="blocks")
+    device = METLApp(coord, engine="fused", device_densify=True)
     src = EventSource(sc.registry, seed=5)
     events = src.slice(0, 200)
     rf = fused.consume(events)
-    rb = blocks.consume(events)
-    assert len(rf) == len(rb)
-    for a, b in zip(rf, rb):
+    rd = device.consume(events)
+    assert len(rf) == len(rd) > 0
+    for a, b in zip(rf, rd):
         assert a[0] == b[0] and a[3] == b[3]
         np.testing.assert_array_equal(a[1], b[1])
         np.testing.assert_array_equal(a[2], b[2])
-    for k in ("events", "duplicates", "mapped", "empty"):
-        assert fused.stats[k] == blocks.stats[k], k
+    for k in ("events", "duplicates", "mapped", "empty", "dispatches"):
+        assert fused.stats[k] == device.stats[k], k
     assert fused.stats["dispatches"] == 1
-    assert blocks.stats["dispatches"] > 1
+    assert (fused.stats["transfers"], device.stats["transfers"]) == (4, 1)
 
 
 def test_multi_block_column_and_null_column():

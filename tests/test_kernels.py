@@ -8,7 +8,7 @@ import jax.numpy as jnp
 from repro.kernels import ref
 from repro.kernels.masked_gather import masked_gather
 from repro.kernels.moe_combine import moe_combine
-from repro.kernels.onehot_map import onehot_map
+from repro.kernels.densify_map import densify_map
 from repro.kernels.segmented_gather import segmented_gather
 
 
@@ -48,15 +48,81 @@ def test_masked_gather_matches_oracle(b, n_in, n_out, dtype, density):
     assert np.array_equal(np.asarray(rm), np.asarray(gm))
 
 
-@pytest.mark.parametrize("b,n_in,n_out", SHAPES[:3])
-@pytest.mark.parametrize("density", [0.0, 0.5, 1.0])
-def test_onehot_map_matches_oracle(b, n_in, n_out, density):
-    rng = np.random.default_rng(hash((b, n_in, n_out, density, 1)) % 2**31)
-    vals, mask, src = _mk_case(rng, b, n_in, n_out, density, np.float32)
-    rv, rm = ref.onehot_map_ref(vals, mask, src)
-    ov, om = onehot_map(vals, mask, src, interpret=True)
-    np.testing.assert_allclose(np.asarray(rv), np.asarray(ov), atol=1e-5)
-    assert np.array_equal(np.asarray(rm), np.asarray(om))
+def _densify_case(rng, case, w):
+    """Operands of one ``densify_map`` case: (B, K) resolved items, (S,)
+    routing and an (n_blocks, W) block table whose entries are payload
+    slots drawn from the items' slot range, so most output lanes hit."""
+    b, k, n_rows, n_blocks, n_slots = 24, 8, 40, 16, 20
+    if case == "rows_above_events":
+        n_rows = 3 * b
+    elif case == "rows_below_events":
+        n_rows = b // 3
+    slot2d = rng.integers(0, n_slots, size=(b, k)).astype(np.int32)
+    # distinct slots per event by default: duplicates get their own case
+    for e in range(b):
+        slot2d[e] = rng.choice(n_slots, size=k, replace=False)
+    if case == "zero_items":
+        slot2d[::2] = -1  # every other event carries no item at all
+    elif case == "duplicate_uids":
+        slot2d[:, k // 2:] = slot2d[:, : k - k // 2]  # each slot twice
+    elif case == "unknown_uids":
+        slot2d[rng.random((b, k)) < 0.5] = -1  # unknown / foreign: dropped
+    x2d = rng.normal(size=(b, k)).astype(np.float32)
+    rows = rng.integers(0, b, size=n_rows).astype(np.int32)
+    blks = rng.integers(0, n_blocks, size=n_rows).astype(np.int32)
+    src2d = np.where(
+        rng.random((n_blocks, w)) < 0.3,
+        rng.integers(0, n_slots, size=(n_blocks, w)),
+        -1,
+    ).astype(np.int32)
+    return slot2d, x2d, rows, blks, src2d
+
+
+def _densify_scatter_oracle(slot2d, x2d, rows, blks, src2d, fill):
+    """The host semantics in plain numpy: scatter each event's items into a
+    dense row in item order (a later duplicate overwrites an earlier one),
+    then gather every routed (event, block) row through the block table."""
+    b, _ = slot2d.shape
+    n_slots = int(max(slot2d.max(), src2d.max())) + 1
+    dense = np.zeros((b, n_slots), np.float32)
+    have = np.zeros((b, n_slots), bool)
+    for e in range(b):
+        for j in range(slot2d.shape[1]):
+            if slot2d[e, j] >= 0:
+                dense[e, slot2d[e, j]] = x2d[e, j]
+                have[e, slot2d[e, j]] = True
+    src = src2d[blks]
+    safe = np.where(src >= 0, src, 0)
+    hit = (src >= 0) & np.take_along_axis(have[rows], safe, axis=1)
+    vals = np.where(hit, np.take_along_axis(dense[rows], safe, axis=1), fill)
+    return vals.astype(np.float32), hit.astype(np.int8)
+
+
+DENSIFY_CASES = [
+    "max_items", "zero_items", "duplicate_uids", "unknown_uids",
+    "rows_above_events", "rows_below_events",
+]
+
+
+@pytest.mark.parametrize("w", [128, 256])
+@pytest.mark.parametrize("case", DENSIFY_CASES)
+def test_densify_map_matches_oracle(case, w):
+    """The kernel every cell runs, under the interpreter, against its
+    ``ref.py`` twin and a plain numpy scatter-then-gather: events with no
+    item and with all ``k``, duplicate slots (last writer wins), dropped
+    (-1) items, more and fewer output rows than events, two table widths."""
+    rng = np.random.default_rng([DENSIFY_CASES.index(case), w])
+    slot2d, x2d, rows, blks, src2d = _densify_case(rng, case, w)
+    fill = 0.25
+    kv, km = densify_map(slot2d, x2d, rows, blks, src2d, fill=fill, interpret=True)
+    rv, rm = ref.densify_map_ref(slot2d, x2d, rows, blks, src2d, fill=fill)
+    ov, om = _densify_scatter_oracle(slot2d, x2d, rows, blks, src2d, fill)
+    assert np.asarray(kv).shape == (rows.size, w)
+    np.testing.assert_array_equal(np.asarray(kv), np.asarray(rv))
+    np.testing.assert_array_equal(np.asarray(km), np.asarray(rm))
+    np.testing.assert_array_equal(np.asarray(kv), ov)
+    np.testing.assert_array_equal(np.asarray(km), om)
+    assert om.any()  # the case maps something
 
 
 @pytest.mark.parametrize("b,n_in,w", [(8, 64, 128), (37, 300, 256), (64, 128, 128)])

@@ -69,19 +69,20 @@ def test_sync_pipeline_matches_direct_consume(world):
         assert direct.stats[k] == app.stats[k], k
 
 
-@pytest.mark.parametrize("engine", ["fused", "blocks"])
+@pytest.mark.parametrize("device_densify", [False, True], ids=["fused", "device"])
 @pytest.mark.parametrize("densify_thread", [False, True])
-def test_async_bit_exact_with_sync(world, engine, densify_thread):
+def test_async_bit_exact_with_sync(world, device_densify, densify_thread):
     """The double buffer changes wall-clock, never results: same rows, same
-    order, same stats, still one dispatch per chunk for the fused engine."""
+    order, same stats, still one dispatch per chunk, with host and with
+    device densify."""
     sc, coord, src = world
     chunks = _chunks(src, 5)
 
-    app_s = METLApp(coord, engine=engine)
+    app_s = METLApp(coord, device_densify=device_densify)
     sink_s = CollectSink()
     Pipeline(ListSource(chunks), app_s, [sink_s]).run()
 
-    app_a = METLApp(coord, engine=engine)
+    app_a = METLApp(coord, device_densify=device_densify)
     sink_a = CollectSink()
     pipe = Pipeline(
         ListSource(chunks), app_a, [sink_a],
@@ -94,8 +95,7 @@ def test_async_bit_exact_with_sync(world, engine, densify_thread):
     _assert_rows_equal(sink_s.rows, sink_a.rows)
     for k in STAT_KEYS:
         assert app_s.stats[k] == app_a.stats[k], k
-    if engine == "fused":
-        assert app_a.stats["dispatches"] == 5  # 1 per chunk, unchanged
+    assert app_a.stats["dispatches"] == 5  # 1 per chunk, unchanged
 
 
 def test_fanout_two_sinks(world):

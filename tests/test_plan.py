@@ -1,4 +1,4 @@
-"""The epoched plan lifecycle: PlanManager, incremental recompaction, tiering.
+"""The epoched plan lifecycle: PlanManager, incremental recompaction, epochs.
 
 Covers the acceptance surface of the plan-lifecycle tentpole:
   * incremental lowering is bit-exact with the full-rebuild oracle at both
@@ -7,15 +7,11 @@ Covers the acceptance surface of the plan-lifecycle tentpole:
     MatrixEdit that deletes columns from the table);
   * a :class:`PlanManager` with ``incremental=True`` produces bit-identical
     canonical rows (and stats) to ``incremental=False`` through the full
-    in-band pipeline -- fused and blocks engines, sync and async consume,
-    device densify, and the sharded engine on a forced 1x4 topology;
-  * hot/cold residency tiering: cold columns are served through the host
-    ``apply_compacted`` fallback with the same rows (sorted by event key)
-    as an untiered twin, ``bytes_resident`` shrinks, ``tier_misses`` are
-    counted, and :meth:`PlanManager.repartition` warms hit columns back in
-    as a new epoch for the SAME state;
-  * the background recompactor matches the synchronous build bit for bit
-    (it is an optimisation, never a correctness dependency);
+    in-band pipeline -- host and device densify, sync and async consume,
+    and the sharded engine on a forced 1x4 topology;
+  * the manager builds one resident plan: ``bytes_resident`` is the device
+    block table's bytes before and after an evolution, and the removed
+    kinds and options are refused with an error naming what exists;
   * ``publish=True`` logs :class:`PlanPublished` cutovers in the control
     log, ``replay_control_log`` reproduces registry/state/DPM bit-exactly
     across them, and an in-flight epoch-pinned chunk drains on the OLD
@@ -53,7 +49,7 @@ from repro.etl import (
     PlanManager,
     PlanPublished,
     SchemaEvolved,
-    TieringPolicy,
+    make_engine,
     replay_control_log,
 )
 
@@ -208,9 +204,9 @@ class TestPlanManager:
         with pytest.raises(ValueError):
             PlanManager(kind="sharded")  # needs a mesh or n_shards
         sc, coord = _world()
-        with pytest.raises(ValueError):
-            # the fused engine cannot consume a blocks manager
-            METLApp(coord, plan_manager=PlanManager(kind="blocks"))
+        with pytest.raises(ValueError, match="consumes plan kind"):
+            # the fused engine cannot consume a sharded manager
+            METLApp(coord, plan_manager=PlanManager(kind="sharded", n_shards=2))
 
     def test_manager_incremental_plan_equals_full_oracle_plan(self):
         """The manager's own DPM diff + splice, checked against a
@@ -286,8 +282,89 @@ def test_table_shape_changes_counts_a_capacity_crossing():
     assert mgr.info()["table_shape_changes"] == 1
     assert lease.plan.src2d.shape[0] == 2 * n
     # a same-state rebuild keeps the shapes: nothing more to count
-    mgr.repartition(coord.snapshot(), coord.registry)
+    mgr.invalidate()
+    mgr.acquire(coord.snapshot(), coord.registry)
     assert mgr.info()["table_shape_changes"] == 1
+
+
+# ---------------------------------------------------------------------------
+# one resident plan: what the manager guarantees, and what it refuses
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("kind", ["fused", "sharded"])
+def test_bytes_resident_is_the_device_table(kind):
+    """Every lease holds the whole plan on the device: ``bytes_resident``
+    is the block table's bytes, on the lease, in the manager's info and in
+    the engine's, before and after an evolution."""
+    sc, coord = _world(seed=103)
+    mgr = PlanManager(kind=kind, n_shards=4 if kind == "sharded" else None)
+    for step in range(2):
+        if step:
+            ev, _, _ = _evolve_event(coord.registry, 0, "res")
+            coord.apply(ev)
+        lease = mgr.acquire(coord.snapshot(), coord.registry)
+        assert lease.incremental == bool(step)
+        table = lease.plan.src3d if kind == "sharded" else lease.plan.src2d
+        assert lease.bytes_resident == int(table.nbytes) > 0
+        assert mgr.info()["bytes_resident"] == lease.bytes_resident
+    if kind == "fused":
+        app = METLApp(coord, plan_manager=mgr)
+        info = app.engine.info()
+        assert info["bytes_resident"] == info["table_bytes"] == lease.bytes_resident
+
+
+@pytest.mark.parametrize(
+    "build,names",
+    [
+        (lambda: PlanManager(kind="blocks"), ("fused", "sharded")),
+        (lambda: make_engine("blocks"), ("fused", "sharded")),
+        (lambda: make_engine("fused", impl="onehot"),
+         ("fused", "ref", "segmented_gather", "densify_map")),
+    ],
+    ids=["plan_kind_blocks", "engine_blocks", "impl_onehot"],
+)
+def test_removed_paths_are_refused_by_name(build, names):
+    """The per-block plan kind and engine and the one-hot impl are gone:
+    asking for one raises, and the error names what exists instead of
+    falling back to another path."""
+    with pytest.raises(ValueError) as err:
+        build()
+    for name in names:
+        assert name in str(err.value), (name, str(err.value))
+
+
+def test_publish_manager_device_densify_replays_bit_exact():
+    """A ``publish=True`` manager on the device-densify path across two
+    evolutions and a revert: the rows equal the host-densify run's, each
+    published epoch prices its whole table, and a coordinator rebuilt from
+    the control log maps a fresh chunk bit-exactly like the original."""
+    rows_dev, app, mgr = _run_churn(
+        "fused", "fused", False, incremental=True, seed=88, publish=True,
+        device_densify=True,
+    )
+    rows_host, _, _ = _run_churn(
+        "fused", "fused", False, incremental=True, seed=88, publish=True
+    )
+    assert len(rows_dev) > 0
+    _assert_rows_equal(rows_host, rows_dev)
+    assert app.stats["transfers"] == app.stats["dispatches"]  # device path
+    coord = app.coordinator
+    pubs = [r.event for r in coord.control_log if isinstance(r.event, PlanPublished)]
+    assert [p.epoch for p in pubs] == [1, 2, 3, 4]
+    assert pubs[-1].bytes_resident == app.engine.info()["table_bytes"]
+
+    seed = build_scenario(ScenarioConfig(seed=88))
+    replayed = replay_control_log(coord.control_log, seed.registry, seed.dpm)
+    assert replayed.snapshot().dpm == coord.snapshot().dpm
+    rows = [
+        METLApp(c, device_densify=True).consume(
+            EventSource(c.registry, seed=7).slice_columnar(0, 96)
+        )
+        for c in (coord, replayed)
+    ]
+    assert len(rows[0]) > 0
+    _assert_rows_equal(rows[0], rows[1])
 
 
 # ---------------------------------------------------------------------------
@@ -304,7 +381,6 @@ def _run_churn(
     device_densify=False,
     seed=91,
     publish=False,
-    background=False,
     n_chunks=7,
     size=64,
 ):
@@ -313,8 +389,7 @@ def _run_churn(
     sc = build_scenario(ScenarioConfig(seed=seed))
     coord = StateCoordinator(sc.registry, sc.dpm)
     mgr = PlanManager(
-        kind=kind, coordinator=coord, incremental=incremental,
-        publish=publish, background=background,
+        kind=kind, coordinator=coord, incremental=incremental, publish=publish
     )
     app = METLApp(
         coord, engine=engine, plan_manager=mgr, device_densify=device_densify
@@ -332,21 +407,22 @@ def _run_churn(
         app, [sink], async_consume=async_consume,
     ).run()
     assert st.chunks == n_chunks and st.control == 3
-    mgr.close()
     return sink.rows, app, mgr
 
 
-@pytest.mark.parametrize("engine,kind", [("fused", "fused"), ("blocks", "blocks")])
+@pytest.mark.parametrize("device_densify", [False, True], ids=["fused", "device"])
 @pytest.mark.parametrize("async_consume", [False, True])
-def test_incremental_rows_match_full_rebuild_oracle(engine, kind, async_consume):
+def test_incremental_rows_match_full_rebuild_oracle(device_densify, async_consume):
     """The acceptance oracle: a manager splicing only the touched columns
     yields bit-identical rows (zero dropped, zero duplicated) to a manager
     doing the full rebuild at every churn step."""
     rows_full, app_full, mgr_full = _run_churn(
-        engine, kind, async_consume, incremental=False
+        "fused", "fused", async_consume, incremental=False,
+        device_densify=device_densify,
     )
     rows_inc, app_inc, mgr_inc = _run_churn(
-        engine, kind, async_consume, incremental=True
+        "fused", "fused", async_consume, incremental=True,
+        device_densify=device_densify,
     )
     assert len(rows_full) > 0
     _assert_rows_equal(rows_full, rows_inc)
@@ -425,132 +501,6 @@ def test_incremental_rows_match_oracle_sharded():
         print("sharded incremental parity OK")
     """)
     assert "sharded incremental parity OK" in out
-
-
-# ---------------------------------------------------------------------------
-# hot/cold residency tiering
-# ---------------------------------------------------------------------------
-
-
-class TestTiering:
-    def test_policy_pins_latest_live_versions(self):
-        sc, coord = _world(seed=97)
-        ev, o, v = _evolve_event(coord.registry)
-        coord.apply(ev)
-        reg = coord.registry
-        compiled = compile_dpm(coord.snapshot().dpm, reg)
-        latest = {
-            (oo, reg.domain.latest_version(oo))
-            for oo in reg.domain.schema_ids()
-        }
-        pol = TieringPolicy(min_hits=1, pin_latest=True)
-        # no hits anywhere: every non-latest column is cold, latest stay hot
-        assert pol.cold_columns(compiled, reg, {}) == (
-            set(compiled.by_column) - latest
-        )
-        # a hit warms its column in
-        cold = pol.cold_columns(compiled, reg, {(o, v): 3})
-        assert (o, v) not in cold
-        # without the pin, hit-less latest versions go cold too
-        pol2 = TieringPolicy(min_hits=1, pin_latest=False)
-        assert pol2.cold_columns(compiled, reg, {}) == set(compiled.by_column)
-
-    def test_all_cold_fallback_is_bit_exact(self):
-        """An impossible hit bar with no latest pin forces EVERY column
-        through the host apply_compacted miss path: same rows (per-chunk,
-        sorted by event key), zero device dispatches, smaller residency."""
-        seed = 98
-        sc_a = build_scenario(ScenarioConfig(seed=seed))
-        coord_a = StateCoordinator(sc_a.registry, sc_a.dpm)
-        app_a = METLApp(coord_a)
-        src_a = EventSource(sc_a.registry, seed=5)
-        sc_b = build_scenario(ScenarioConfig(seed=seed))
-        coord_b = StateCoordinator(sc_b.registry, sc_b.dpm)
-        mgr = PlanManager(
-            kind="fused", coordinator=coord_b,
-            tiering=TieringPolicy(min_hits=10**9, pin_latest=False),
-        )
-        app_b = METLApp(coord_b, plan_manager=mgr)
-        src_b = EventSource(sc_b.registry, seed=5)
-        for k in range(3):
-            rows_a = app_a.consume(src_a.slice_columnar(k * 64, 64))
-            rows_b = app_b.consume(src_b.slice_columnar(k * 64, 64))
-            _assert_rows_equal(_sorted_rows(rows_a), _sorted_rows(rows_b))
-        assert app_b.stats["tier_misses"] > 0
-        assert app_b.stats["dispatches"] == 0  # nothing resident to launch
-        assert app_a.stats["mapped"] == app_b.stats["mapped"] > 0
-        assert (
-            app_b.engine.info()["bytes_resident"]
-            < app_a.engine.info()["bytes_resident"]
-        )
-        assert mgr.info()["cold_columns"] == len(
-            app_b.engine.lease.compiled.by_column
-        )
-
-    def test_repartition_warms_hit_columns_same_state(self):
-        """Hit counters fed by triage + an explicit repartition: a NEW epoch
-        for the SAME state brings the hit columns device-side; rows stay
-        bit-exact with an untiered twin throughout."""
-        seed = 99
-        sc = build_scenario(ScenarioConfig(seed=seed))
-        coord = StateCoordinator(sc.registry, sc.dpm)
-        mgr = PlanManager(
-            kind="fused", coordinator=coord,
-            tiering=TieringPolicy(min_hits=1, pin_latest=False),
-        )
-        app = METLApp(coord, plan_manager=mgr)
-        src = EventSource(sc.registry, seed=5)
-        sc2 = build_scenario(ScenarioConfig(seed=seed))
-        coord2 = StateCoordinator(sc2.registry, sc2.dpm)
-        app2 = METLApp(coord2)
-        src2 = EventSource(sc2.registry, seed=5)
-
-        app.ensure_ready()
-        lease0 = app.engine.lease
-        assert lease0.epoch == 1 and lease0.cold  # no hits yet: all cold
-        r1 = app.consume(src.slice_columnar(0, 96))
-        o1 = app2.consume(src2.slice_columnar(0, 96))
-        _assert_rows_equal(_sorted_rows(o1), _sorted_rows(r1))
-        assert app.stats["tier_misses"] > 0
-
-        lease1 = mgr.repartition(coord.snapshot(), coord.registry)
-        assert lease1.epoch == 2 and lease1.state == lease0.state
-        assert len(lease1.cold) < len(lease0.cold)
-        assert lease1.bytes_resident > lease0.bytes_resident
-        app.refresh()  # re-acquire: cache hit on the repartitioned lease
-        assert app.engine.lease is lease1
-
-        r2 = app.consume(src.slice_columnar(96, 96))
-        o2 = app2.consume(src2.slice_columnar(96, 96))
-        _assert_rows_equal(_sorted_rows(o2), _sorted_rows(r2))
-        assert app.stats["dispatches"] >= 1  # warmed columns now launch
-
-
-# ---------------------------------------------------------------------------
-# background recompaction
-# ---------------------------------------------------------------------------
-
-
-def test_background_recompactor_matches_sync_build():
-    """background=True prepares epoch N+1 on the worker thread off the
-    eviction fan-out; adoption (or the sync fallback) is bit-exact with the
-    synchronous manager."""
-    rows_sync, app_sync, mgr_sync = _run_churn(
-        "fused", "fused", False, incremental=True, seed=90
-    )
-    rows_bg, app_bg, mgr_bg = _run_churn(
-        "fused", "fused", False, incremental=True, seed=90, background=True
-    )
-    assert len(rows_sync) > 0
-    _assert_rows_equal(rows_sync, rows_bg)
-    for k in STAT_KEYS:
-        assert app_sync.stats[k] == app_bg.stats[k], k
-    _assert_plans_equal(app_sync.engine.plan, app_bg.engine.plan)
-
-
-def test_background_requires_coordinator():
-    with pytest.raises(ValueError):
-        PlanManager(kind="fused", background=True)
 
 
 # ---------------------------------------------------------------------------
@@ -651,8 +601,6 @@ FUSED_ALWAYS = {
     "readbacks_early", "transfers", "plan_epoch", "rebuilds",
     "table_shape_changes",
 } | REPLICATION_KEYS
-BLOCKS_ALWAYS = {"engine", "impl", "n_shards", "dispatches", "plan_epoch",
-                 "rebuilds", "table_shape_changes"} | REPLICATION_KEYS
 PLAN_KEYS = {"state", "n_blocks", "blocks_per_shard", "table_bytes",
              "table_bytes_per_shard", "bytes_resident"}
 FUSED_PLAN_KEYS = PLAN_KEYS | {"width", "uid_capacity", "block_capacity"}
@@ -672,35 +620,30 @@ def test_engine_info_keys_match_documented_lists():
 
     doc = _documented(MappingEngine.info.__doc__)
     assert (FUSED_ALWAYS | FUSED_PLAN_KEYS) <= doc
-    assert (BLOCKS_ALWAYS | PLAN_KEYS) <= doc
 
     sc, coord = _world(seed=101)
     src = EventSource(sc.registry, seed=5)
-    for engine, always, plan_keys in [
-        ("fused", FUSED_ALWAYS, FUSED_PLAN_KEYS),
-        ("blocks", BLOCKS_ALWAYS, PLAN_KEYS),
-    ]:
-        from repro.etl import make_engine
-
+    for device_densify in (False, True):
         # pre-compile surface (METLApp compiles eagerly, so ask a bare one)
-        assert set(make_engine(engine).info()) == always, engine
-        app = METLApp(coord, engine=engine)
+        bare = make_engine("fused", device_densify=device_densify)
+        assert set(bare.info()) == FUSED_ALWAYS, device_densify
+        app = METLApp(coord, device_densify=device_densify)
         eng = app.engine
         app.consume(src.slice_columnar(0, 32))
         info = eng.info()
-        assert set(info) == always | plan_keys, engine
+        assert set(info) == FUSED_ALWAYS | FUSED_PLAN_KEYS, device_densify
         assert info["plan_epoch"] == 1 and info["rebuilds"] == 1
         # unreplicated coordinator: the single writer IS the leader
         assert info["role"] == "leader" and info["term"] == 0
         assert info["log_offset"] == len(coord.control_log)
         assert info["lag_records"] == 0
-        # default residency: everything hot, the lease prices the full table
+        # one resident plan: the lease prices the full table
         assert info["bytes_resident"] == info["table_bytes"] > 0
         eng.evict()
         # plan-gated keys (bytes_resident included) drop while evicted; the
         # manager-side counters survive
         evicted = eng.info()
-        assert set(evicted) == always, engine
+        assert set(evicted) == FUSED_ALWAYS, device_densify
         assert evicted["plan_epoch"] == 1
 
 

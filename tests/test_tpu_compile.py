@@ -118,7 +118,7 @@ def test_columnar_program_compiles(topo, one_chip, no_cache, kernels_compiled, e
 
 @pytest.mark.parametrize("events", CHUNKS)
 def test_masked_gather_compiles(topo, one_chip, no_cache, events):
-    """The per-block kernel ``engine="blocks"`` reaches on a TPU."""
+    """The per-block masked gather (``ops.dmm_apply``) on a TPU."""
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     text = _compile(
         functools.partial(masked_gather, interpret=False),
